@@ -15,6 +15,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.framework import jax_compat
 from paddle_tpu.models import gpt, gpt_hybrid
 from paddle_tpu.parallel.mesh import create_mesh
 from paddle_tpu.utils import CheckpointManager
@@ -56,5 +57,8 @@ if __name__ == "__main__":
     ap.add_argument("--steps", type=int, default=5)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    # an entry point: the fixed checkout cache unless one is named from
+    # outside (JAX_COMPILATION_CACHE_DIR, then PADDLE_JIT_CACHE_DIR)
+    jax_compat.enable_persistent_cache(jax_compat.checkout_cache_dir())
     main(dp=args.dp, pp=args.pp, tp=args.tp, sp=args.sp, steps=args.steps,
          ckpt_dir=args.ckpt_dir)

@@ -2,8 +2,8 @@
 
 Version-moving jax APIs must route through
 ``paddle_tpu/framework/jax_compat.py`` (standing ROADMAP constraint:
-the container pins jax 0.4.37 while the code targets the current
-names).  The old ``tools/shard_map_guard.sh`` grep enforced three
+one installed jax is supported, and the next upgrade is repaired in
+that one file).  The old ``tools/shard_map_guard.sh`` grep enforced three
 surface spellings and missed every aliased import; this rule resolves
 imports, aliases and attribute chains, so ``from jax.experimental
 import shard_map as sm`` and ``import jax; jax.sharding.NamedSharding``

@@ -280,6 +280,9 @@ def make_train_step(cfg, mesh, n_microbatch=1, zero_stage=2,
     # the live mesh's device topology, which the artifact store cannot
     # attest across processes.
     import dataclasses as _dc
+    # same rule as every other compile site: a cache directory named
+    # from outside is honoured, nothing is started otherwise
+    _cc.enable_persistent_cache()
     _mp_key = _cc.make_key(
         "mp_step",
         tuple(sorted((k, str(v))

@@ -29,7 +29,9 @@ single service they all ride now:
   / :func:`next_pow2`: the shared shape-bucketing maths the serving
   prefill ladder and the dynamic-batch StandaloneModel both use.
 * **persistent-cache integration** — :func:`enable_persistent_cache`
-  delegates to framework/jax_compat.py (``PADDLE_JIT_CACHE_DIR``); the
+  delegates to framework/jax_compat.py, which alone decides the
+  directory (``JAX_COMPILATION_CACHE_DIR``, else
+  ``PADDLE_JIT_CACHE_DIR``, else an entry point's default); the
   jax monitoring listener's ``compile.persistent_cache_*`` counters are
   absorbed into the same family.
 * **AOT-serialized executables** (the production win) — with
@@ -173,15 +175,6 @@ def artifact_dir():
     return _artifact_dir_override[0] or os.environ.get(ARTIFACT_ENV) or None
 
 
-def aot_available():
-    """Can this jax serialize compiled executables at all?  False
-    degrades every site to the plain build path (CPU-safe: jax 0.4.37
-    supports it on CPU and TPU, but a future jax without the API must
-    not crash the serving boot)."""
-    from . import jax_compat
-    return jax_compat.aot_supported()
-
-
 class ArtifactStore:
     """One shared artifact directory of serialized executables, keyed by
     the blake2b of a cross-process-stable key string.  Every artifact is
@@ -295,7 +288,7 @@ class ArtifactStore:
 
 def _store():
     d = artifact_dir()
-    if d is None or not aot_available():
+    if d is None:
         return None
     return ArtifactStore(d)
 
@@ -462,9 +455,11 @@ class SignatureLRU(Site):
 # persistent-cache integration
 # --------------------------------------------------------------------------
 
-def enable_persistent_cache(cache_dir=None):
-    """Delegates to jax_compat (``PADDLE_JIT_CACHE_DIR``); the
-    monitoring listener's ``compile.persistent_cache_*`` counters are
-    cells of this module's family."""
+def enable_persistent_cache(default_dir=None):
+    """Delegates to jax_compat, which alone decides the directory
+    (``JAX_COMPILATION_CACHE_DIR``, then ``PADDLE_JIT_CACHE_DIR``, then
+    ``default_dir``); the monitoring listener's
+    ``compile.persistent_cache_*`` counters are cells of this module's
+    family."""
     from . import jax_compat
-    return jax_compat.enable_persistent_cache(cache_dir)
+    return jax_compat.enable_persistent_cache(default_dir)

@@ -1,39 +1,24 @@
-"""Adapters for the moving jax API surface this repo targets.
+"""The seam for the jax API surface that moves between releases.
 
-The codebase is written against the current stable names (``jax.shard_map``
-with ``check_vma``, ``pltpu.CompilerParams``); older jax releases spell
-them ``jax.experimental.shard_map.shard_map`` with ``check_rep`` and
-``pltpu.TPUCompilerParams``.  Import from here instead of pinning either
-spelling.
+One installation is supported: Python 3.12 with jax/jaxlib 0.9.0 (libtpu
+0.0.34 on the chip machine).  Every name below is the spelling that
+installation has, called directly — there is no branch for another
+release.  The seam itself stays (analysis rule PTL001): callers import
+the moving names from here, so the next jax upgrade is repaired in this
+file and nowhere else.
 """
 from __future__ import annotations
 
-import inspect
+import os
 
-try:
-    from jax import shard_map as _shard_map          # jax >= 0.6
-except ImportError:                                  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_PARAMS = inspect.signature(_shard_map).parameters
-
-
-def shard_map(f, **kwargs):
-    """``jax.shard_map`` with the ``check_vma``/``check_rep`` rename
-    papered over (same meaning: skip per-axis replication checking)."""
-    if "check_vma" in kwargs and "check_vma" not in _PARAMS:
-        v = kwargs.pop("check_vma")
-        if "check_rep" in _PARAMS:
-            kwargs["check_rep"] = v
-    return _shard_map(f, **kwargs)
+from jax import shard_map  # noqa: F401  (re-exported: the PTL001 seam)
 
 
 def make_mesh(devices, axis_names):
-    """``jax.sharding.Mesh`` over an already-shaped device ndarray.  The
-    constructor itself is stable across the jax releases this repo
-    targets, but every *new* mesh call site routes through here (standing
-    ROADMAP constraint) so a future rename — jax keeps re-homing the
-    sharding types — is a one-line fix instead of a repo-wide grep."""
+    """``jax.sharding.Mesh`` over an already-shaped device ndarray.
+    Every mesh call site routes through here (standing ROADMAP
+    constraint) so a future rename — jax keeps re-homing the sharding
+    types — is a one-line fix instead of a repo-wide grep."""
     from jax.sharding import Mesh
     return Mesh(devices, axis_names)
 
@@ -64,19 +49,15 @@ def named_sharding(mesh, spec):
 
 def with_sharding_constraint(x, mesh, spec):
     """``jax.lax.with_sharding_constraint`` with the NamedSharding built
-    through :func:`named_sharding` (jax has moved this function between
-    ``jax.lax`` and ``jax.experimental.pjit`` across releases)."""
+    through :func:`named_sharding`."""
     import jax
-    fn = getattr(jax.lax, "with_sharding_constraint", None)
-    if fn is None:                                   # pragma: no cover
-        from jax.experimental.pjit import with_sharding_constraint as fn
-    return fn(x, named_sharding(mesh, spec))
+    return jax.lax.with_sharding_constraint(x, named_sharding(mesh, spec))
 
 
 def psum_scatter(x, axis_name, scatter_dimension=0, tiled=True):
-    """``jax.lax.psum_scatter`` (reduce-scatter inside shard_map/pmap) —
-    stable in the pinned jax, wrapped here because it is a
-    version-moving manual-collective like shard_map itself."""
+    """``jax.lax.psum_scatter`` (reduce-scatter inside shard_map) —
+    wrapped here because it is a version-moving manual collective like
+    shard_map itself."""
     import jax
     return jax.lax.psum_scatter(x, axis_name,
                                 scatter_dimension=scatter_dimension,
@@ -94,42 +75,30 @@ def all_gather(x, axis_name, axis=0, tiled=True):
 
 
 def axis_size(axis_name):
-    """``jax.lax.axis_size`` (new) — older jax spells it ``psum(1, axis)``,
-    which constant-folds to a python int inside mapped code."""
+    """``jax.lax.axis_size``: the size of a mapped axis, a python int
+    inside mapped code."""
     import jax
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def pcast_varying(x, axis_name):
-    """``lax.pcast(..., to="varying")`` where available; older jax has no
-    varying/invariant typing on manual axes, so the cast is a no-op."""
+    """``lax.pcast(..., to="varying")``: mark a value as differing per
+    rank of a manual axis."""
     import jax
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axis_name, to="varying")
-    return x
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def tpu_compiler_params(pltpu, **kwargs):
-    """``pltpu.CompilerParams`` (new) / ``pltpu.TPUCompilerParams`` (old)."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    """``pltpu.CompilerParams``."""
+    return pltpu.CompilerParams(**kwargs)
 
 
 def fp8_dtype():
     """The float8 storage dtype for weight-only quantized serving
-    (``PagedServingEngine(quant="fp8")``), or None when this jax doesn't
-    expose one.  jax 0.4.37 ships ``jnp.float8_e4m3fn`` (e4m3, max 448);
-    route through here instead of naming it so older/newer spellings
-    degrade to a clean "fp8 unavailable" error instead of an
-    AttributeError."""
+    (``PagedServingEngine(quant="fp8")``): ``jnp.float8_e4m3fn`` (e4m3,
+    max 448)."""
     import jax.numpy as jnp
-    return getattr(jnp, "float8_e4m3fn", None)
+    return jnp.float8_e4m3fn
 
 
 def donation_enabled(env_var):
@@ -138,17 +107,15 @@ def donation_enabled(env_var):
     Used by the fused optimizer step (``PADDLE_TPU_FUSED_DONATE``) and
     the serving engine's prefill/decode executables
     (``PADDLE_TPU_SERVING_DONATE``)."""
-    import os
     import jax
     mode = os.environ.get(env_var, "auto")
     if mode == "0":
         return False
     if mode == "1":
         return True
-    try:
-        return jax.default_backend() != "cpu"
-    except Exception:                                      # noqa: BLE001
-        return False
+    # a backend that fails to start raises here — "no accelerator" and
+    # "the accelerator did not answer" are not the same answer
+    return jax.default_backend() != "cpu"
 
 
 # --------------------------------------------------------------------------
@@ -157,86 +124,109 @@ def donation_enabled(env_var):
 
 def jax_export_module():
     """The ``jax.export`` module (StableHLO export/deserialize,
-    symbolic shapes).  jax has re-homed export twice
-    (``jax.experimental.export`` -> ``jax.export``); every export site
+    symbolic shapes).  jax has re-homed export twice; every export site
     routes through here so the next move is a one-line fix."""
-    try:
-        from jax import export
-        return export
-    except ImportError:                                  # pragma: no cover
-        from jax.experimental import export
-        return export
-
-
-def aot_supported():
-    """Can this jax serialize AOT-compiled executables
-    (``jax.experimental.serialize_executable``)?  False on jax builds
-    without the API — compile_cache degrades to the plain
-    build/persistent-cache path."""
-    try:
-        from jax.experimental import serialize_executable  # noqa: F401
-        return True
-    except Exception:                                      # noqa: BLE001
-        return False
+    from jax import export
+    return export
 
 
 def aot_serialize_compiled(compiled):
     """One pickleable blob for a ``jit(f).lower(...).compile()``
     executable: the xla-serialized binary plus its in/out pytree defs
-    (the triple ``serialize_executable.serialize`` returns).  Loading
-    it back in a FRESH process costs zero traces and zero backend
-    compiles — the whole point of the artifact store."""
+    (the triple ``serialize_executable.serialize`` returns) and the ids
+    of the devices it was compiled for.  Loading it back in a FRESH
+    process costs zero traces and zero backend compiles — the whole
+    point of the artifact store."""
     import pickle
     from jax.experimental import serialize_executable as _se
-    return pickle.dumps(_se.serialize(compiled))
+    ids = [d.id for d in compiled.runtime_executable().local_devices()]
+    return pickle.dumps((*_se.serialize(compiled), ids))
 
 
 def aot_deserialize_compiled(blob):
     """Inverse of :func:`aot_serialize_compiled`: a callable executable
-    bound to this process's devices."""
+    bound to this process's devices of the same ids.  jax 0.9.0 loads a
+    serialized executable onto EVERY device of the backend unless told
+    which ones (``execution_devices``), so a one-device executable
+    revived in an eight-device process would demand eight shards per
+    argument."""
     import pickle
+    import jax
     from jax.experimental import serialize_executable as _se
-    return _se.deserialize_and_load(*pickle.loads(blob))
+    payload, in_tree, out_tree, ids = pickle.loads(blob)
+    by_id = {d.id: d for d in jax.devices()}
+    return _se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in ids])
 
 
 # --------------------------------------------------------------------------
-# Persistent compilation cache (PADDLE_JIT_CACHE_DIR)
+# Persistent compilation cache
 # --------------------------------------------------------------------------
 
 _persistent_cache_dir = [None]
 
 
-def enable_persistent_cache(cache_dir=None):
-    """Point jax's persistent compilation cache at ``cache_dir`` (default:
-    ``PADDLE_JIT_CACHE_DIR``), so a fresh process re-loads every executable
-    it compiled last time instead of re-running XLA — the serving engine's
-    warm-restart path.  Thresholds are dropped to zero (the default
-    min-compile-time gate of 1s would skip exactly the small CPU
-    executables the tests exercise).  jax memoizes its is-cache-used
-    decision at first compile, so flipping the knob after a compile has
-    already happened must reset that memo — done here via
-    ``compilation_cache.reset_cache()``.
+def checkout_cache_dir():
+    """``<checkout>/.jax_cache`` (git-ignored): the fixed directory the
+    entry-point scripts (``chip_smoke.py``, ``bench.py``,
+    ``tools/tpu_kernel_check.py``, ``examples/``) fall back to.  Never a
+    temp name, pid or time: the path is part of the cache key, so a
+    directory that moves never hits."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    return os.path.join(os.path.dirname(os.path.dirname(here)), ".jax_cache")
+
+
+def resolve_cache_dir(default_dir=None):
+    """Where the persistent compilation cache lives, decided in ONE
+    place.  Returns ``(directory or None, placed_by_jax)``:
+
+    1. ``JAX_COMPILATION_CACHE_DIR`` set — that directory, and
+       ``placed_by_jax`` is True: jax read the variable itself at import
+       and no directory is ever set in code.  Neither
+       ``PADDLE_JIT_CACHE_DIR`` nor the fleet's ``jit_cache_dir=``
+       argument overrides it — the chip tool (or any operator) places
+       the cache from outside;
+    2. else ``PADDLE_JIT_CACHE_DIR`` if set;
+    3. else ``default_dir`` — entry-point scripts pass
+       :func:`checkout_cache_dir`; library constructors pass nothing, so
+       importing or constructing an engine under the tests never starts
+       a cache nobody asked for."""
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if d:
+        return d, True
+    d = os.environ.get("PADDLE_JIT_CACHE_DIR") or default_dir
+    return (str(d) if d else None), False
+
+
+def enable_persistent_cache(default_dir=None):
+    """Turn on jax's persistent compilation cache in the directory
+    :func:`resolve_cache_dir` names, so a fresh process re-loads every
+    executable it compiled last time instead of re-running XLA — the
+    serving engine's warm-restart path, and what lets one chip-tool
+    command share compiles between its processes.  Thresholds are
+    dropped to zero (the default min-compile-time gate of 1s would skip
+    exactly the small CPU executables the tests exercise).  jax memoizes
+    its is-cache-used decision at first compile, so flipping the knob
+    after a compile has already happened must reset that memo — done
+    here via ``compilation_cache.reset_cache()``.
 
     No-op (returns None) when no directory is configured; returns the
     active directory otherwise.  Idempotent per directory.
     """
-    import os as _os
-    d = cache_dir or _os.environ.get("PADDLE_JIT_CACHE_DIR")
-    if not d:
+    d, placed_by_jax = resolve_cache_dir(default_dir)
+    if d is None:
         return None
-    d = str(d)
-    import jax
     if _persistent_cache_dir[0] == d:
         return d
-    jax.config.update("jax_compilation_cache_dir", d)
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    if not placed_by_jax:
+        jax.config.update("jax_compilation_cache_dir", d)
     # cache every executable, however small/fast the compile
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()           # drop the memoized cache-unused verdict
-    except Exception:                                  # noqa: BLE001
-        pass                        # older/newer layout: first-compile wins
+    compilation_cache.reset_cache()  # drop the memoized cache-unused verdict
     _persistent_cache_dir[0] = d
     install_cache_event_hook()
     return d
@@ -294,8 +284,8 @@ def install_compile_hook(callback):
     """Fire ``callback(kind, seconds)`` once per XLA retrace — i.e. per
     backend compile of a new executable; cache hits and repeat calls with
     known signatures never fire.  Rides ``jax.monitoring``'s duration
-    listeners (stable across the jax versions this repo targets); the
-    listener stays registered for the process lifetime, so installation
+    listeners; the listener stays registered for the process lifetime, so
+    installation
     is once-only — a second call replaces the callback rather than
     stacking listeners.  Returns True on first install."""
     first = _compile_hook[0] is None

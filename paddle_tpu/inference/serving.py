@@ -106,6 +106,9 @@ def _stats_family():
         # not "how many steps ran" (0 off-TPU: the lax fallback serves)
         "quant_matmuls": 0, "kv_quant_bytes_saved": 0,
         "dequant_kernel_calls": 0,
+        # Pallas paged-attention kernel instantiations, fp and int8
+        # pools alike (same trace-time meaning; 0 off-TPU)
+        "paged_kernel_calls": 0,
         # speculative-decoding family (SpeculativeServingEngine,
         # ISSUE 13; zero on non-speculative engines): candidates the
         # drafter proposed, how many of those the verify accepted /
@@ -351,8 +354,9 @@ class ServingEngine:
         self.spec_mode = None
         self.spec_k = None
 
-        # a restart re-loads yesterday's executables (no-op without
-        # PADDLE_JIT_CACHE_DIR)
+        # a restart re-loads yesterday's executables (no-op unless
+        # JAX_COMPILATION_CACHE_DIR or PADDLE_JIT_CACHE_DIR names a
+        # directory — the fixed default belongs to entry-point scripts)
         jax_compat.enable_persistent_cache()
         timeline.install_compile_hook()
 
@@ -1069,7 +1073,7 @@ class ServingEngine:
         executables (decode; subclasses add theirs) have no valid
         artifacts — a partial store must not skip the wave that would
         have compiled the missing piece (the degradation contract)."""
-        if _cc.artifact_dir() is None or not _cc.aot_available():
+        if _cc.artifact_dir() is None:
             return set()
         if not self._aot_has_core():
             return set()
@@ -2606,7 +2610,7 @@ class PagedServingEngine(ServingEngine):
             step = (gpt.decode_step_paged_quant if kvq
                     else gpt.decode_step_paged)
             logits, *cache = step(params, toks, cfg, *cache, page_table,
-                                  wpages, woffs, lens)
+                                  wpages, woffs, lens, mesh=self._mesh)
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
             cache = self._constrain_cache(cache)
             if cap:

@@ -328,7 +328,7 @@ def quantize_params(params, quant="int8"):
       retries stay deterministic), through
       ``quantization.int8_matmul``'s int8xint8 MXU core.  More
       throughput on int8-rich TPUs, looser accuracy.
-    * ``"fp8"`` — float8_e4m3 storage where this jax exposes it
+    * ``"fp8"`` — float8_e4m3 storage
       (framework/jax_compat.py::fp8_dtype), dequant-fused via the lax
       path.
 
@@ -343,10 +343,6 @@ def quantize_params(params, quant="int8"):
     if quant == "fp8":
         from ..framework import jax_compat
         fp8 = jax_compat.fp8_dtype()
-        if fp8 is None:
-            raise ValueError(
-                "quant='fp8': this jax exposes no float8_e4m3 dtype — "
-                "use quant='int8'")
     key = "qw_dyn" if quant == "int8_dynamic" else "qw"
     blocks = dict(params["blocks"])
     if "moe_w1" in blocks:
@@ -844,7 +840,7 @@ def init_paged_cache(cfg: GPTConfig, num_pages, page_size, dtype=None,
 
 
 def _paged_slot_block(cfg, x, blk, k_pages, v_pages, page_table,
-                      write_pages, write_offs, lens):
+                      write_pages, write_offs, lens, mesh=None):
     """block_apply for the page-table single-token decode: slot s's new
     K/V land at (write_pages[s], write_offs[s]) — a batched scatter into
     the shared pool — and its query attends the gathered page view
@@ -857,7 +853,7 @@ def _paged_slot_block(cfg, x, blk, k_pages, v_pages, page_table,
             k[:, 0].astype(k_pages.dtype))
         vc = v_pages.at[write_pages, write_offs].set(
             v[:, 0].astype(v_pages.dtype))
-        a = paged_attention(q, kc, vc, page_table, lens)
+        a = paged_attention(q, kc, vc, page_table, lens, mesh=mesh)
         return a, (kc, vc)
 
     x, (k_pages, v_pages) = block_apply(cfg, x, blk, attn_fn=pattn)
@@ -865,13 +861,16 @@ def _paged_slot_block(cfg, x, blk, k_pages, v_pages, page_table,
 
 
 def decode_step_paged(params, tokens, cfg: GPTConfig, cache_k, cache_v,
-                      page_table, write_pages, write_offs, lens):
+                      page_table, write_pages, write_offs, lens, mesh=None):
     """One decode iteration for every slot through the paged pool:
     consume one token per slot (at its own ``lens[s]``), return
     (logits [S, V] fp32, k_pool, v_pool).  Inactive slots point their
     write coordinates at the scratch page and their table rows at
     scratch, so the batch shape stays static and their garbage never
-    lands on a real page — the host advances only active lens."""
+    lands on a real page — the host advances only active lens.
+    ``mesh``: the 'tp' serving mesh of a head-sharded pool, which the
+    paged-attention kernel needs to run per shard (GSPMD cannot split
+    it; ops/pallas/paged_attn.py::_over_heads)."""
     x = jnp.take(params["wte"], tokens, axis=0) \
         + jnp.take(params["wpe"], lens, axis=0)
     x = x[:, None, :].astype(jnp.dtype(cfg.dtype))        # [S, 1, H]
@@ -880,7 +879,7 @@ def decode_step_paged(params, tokens, cfg: GPTConfig, cache_k, cache_v,
         blk, kp, vp = layer
         xx, kp, vp = _paged_slot_block(cfg, carry, blk, kp, vp,
                                        page_table, write_pages,
-                                       write_offs, lens)
+                                       write_offs, lens, mesh)
         return xx, (kp, vp)
 
     x, (ks, vs) = jax.lax.scan(scan_body, x,
@@ -979,7 +978,7 @@ def init_paged_cache_quant(cfg: GPTConfig, num_pages, page_size,
 
 def _paged_slot_block_quant(cfg, x, blk, k_pages, k_scale, v_pages,
                             v_scale, page_table, write_pages, write_offs,
-                            lens):
+                            lens, mesh=None):
     """:func:`_paged_slot_block` over the int8 pool: each slot's new K/V
     quantize on write — int8 bytes into (write_pages[s], write_offs[s]),
     the absmax scale into the scale arrays at the same coordinate — and
@@ -994,7 +993,8 @@ def _paged_slot_block_quant(cfg, x, blk, k_pages, k_scale, v_pages,
         ksc = k_scale.at[write_pages, write_offs].set(ks)
         vc = v_pages.at[write_pages, write_offs].set(vq)
         vsc = v_scale.at[write_pages, write_offs].set(vs)
-        a = paged_attention_quant(q, kc, ksc, vc, vsc, page_table, lens)
+        a = paged_attention_quant(q, kc, ksc, vc, vsc, page_table, lens,
+                                  mesh=mesh)
         return a, (kc, ksc, vc, vsc)
 
     x, (k_pages, k_scale, v_pages, v_scale) = block_apply(
@@ -1004,7 +1004,7 @@ def _paged_slot_block_quant(cfg, x, blk, k_pages, k_scale, v_pages,
 
 def decode_step_paged_quant(params, tokens, cfg: GPTConfig, cache_k,
                             k_scale, cache_v, v_scale, page_table,
-                            write_pages, write_offs, lens):
+                            write_pages, write_offs, lens, mesh=None):
     """One decode iteration for every slot through the INT8 paged pool
     (same contract as :func:`decode_step_paged`; the scale arrays ride
     along as donated operands).  Returns
@@ -1017,7 +1017,7 @@ def decode_step_paged_quant(params, tokens, cfg: GPTConfig, cache_k,
         blk, kp, ksp, vp, vsp = layer
         xx, kp, ksp, vp, vsp = _paged_slot_block_quant(
             cfg, carry, blk, kp, ksp, vp, vsp, page_table, write_pages,
-            write_offs, lens)
+            write_offs, lens, mesh)
         return xx, (kp, ksp, vp, vsp)
 
     x, (ks, kss, vs, vss) = jax.lax.scan(

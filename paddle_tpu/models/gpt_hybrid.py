@@ -31,7 +31,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from ..framework.jax_compat import shard_map
-from ..framework.jax_compat import (named_sharding,
+from ..framework.jax_compat import (enable_persistent_cache, named_sharding,
                                     partition_spec_class)
 
 P = partition_spec_class()
@@ -98,18 +98,29 @@ def init_sharded(cfg: GPTConfig, mesh, key, moment_dtype=jnp.float32):
     """Init params + AdamW moments, placed with their NamedShardings.
     ``moment_dtype=bfloat16`` halves optimizer-state HBM (the update math
     still runs fp32 — see optimizer/functional.adamw_update), which is what
-    lets the 1.3B flagship train on a single 16GB v5e chip."""
-    params = init_params(cfg, key)
-    specs = param_specs(cfg)
+    lets the 1.3B flagship train on a single 16GB v5e chip.
 
-    def place(x, spec):
-        return jax.device_put(x, named_sharding(mesh, spec))
+    The init runs as ONE jitted program whose outputs carry the
+    shardings, so every device materialises only its own shards: an
+    eager ``init_params`` followed by ``device_put`` would first build
+    every full array (and the fp32 draw behind each bf16 leaf) on device
+    0.  The values do not depend on the mesh (jax's threefry is
+    partitionable), which is what lets a 2x2 run be compared with a
+    one-chip run from the same key."""
+    shardings = jax.tree_util.tree_map(
+        lambda s: named_sharding(mesh, s), param_specs(cfg),
+        is_leaf=lambda s: isinstance(s, P))
 
-    params = jax.tree_util.tree_map(place, params, specs)
-    zeros = functools.partial(jax.tree_util.tree_map,
-                              lambda p, s: place(
-                                  jnp.zeros(p.shape, moment_dtype), s))
-    return params, zeros(params, specs), zeros(params, specs)
+    def init(k):
+        params = init_params(cfg, k)
+        zeros = functools.partial(
+            jax.tree_util.tree_map,
+            lambda p: jnp.zeros(p.shape, moment_dtype))
+        # two zero trees, never one aliased: the train step donates
+        # m and v separately
+        return params, zeros(params), zeros(params)
+
+    return jax.jit(init, out_shardings=(shardings,) * 3)(key)
 
 
 # --------------------------------------------------------------------------
@@ -328,6 +339,9 @@ def make_train_step(cfg: GPTConfig, mesh, n_microbatch=1,
     (rematerialized) to cap logits activation memory."""
     sp_size, pp_size = _check_mesh(cfg, mesh)
     specs = param_specs(cfg)
+    # a restarted trainer re-loads the step it compiled last time (no-op
+    # unless a cache directory is named from outside — the one rule)
+    enable_persistent_cache()
 
     def step(params, m, v, t, tokens, labels, lr):
         loss, grads = jax.value_and_grad(

@@ -14,20 +14,32 @@ except ImportError:  # pragma: no cover
 
 
 def on_tpu():
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    """Is the default device a TPU?  A backend that fails to start
+    raises here: "no chip" and "the chip did not answer" are different
+    answers, and only the first may select the XLA path."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def pallas_enabled():
     """Master gate for the compiled Pallas paths.  Set
     ``PADDLE_TPU_DISABLE_PALLAS=1`` to force every op to its XLA fallback
-    (bench.py's safety valve: a lowering regression must never crash a
-    training run — it degrades to the fused-XLA path instead)."""
+    (an operator's switch for A/B runs such as tools/bench_sweep.py;
+    nothing in the repo sets it on a kernel failure — a kernel the
+    chip's compiler refuses fails the run)."""
     if os.environ.get("PADDLE_TPU_DISABLE_PALLAS", "") not in ("", "0"):
         return False
     return HAS_PALLAS and on_tpu()
+
+
+def count_paged_kernel():
+    """Trace-time engagement counter of the paged-attention Pallas
+    kernel, fp and int8 pools alike (``serving.paged_kernel_calls``).
+    Like :func:`count_dequant_kernel` it fires once per kernel instance
+    per compiled executable — it answers "did what XLA built contain
+    the kernel?", which is what ``chip_smoke.py`` reads instead of
+    assuming the gate admitted the shape."""
+    from ...observability import metrics
+    metrics.counter("serving.paged_kernel_calls").inc()
 
 
 def count_dequant_kernel(kernel):

@@ -19,8 +19,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _run_py(code, *argv, env_extra=None):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("PADDLE_AOT_CACHE_DIR", None)
-    env.pop("PADDLE_JIT_CACHE_DIR", None)
+    for name in ("PADDLE_AOT_CACHE_DIR", "PADDLE_JIT_CACHE_DIR",
+                 "JAX_COMPILATION_CACHE_DIR"):
+        env.pop(name, None)
     env.update(env_extra or {})
     r = subprocess.run([sys.executable, "-c", code, *map(str, argv)],
                        env=env, cwd=REPO, capture_output=True, text=True,
@@ -290,8 +291,6 @@ class TestArtifactStoreUnits:
         cc.set_artifact_dir(str(tmp_path))
         try:
             assert not cc.artifact_ready("no-such-key")
-            if not cc.aot_available():
-                pytest.skip("jax without serialize_executable")
             import jax
             compiled = jax.jit(lambda x: x + 1).lower(
                 jax.ShapeDtypeStruct((2,), np.float32)).compile()
@@ -314,3 +313,99 @@ class TestArtifactStoreUnits:
             assert not cc.artifact_ready("k1")
         finally:
             cc.set_artifact_dir(None)
+
+
+# --------------------------------------------------------------------------
+# where the persistent cache lives: one rule (jax_compat.resolve_cache_dir)
+# --------------------------------------------------------------------------
+
+_CACHE_RULE_PROBE = """
+import json, sys
+import jax
+set_in_code = []
+_update = jax.config.update
+def spy(name, value):
+    if name == "jax_compilation_cache_dir":
+        set_in_code.append(value)
+    return _update(name, value)
+jax.config.update = spy
+from paddle_tpu.framework import jax_compat
+default = jax_compat.checkout_cache_dir() if sys.argv[1] == "entry" else None
+got = jax_compat.enable_persistent_cache(default)
+print(json.dumps({"returned": got, "set_in_code": set_in_code,
+                  "config": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+@pytest.fixture(scope="module")
+def cache_rule(tmp_path_factory):
+    """Every scenario of the rule, probed once each in its own fresh
+    interpreter (the variables are read when jax is imported) — all at
+    the same time, since tier-1's budget is tight."""
+    from concurrent.futures import ThreadPoolExecutor
+    tmp = tmp_path_factory.mktemp("cache_rule")
+    x, y = str(tmp / "x"), str(tmp / "y")
+    scenarios = {
+        "jax_wins": ("entry", {"JAX_COMPILATION_CACHE_DIR": x,
+                               "PADDLE_JIT_CACHE_DIR": y}),
+        "paddle_second": ("entry", {"PADDLE_JIT_CACHE_DIR": y}),
+        "entry_default": ("entry", {}),
+        "entry_default_again": ("entry", {}),
+        "library": ("library", {}),
+        "import_only": (None, {}),
+    }
+
+    def probe(item):
+        name, (caller, env) = item
+        if caller is None:
+            out = _run_py(
+                "import paddle_tpu, paddle_tpu.inference.serving, "
+                "paddle_tpu.inference.fleet\n"
+                "import jax, json\n"
+                "print(json.dumps(len(jax._src.xla_bridge._backends)))")
+        else:
+            out = _run_py(_CACHE_RULE_PROBE, caller, env_extra=env)
+        return name, json.loads(out.strip().splitlines()[-1])
+
+    with ThreadPoolExecutor(len(scenarios)) as pool:
+        got = dict(pool.map(probe, scenarios.items()))
+    return dict(got, x=x, y=y)
+
+
+class TestCacheDirRule:
+    def test_jax_variable_wins_and_nothing_is_set_in_code(self, cache_rule):
+        """JAX_COMPILATION_CACHE_DIR places the cache from outside: jax
+        read it at import, the program sets no directory — not the
+        entry-point default and not PADDLE_JIT_CACHE_DIR either."""
+        x = cache_rule["x"]
+        assert cache_rule["jax_wins"] == {
+            "returned": x, "set_in_code": [], "config": x}
+
+    def test_paddle_variable_is_second(self, cache_rule):
+        y = cache_rule["y"]
+        assert cache_rule["paddle_second"] == {
+            "returned": y, "set_in_code": [y], "config": y}
+
+    def test_entry_point_default_is_the_fixed_checkout_path(self,
+                                                            cache_rule):
+        """No variable: entry-point scripts land in <checkout>/.jax_cache,
+        the same path in every process (the path is part of the cache
+        key — a temp name, pid or time in it would never hit)."""
+        want = os.path.join(REPO, ".jax_cache")
+        assert (cache_rule["entry_default"]
+                == cache_rule["entry_default_again"]
+                == {"returned": want, "set_in_code": [want],
+                    "config": want})
+
+    def test_library_constructors_start_no_cache(self, cache_rule):
+        """No variable, no default: an engine built under the tests must
+        keep its compile counts, so the library starts nothing."""
+        assert cache_rule["library"] == {
+            "returned": None, "set_in_code": [], "config": None}
+
+
+def test_import_starts_no_backend(cache_rule):
+    """Importing the package — serving engine and fleet router included —
+    must not initialise a jax backend: a parent that has touched jax
+    holds the chip, and a fleet's router process must stay off it."""
+    assert cache_rule["import_only"] == 0

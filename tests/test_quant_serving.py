@@ -84,10 +84,6 @@ class TestQuantizeParams:
     def test_fp8_where_available(self, tiny_model):
         from paddle_tpu.framework import jax_compat
         from paddle_tpu.models import gpt as G
-        if jax_compat.fp8_dtype() is None:
-            with pytest.raises(ValueError, match="fp8"):
-                G.quantize_params(tiny_model[0], "fp8")
-            return
         qp = G.quantize_params(tiny_model[0], "fp8")
         leaf = qp["blocks"]["fc1_w"]
         assert leaf["qw"].dtype == jax_compat.fp8_dtype()

@@ -237,8 +237,6 @@ class TestMeshKeysAndTopology:
         existed) stay valid."""
         import jax
         from paddle_tpu.framework import compile_cache as cc
-        if not cc.aot_available():
-            pytest.skip("no serialize_executable in this jax")
         store = cc.ArtifactStore(str(tmp_path))
         compiled = jax.jit(lambda x: x + 1).lower(1.0).compile()
         store.save("k1", compiled, topology="tp/2/cpu/2")
@@ -293,8 +291,6 @@ class TestMeshKeysAndTopology:
         (pp, tp) grid that built them)."""
         import jax
         from paddle_tpu.framework import compile_cache as cc
-        if not cc.aot_available():
-            pytest.skip("no serialize_executable in this jax")
         store = cc.ArtifactStore(str(tmp_path))
         compiled = jax.jit(lambda x: x + 1).lower(1.0).compile()
         store.save("pp_decode", compiled, topology="pp/2/tp/2/cpu/4")

@@ -20,8 +20,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.framework import jax_compat
 from paddle_tpu.parallel.mesh import create_mesh
 from paddle_tpu.models import gpt, gpt_hybrid
+
+jax_compat.enable_persistent_cache(jax_compat.checkout_cache_dir())
 
 cfg = gpt.GPTConfig(
     vocab_size=50304,
@@ -49,15 +52,16 @@ toks = jnp.asarray(np.random.RandomState(0).randint(
 lr = jnp.float32(1e-4)
 
 params, m, mv, loss = step(params, m, mv, jnp.int32(1), toks, toks, lr)
-float(loss)
+jax.block_until_ready(loss)
 t0 = time.perf_counter()
 for i in range(steps):
     params, m, mv, loss = step(params, m, mv, jnp.int32(i + 2), toks, toks, lr)
-fl = float(loss)
+jax.block_until_ready(loss)
 dt = time.perf_counter() - t0
+fl = float(loss)
 tps = batch * N * steps / dt
-from bench import _peak_flops
-mfu = tps * cfg.flops_per_token() / _peak_flops(dev)
+from bench import _peak_flops_kind
+mfu = tps * cfg.flops_per_token() / _peak_flops_kind(dev.device_kind)
 print(json.dumps({"variant": v, "tokens_per_sec": round(tps, 1),
                   "mfu": round(mfu, 4), "loss": round(fl, 4),
                   "step_ms": round(dt / steps * 1e3, 1)}))

@@ -20,22 +20,18 @@ v = jnp.asarray(rng.randn(B, N, H, D), jnp.bfloat16)
 do = jnp.asarray(rng.randn(B, N, H, D), jnp.bfloat16)
 
 
-def fetch(xs):
-    return float(sum(jnp.sum(jnp.abs(x).astype(jnp.float32)) for x in xs))
-
-
 def timeit(fn, iters=20):
-    fetch(fn(q, k, v, do))
+    jax.block_until_ready(fn(q, k, v, do))
     t0 = time.perf_counter()
     for _ in range(iters):
         out = fn(q, k, v, do)
-    fetch(out)
+    jax.block_until_ready(out)
     return (time.perf_counter() - t0) / iters * 1e3
 
 
 out, lse = jax.jit(lambda q, k, v: fa._flash_attention_tpu(
     q, k, v, True, return_lse=True))(q, k, v)
-fetch([out])
+jax.block_until_ready(out)
 print("lse ready", flush=True)
 
 for bq, bk in [(128, 128), (256, 256), (512, 512), (256, 512), (512, 256)]:

@@ -1,6 +1,7 @@
 #!/usr/bin/env bash
 # Full compile-hygiene static-analysis gate: every PTL rule over the
-# package, the tools and the bench driver (<30s on the CPU container).
+# package, the tools, the bench driver and chip_smoke.py (<30s on the CPU
+# container).
 # A NEW finding (unsuppressed, unbaselined) fails the same way a dirty
 # worktree fails tier-1 — tools/tier1_guard.sh runs this first.
 #
@@ -16,7 +17,7 @@ cd "$(dirname "$0")/.." || exit 2
 start=$(date +%s)
 # ptl_lint.py = the same analyzer CLI standalone-loaded without the
 # paddle_tpu package import, so the gate runs jax-less and in ~1s
-python tools/ptl_lint.py paddle_tpu tools bench.py "$@"
+python tools/ptl_lint.py paddle_tpu tools bench.py chip_smoke.py "$@"
 rc=$?
 elapsed=$(( $(date +%s) - start ))
 if [ "$rc" -eq 1 ]; then
