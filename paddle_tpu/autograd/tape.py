@@ -10,6 +10,7 @@ kernels.  Under ``jit.to_static`` the tape is bypassed entirely and
 """
 from __future__ import annotations
 
+import contextlib
 from typing import List, Optional, Sequence
 
 import jax.numpy as jnp
@@ -216,7 +217,7 @@ def backward(tensor, grad=None, retain_graph: bool = False, watch=()):
     from ..observability import timeline as _timeline
     _span = (_timeline.span("backward")
              if _backward_depth[0] == 1 and not watch
-             else _timeline._NULL)
+             else contextlib.nullcontext())
     try:
         with _span:
             _backward_impl(tensor, grad, retain_graph, watch)

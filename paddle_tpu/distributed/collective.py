@@ -265,8 +265,6 @@ def _kv_allgather(value, op="allgather", bucket=None, group=None):
     timeout_ms = _collective_timeout_ms()
     _kv_seq[0] += 1
     key = f"paddle_tpu_eager_ag_{_kv_seq[0]}"
-    if _faults.active():
-        _faults.collective_entry(op)       # injected straggler/vanish
     payload = base64.b64encode(
         pickle.dumps(np.asarray(value))).decode("ascii")
     _kv_call(client, "key_value_set", f"{key}/{me}", payload)
@@ -316,8 +314,15 @@ def _eager_rows(value, op="allgather", bucket=None, group=None):
     """Host-level cross-process allgather: every live process contributes
     its local value; returns a [process_count, ...] numpy stack."""
     from jax.experimental import multihost_utils
+    if _faults.active():
+        _faults.collective_entry(op)       # injected straggler/vanish
+    # where the backend gathers across processes itself, the rendezvous
+    # happens inside this one call: timed from entry, a straggler (late
+    # to enter) records ~zero and its peers the time they sat waiting —
+    # the same asymmetry _kv_allgather records for the fallback
+    t_wait = time.perf_counter()
     try:
-        return np.asarray(
+        rows = np.asarray(
             multihost_utils.process_allgather(np.asarray(value)))
     except CollectiveTimeout:
         raise
@@ -325,6 +330,8 @@ def _eager_rows(value, op="allgather", bucket=None, group=None):
         # e.g. "Multiprocess computations aren't implemented on the CPU
         # backend" — gather through the coordination service instead
         return _kv_allgather(value, op=op, bucket=bucket, group=group)
+    _timeline.record_collective_wait(time.perf_counter() - t_wait, op=op)
+    return rows
 
 
 def _member_rows(rows, group):

@@ -192,6 +192,11 @@ class Request:
         self.finish_reason = None   # "length" | "eos"
         self.submit_t = time.perf_counter()
         self.finish_t = None
+        # perf_counter() reading per NEW generated position, stamped by
+        # the engine when it appends the token (the moment a streaming
+        # client could be sent it).  Survives reset_for_retry: positions
+        # regenerated after a preemption are not news.
+        self.token_t = []
         # distributed tracing (ISSUE 19): the router mints this at
         # admission and ships it on every RPC hop; engine-side span
         # events carry it so cross-process assembly stitches one
@@ -206,6 +211,11 @@ class Request:
         return np.concatenate([self.prompt,
                                np.asarray(self.tokens, np.int32)])
 
+    @property
+    def first_token_t(self):
+        """When the first generated token was appended (None before)."""
+        return self.token_t[0] if self.token_t else None
+
     def latency(self):
         return (self.finish_t - self.submit_t) if self.done else None
 
@@ -214,7 +224,8 @@ class Request:
         limits) can be re-queued from scratch after a mid-step abort or
         a page-exhaustion preemption — greedy decoding makes the retry
         token-exact with a run that never failed.  ``preemptions``
-        survives on purpose (it is the retry's audit trail)."""
+        survives on purpose (it is the retry's audit trail), and so does
+        ``token_t`` (the client already has those positions)."""
         self.tokens = []
         self.logits = None
         self.slot = None
@@ -384,9 +395,10 @@ class ServingEngine:
         self._g_queue = metrics.gauge("serving.queue_depth")
         self._g_occ = metrics.gauge("serving.slot_occupancy")
         self._g_occ_peak = metrics.gauge("serving.slot_occupancy_peak")
-        self._g_tps = metrics.gauge("serving.tokens_per_s")
         self._h_prefill = metrics.histogram("serving.prefill_s")
         self._h_decode = metrics.histogram("serving.decode_step_s")
+        self._h_ttft = metrics.histogram("serving.ttft_s")
+        self._h_gap = metrics.histogram("serving.token_gap_s")
         # a fleet replica labels its latency series with its replica id
         # (PADDLE_FLEET_REPLICA, set by the router) so per-replica
         # latency joins across the fleet's merged telemetry
@@ -398,7 +410,7 @@ class ServingEngine:
         self._aborted = []          # mid-step abort victims, until taken
         self._admitting = []        # requests inside the current prefill
         self._finished_backlog = []  # finished, not yet handed to a caller
-        self._tok_window = collections.deque(maxlen=64)  # (t, n) samples
+        self._step_idx = 0          # engine iterations, warm-up included
         self._occ_peak = 0
         self._warming = False
 
@@ -717,55 +729,63 @@ class ServingEngine:
                         request_id=req.id, batch=bbucket, seq=sbucket,
                         wait_s=round(
                             time.perf_counter() - req.submit_t, 6))
-            donate = self._donate()
-            operands = (self.params, self._cache_k, self._cache_v,
-                        jnp.asarray(toks), jnp.asarray(lens),
-                        jnp.asarray(slot_ids))
-            fn = self._prefill.get(
-                _cc.make_key(bbucket, sbucket, donate=donate,
-                             mesh=self._mesh_key()),
-                lambda: self._build_prefill(bbucket, sbucket),
-                stable_key=self._aot_key("prefill", b=bbucket, s=sbucket),
-                example_args=operands, topology=self._topology())
-            t0 = time.perf_counter()
-            with timeline.span("serving.prefill", batch=bbucket,
+            with timeline.span("serving.prefill_operands", batch=bbucket,
                                seq=sbucket):
-                out = fn(*operands)
-            if self.capture_logits:
-                self._cache_k, self._cache_v, first_tok, last_logits = out
-                # capture_logits debug mode: the caller asked for host
-                # logits; off by default
-                # ptl: disable-next=PTL004 -- capture_logits debug mode
-                logits_np = np.asarray(last_logits)
-            else:
-                self._cache_k, self._cache_v, first_tok = out
-                logits_np = None
-            self._inc("prefill_calls")
-            self._count_quant_matmuls()
-            # sampled-first-token readback: the one designed sync point
-            # of the prefill wave
-            # ptl: disable-next=PTL004 -- sampled-first-token readback
-            first_np = np.asarray(first_tok)
-            for req in group:
-                r = group_rows[id(req)]
-                s = req.slot
-                self._lens[s] = len(req.prompt)
-                self._active[s] = True
-                self._slot_req[s] = req
-                self._append_token(req, int(first_np[r]),
-                                   logits_np[r] if logits_np is not None
-                                   else None)
-                self._last_tok[s] = int(first_np[r])
-                self._inc("requests_admitted")
-                # not during warmup: the quiet counters don't advance
-                # there, so a step/request-scoped fault would see the
-                # same index forever and fire at boot
-                if _faults.active() and not self._warming:
-                    _faults.replica_kill_check(
-                        request=self._counts["requests_admitted"])
-            self._admitting = []
+                donate = self._donate()
+                operands = (self.params, self._cache_k, self._cache_v,
+                            jnp.asarray(toks), jnp.asarray(lens),
+                            jnp.asarray(slot_ids))
+                fn = self._prefill.get(
+                    _cc.make_key(bbucket, sbucket, donate=donate,
+                                 mesh=self._mesh_key()),
+                    lambda: self._build_prefill(bbucket, sbucket),
+                    stable_key=self._aot_key("prefill", b=bbucket,
+                                             s=sbucket),
+                    example_args=operands, topology=self._topology())
+            # the wave span IS the serving.prefill_s interval: from the
+            # jitted call to the end of the first-token commit loop
+            with timeline.span("serving.prefill_wave", batch=bbucket,
+                               seq=sbucket,
+                               request_ids=[r.id for r in group]) as wave:
+                with timeline.span("serving.prefill_wave.dispatch"):
+                    out = fn(*operands)
+                if self.capture_logits:
+                    (self._cache_k, self._cache_v, first_tok,
+                     last_logits) = out
+                else:
+                    self._cache_k, self._cache_v, first_tok = out
+                self._inc("prefill_calls")
+                self._count_quant_matmuls()
+                with timeline.span("serving.prefill_wave.readback"):
+                    # capture_logits debug mode: the caller asked for
+                    # host logits; off by default
+                    # ptl: disable-next=PTL004 -- capture_logits debug mode
+                    logits_np = (np.asarray(last_logits)
+                                 if self.capture_logits else None)
+                    # sampled-first-token readback: the one designed
+                    # sync point of the prefill wave
+                    # ptl: disable-next=PTL004 -- sampled-first-token readback
+                    first_np = np.asarray(first_tok)
+                for req in group:
+                    r = group_rows[id(req)]
+                    s = req.slot
+                    self._lens[s] = len(req.prompt)
+                    self._active[s] = True
+                    self._slot_req[s] = req
+                    self._append_token(req, int(first_np[r]),
+                                       logits_np[r] if logits_np is not None
+                                       else None)
+                    self._last_tok[s] = int(first_np[r])
+                    self._inc("requests_admitted")
+                    # not during warmup: the quiet counters don't advance
+                    # there, so a step/request-scoped fault would see the
+                    # same index forever and fire at boot
+                    if _faults.active() and not self._warming:
+                        _faults.replica_kill_check(
+                            request=self._counts["requests_admitted"])
+                self._admitting = []
             if not self._warming:
-                self._h_prefill.observe(time.perf_counter() - t0)
+                self._h_prefill.observe(wave.dur)
         self._g_queue.set(self._queued_total())
         occ = int(self._active.sum())
         self._g_occ.set(occ)
@@ -776,6 +796,15 @@ class ServingEngine:
 
     def _append_token(self, req, tok, logits_row):
         req.tokens.append(tok)
+        if not self._warming and len(req.tokens) > len(req.token_t):
+            # one stamp per NEW position: after a preemption the engine
+            # regenerates positions the client already has
+            now = time.perf_counter()
+            if req.token_t:
+                self._h_gap.observe(now - req.token_t[-1])
+            else:
+                self._h_ttft.observe(now - req.submit_t)
+            req.token_t.append(now)
         if self.capture_logits:
             if req.logits is None:
                 req.logits = []
@@ -784,8 +813,6 @@ class ServingEngine:
             # ptl: disable-next=PTL004 -- already-synced host copy
             req.logits.append(np.asarray(logits_row, np.float32))
         self._inc("tokens_generated")
-        if not self._warming:
-            self._tok_window.append((time.perf_counter(), 1))
         if (req.eos_token is not None and tok == req.eos_token):
             self._finish(req, "eos")
         elif len(req.tokens) >= req.max_new_tokens:
@@ -865,8 +892,10 @@ class ServingEngine:
         backlog and come back from the next ``step()`` /
         :meth:`take_finished` — a crash after a completion never
         un-completes it."""
+        self._step_idx += 1
         try:
-            self._step_inner()
+            with timeline.span("serving.step", step=self._step_idx):
+                self._step_inner()
         except Exception as e:
             self._abort_inflight(e)
             raise
@@ -946,7 +975,8 @@ class ServingEngine:
         return None
 
     def _step_inner(self):
-        self._admit()
+        with timeline.span("serving.admit"):
+            self._admit()
         if not self._active.any():
             return
         finished = []        # this decode wave's, for the step event
@@ -955,51 +985,56 @@ class ServingEngine:
             _faults.engine_step_error(self._counts["decode_steps"] + 1)
             _faults.replica_kill_check(
                 step=self._counts["decode_steps"] + 1)
-        operands = (self.params, self._cache_k, self._cache_v,
-                    jnp.asarray(self._lens), jnp.asarray(self._last_tok),
-                    jnp.asarray(self._active))
-        if self._decode_jit is None:
-            donate = self._donate()
-            self._decode_jit = self._decode_site.get(
-                _cc.make_key("decode", donate=donate,
-                             mesh=self._mesh_key()),
-                self._build_decode,
-                stable_key=self._aot_key("decode"),
-                example_args=operands, topology=self._topology())
-            self._inc("decode_compiles")
-        t0 = time.perf_counter()
-        with timeline.span("serving.decode_step",
-                           active=int(self._active.sum())):
-            out = self._decode_jit(*operands)
-        if self.capture_logits:
-            self._cache_k, self._cache_v, nxt, logits = out
-            # ptl: disable-next=PTL004 -- capture_logits debug mode readback
-            logits_np = np.asarray(logits)
-        else:
-            self._cache_k, self._cache_v, nxt = out
-            logits_np = None
-        self._inc("decode_steps")
-        self._count_quant_matmuls()
-        # sampled-token readback: THE designed device->host sync of the
-        # decode loop (tokens must reach clients)
-        # ptl: disable-next=PTL004 -- sampled-token readback
-        nxt_np = np.asarray(nxt)
-        for s in range(self.slots):
-            if not self._active[s]:
-                continue
-            req = self._slot_req[s]
-            self._lens[s] += 1
-            self._append_token(req, int(nxt_np[s]),
-                               logits_np[s] if logits_np is not None
-                               else None)
-            self._last_tok[s] = int(nxt_np[s])
-            if req.done:
-                finished.append(req)
-        dt = time.perf_counter() - t0
+        with timeline.span("serving.decode_operands"):
+            operands = (self.params, self._cache_k, self._cache_v,
+                        jnp.asarray(self._lens),
+                        jnp.asarray(self._last_tok),
+                        jnp.asarray(self._active))
+            if self._decode_jit is None:
+                donate = self._donate()
+                self._decode_jit = self._decode_site.get(
+                    _cc.make_key("decode", donate=donate,
+                                 mesh=self._mesh_key()),
+                    self._build_decode,
+                    stable_key=self._aot_key("decode"),
+                    example_args=operands, topology=self._topology())
+                self._inc("decode_compiles")
+        # the decode span IS the serving.decode_step_s interval: from the
+        # jitted call to the end of the commit loop
+        with timeline.span("serving.decode",
+                           active=int(self._active.sum())) as decode:
+            with timeline.span("serving.decode.dispatch"):
+                out = self._decode_jit(*operands)
+            if self.capture_logits:
+                self._cache_k, self._cache_v, nxt, logits = out
+            else:
+                self._cache_k, self._cache_v, nxt = out
+            self._inc("decode_steps")
+            self._count_quant_matmuls()
+            with timeline.span("serving.decode.readback"):
+                # ptl: disable-next=PTL004 -- capture_logits debug readback
+                logits_np = (np.asarray(logits) if self.capture_logits
+                             else None)
+                # sampled-token readback: THE designed device->host sync
+                # of the decode loop (tokens must reach clients)
+                # ptl: disable-next=PTL004 -- sampled-token readback
+                nxt_np = np.asarray(nxt)
+            with timeline.span("serving.decode.commit"):
+                for s in range(self.slots):
+                    if not self._active[s]:
+                        continue
+                    req = self._slot_req[s]
+                    self._lens[s] += 1
+                    self._append_token(req, int(nxt_np[s]),
+                                       logits_np[s] if logits_np is not None
+                                       else None)
+                    self._last_tok[s] = int(nxt_np[s])
+                    if req.done:
+                        finished.append(req)
+        dt = decode.dur
         if not self._warming:
             self._h_decode.observe(dt)
         self._g_occ.set(int(self._active.sum()))
-        self._update_tps()
         if not self._warming and timeline.telemetry_dir():
             timeline.emit({"event": "serving_step",
                            "active": int(self._active.sum()),
@@ -1018,22 +1053,6 @@ class ServingEngine:
                               request_id=r.id, iters=len(r.tokens),
                               decode_s=round(dt, 6),
                               engine=self._engine_id)
-
-    def _tps_value(self):
-        """Tokens/s over THIS engine's recent-sample window (0.0 until
-        two samples exist)."""
-        if len(self._tok_window) < 2:
-            return 0.0
-        t0 = self._tok_window[0][0]
-        t1 = self._tok_window[-1][0]
-        if t1 <= t0:
-            return 0.0
-        return round(sum(c for _, c in self._tok_window) / (t1 - t0), 3)
-
-    def _update_tps(self):
-        v = self._tps_value()
-        if v:
-            self._g_tps.set(v)
 
     def _queued_total(self):
         """Requests waiting for admission — the one definition the
@@ -1221,9 +1240,6 @@ class ServingEngine:
         out["queue_depth"] = self._queued_total()
         out["slot_occupancy"] = int(self._active.sum())
         out["slot_occupancy_peak"] = self._occ_peak
-        # from the engine-local sample window, NOT the shared gauge — a
-        # coexisting engine's throughput must not show up here
-        out["tokens_per_s"] = self._tps_value()
         # the numeric contract (fleet routing/hello attests on these: a
         # mixed fp32/int8 fleet must never cross-route)
         out["quant"] = self.quant
@@ -1649,7 +1665,10 @@ class PagedServingEngine(ServingEngine):
                     break           # next wave picks it up
                 slot = free[len(group)]
                 try:
-                    table, hits = self._pager.admit(slot, nxt.prompt)
+                    with timeline.span("serving.pager.admit",
+                                       request_id=nxt.id) as sp:
+                        table, hits = self._pager.admit(slot, nxt.prompt)
+                        sp.attrs["hits"] = hits
                 except self._PagesExhausted:
                     exhausted = True
                     break
@@ -1695,52 +1714,58 @@ class PagedServingEngine(ServingEngine):
                     request_id=req.id, batch=bbucket, seq=sbucket,
                     wait_s=round(
                         time.perf_counter() - req.submit_t, 6))
-        donate = self._donate()
-        operands = (self.params, *self._cache_operands(),
-                    jnp.asarray(toks), jnp.asarray(lens),
-                    jnp.asarray(ptab))
-        fn = self._prefill.get(
-            _cc.make_key(bbucket, sbucket, donate=donate,
-                         mesh=self._mesh_key()),
-            lambda: self._build_prefill(bbucket, sbucket),
-            stable_key=self._aot_key("prefill", b=bbucket, s=sbucket),
-            example_args=operands, topology=self._topology())
-        t0 = time.perf_counter()
-        with timeline.span("serving.prefill", batch=bbucket, seq=sbucket,
-                           paged=True):
-            out = fn(*operands)
-        self._set_cache(out[:self._n_cache])
-        first_tok = out[self._n_cache]
-        # ptl: disable-next=PTL004 -- capture_logits debug mode readback
-        logits_np = (np.asarray(out[self._n_cache + 1])
-                     if self.capture_logits else None)
-        self._inc("prefill_calls")
-        self._count_quant_matmuls()
-        # sampled-first-token readback: the one designed sync point of
-        # the paged prefill wave
-        # ptl: disable-next=PTL004 -- sampled-first-token readback
-        first_np = np.asarray(first_tok)
-        for r, req in enumerate(group):
-            s = req.slot
-            self._tables_np[s] = 0
-            self._tables_np[s, :len(tables[r])] = tables[r]
-            self._lens[s] = len(req.prompt)
-            self._active[s] = True
-            self._slot_req[s] = req
-            req._admit_seq = self._next_admit_seq()
-            self._append_token(req, int(first_np[r]),
-                               logits_np[r] if logits_np is not None
-                               else None)
-            self._last_tok[s] = int(first_np[r])
-            self._inc("requests_admitted")
-            self._memo_first_token(req)
-            if _faults.active() and not self._warming:
-                _faults.replica_kill_check(
-                    request=self._counts["requests_admitted"])
-            self._maybe_finish_prefill_only(req)
-        self._admitting = []
+        with timeline.span("serving.prefill_operands", batch=bbucket,
+                           seq=sbucket):
+            donate = self._donate()
+            operands = (self.params, *self._cache_operands(),
+                        jnp.asarray(toks), jnp.asarray(lens),
+                        jnp.asarray(ptab))
+            fn = self._prefill.get(
+                _cc.make_key(bbucket, sbucket, donate=donate,
+                             mesh=self._mesh_key()),
+                lambda: self._build_prefill(bbucket, sbucket),
+                stable_key=self._aot_key("prefill", b=bbucket, s=sbucket),
+                example_args=operands, topology=self._topology())
+        # the wave span IS the serving.prefill_s interval: from the
+        # jitted call to the end of the first-token commit loop
+        with timeline.span("serving.prefill_wave", batch=bbucket,
+                           seq=sbucket, paged=True,
+                           request_ids=[r.id for r in group]) as wave:
+            with timeline.span("serving.prefill_wave.dispatch"):
+                out = fn(*operands)
+            self._set_cache(out[:self._n_cache])
+            first_tok = out[self._n_cache]
+            self._inc("prefill_calls")
+            self._count_quant_matmuls()
+            with timeline.span("serving.prefill_wave.readback"):
+                # ptl: disable-next=PTL004 -- capture_logits debug readback
+                logits_np = (np.asarray(out[self._n_cache + 1])
+                             if self.capture_logits else None)
+                # sampled-first-token readback: the one designed sync
+                # point of the paged prefill wave
+                # ptl: disable-next=PTL004 -- sampled-first-token readback
+                first_np = np.asarray(first_tok)
+            for r, req in enumerate(group):
+                s = req.slot
+                self._tables_np[s] = 0
+                self._tables_np[s, :len(tables[r])] = tables[r]
+                self._lens[s] = len(req.prompt)
+                self._active[s] = True
+                self._slot_req[s] = req
+                req._admit_seq = self._next_admit_seq()
+                self._append_token(req, int(first_np[r]),
+                                   logits_np[r] if logits_np is not None
+                                   else None)
+                self._last_tok[s] = int(first_np[r])
+                self._inc("requests_admitted")
+                self._memo_first_token(req)
+                if _faults.active() and not self._warming:
+                    _faults.replica_kill_check(
+                        request=self._counts["requests_admitted"])
+                self._maybe_finish_prefill_only(req)
+            self._admitting = []
         if not self._warming:
-            self._h_prefill.observe(time.perf_counter() - t0)
+            self._h_prefill.observe(wave.dur)
 
     def _build_prefill(self, b, s):
         """Paged prefill executable: causal forward over the padded
@@ -1799,23 +1824,25 @@ class PagedServingEngine(ServingEngine):
             flat = ptab.reshape(-1)
             fk = filled["k"].reshape(L, b * pr, ps, nh, hd)
             fv = filled["v"].reshape(L, b * pr, ps, nh, hd)
-            if kvq:
-                fkq, fks = gpt.quantize_kv(fk)
-                fvq, fvs = gpt.quantize_kv(fv)
-                cache_k = cache_k.at[:, flat].set(fkq)
-                k_scale = k_scale.at[:, flat].set(fks)
-                cache_v = cache_v.at[:, flat].set(fvq)
-                v_scale = v_scale.at[:, flat].set(fvs)
-                out_cache = (cache_k, k_scale, cache_v, v_scale)
-            else:
-                cache_k = cache_k.at[:, flat].set(fk)
-                cache_v = cache_v.at[:, flat].set(fv)
-                out_cache = (cache_k, cache_v)
-            out_cache = self._constrain_cache(out_cache)
-            idx = jnp.clip(lens - 1, 0, s - 1)
-            last = jnp.take_along_axis(
-                logits, idx[:, None, None], axis=1)[:, 0]      # [b, V]
-            first_tok = jnp.argmax(last, -1).astype(jnp.int32)
+            with jax.named_scope("kv_scatter"):
+                if kvq:
+                    fkq, fks = gpt.quantize_kv(fk)
+                    fvq, fvs = gpt.quantize_kv(fv)
+                    cache_k = cache_k.at[:, flat].set(fkq)
+                    k_scale = k_scale.at[:, flat].set(fks)
+                    cache_v = cache_v.at[:, flat].set(fvq)
+                    v_scale = v_scale.at[:, flat].set(fvs)
+                    out_cache = (cache_k, k_scale, cache_v, v_scale)
+                else:
+                    cache_k = cache_k.at[:, flat].set(fk)
+                    cache_v = cache_v.at[:, flat].set(fv)
+                    out_cache = (cache_k, cache_v)
+                out_cache = self._constrain_cache(out_cache)
+            with jax.named_scope("head_sample"):
+                idx = jnp.clip(lens - 1, 0, s - 1)
+                last = jnp.take_along_axis(
+                    logits, idx[:, None, None], axis=1)[:, 0]  # [b, V]
+                first_tok = jnp.argmax(last, -1).astype(jnp.int32)
             if cap:
                 return (*out_cache, first_tok, last)
             return (*out_cache, first_tok)
@@ -2486,7 +2513,8 @@ class PagedServingEngine(ServingEngine):
 
     # ------------------------------------------------------------- driving
     def _step_inner(self):
-        self._admit()
+        with timeline.span("serving.admit"):
+            self._admit()
         self._advance_chunks()
         if not self._active.any():
             return
@@ -2503,53 +2531,58 @@ class PagedServingEngine(ServingEngine):
         if not self._active.any():
             return                  # the injected preemption emptied it
         finished = []
-        wpages, woffs = self._ensure_decode_pages()
+        with timeline.span("serving.pager.ensure"):
+            wpages, woffs = self._ensure_decode_pages()
         if not self._active.any():
             return
-        operands = (self.params, *self._cache_operands(),
-                    jnp.asarray(self._tables_np), jnp.asarray(wpages),
-                    jnp.asarray(woffs), jnp.asarray(self._lens),
-                    jnp.asarray(self._last_tok))
-        if self._decode_jit is None:
-            donate = self._donate()
-            self._decode_jit = self._decode_site.get(
-                _cc.make_key("decode", donate=donate,
-                             mesh=self._mesh_key()),
-                self._build_decode,
-                stable_key=self._aot_key("decode"),
-                example_args=operands, topology=self._topology())
-            self._inc("decode_compiles")
-        t0 = time.perf_counter()
-        with timeline.span("serving.decode_step",
-                           active=int(self._active.sum()), paged=True):
-            out = self._decode_jit(*operands)
-        self._set_cache(out[:self._n_cache])
-        nxt = out[self._n_cache]
-        # ptl: disable-next=PTL004 -- capture_logits debug mode readback
-        logits_np = (np.asarray(out[self._n_cache + 1])
-                     if self.capture_logits else None)
-        self._inc("decode_steps")
-        self._count_quant_matmuls()
-        # sampled-token readback: THE designed device->host sync of the
-        # paged decode loop
-        # ptl: disable-next=PTL004 -- sampled-token readback
-        nxt_np = np.asarray(nxt)
-        for s in range(self.slots):
-            if not self._active[s]:
-                continue
-            req = self._slot_req[s]
-            self._lens[s] += 1
-            self._append_token(req, int(nxt_np[s]),
-                               logits_np[s] if logits_np is not None
-                               else None)
-            self._last_tok[s] = int(nxt_np[s])
-            if req.done:
-                finished.append(req)
-        dt = time.perf_counter() - t0
+        with timeline.span("serving.decode_operands"):
+            operands = (self.params, *self._cache_operands(),
+                        jnp.asarray(self._tables_np), jnp.asarray(wpages),
+                        jnp.asarray(woffs), jnp.asarray(self._lens),
+                        jnp.asarray(self._last_tok))
+            if self._decode_jit is None:
+                donate = self._donate()
+                self._decode_jit = self._decode_site.get(
+                    _cc.make_key("decode", donate=donate,
+                                 mesh=self._mesh_key()),
+                    self._build_decode,
+                    stable_key=self._aot_key("decode"),
+                    example_args=operands, topology=self._topology())
+                self._inc("decode_compiles")
+        # the decode span IS the serving.decode_step_s interval: from the
+        # jitted call to the end of the commit loop
+        with timeline.span("serving.decode", active=int(self._active.sum()),
+                           paged=True) as decode:
+            with timeline.span("serving.decode.dispatch"):
+                out = self._decode_jit(*operands)
+            self._set_cache(out[:self._n_cache])
+            nxt = out[self._n_cache]
+            self._inc("decode_steps")
+            self._count_quant_matmuls()
+            with timeline.span("serving.decode.readback"):
+                # ptl: disable-next=PTL004 -- capture_logits debug readback
+                logits_np = (np.asarray(out[self._n_cache + 1])
+                             if self.capture_logits else None)
+                # sampled-token readback: THE designed device->host sync
+                # of the paged decode loop
+                # ptl: disable-next=PTL004 -- sampled-token readback
+                nxt_np = np.asarray(nxt)
+            with timeline.span("serving.decode.commit"):
+                for s in range(self.slots):
+                    if not self._active[s]:
+                        continue
+                    req = self._slot_req[s]
+                    self._lens[s] += 1
+                    self._append_token(req, int(nxt_np[s]),
+                                       logits_np[s] if logits_np is not None
+                                       else None)
+                    self._last_tok[s] = int(nxt_np[s])
+                    if req.done:
+                        finished.append(req)
+        dt = decode.dur
         if not self._warming:
             self._h_decode.observe(dt)
         self._g_occ.set(int(self._active.sum()))
-        self._update_tps()
         if not self._warming and timeline.telemetry_dir():
             timeline.emit({"event": "serving_step",
                            "active": int(self._active.sum()),
@@ -2611,7 +2644,8 @@ class PagedServingEngine(ServingEngine):
                     else gpt.decode_step_paged)
             logits, *cache = step(params, toks, cfg, *cache, page_table,
                                   wpages, woffs, lens, mesh=self._mesh)
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            with jax.named_scope("head_sample"):
+                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
             cache = self._constrain_cache(cache)
             if cap:
                 return (*cache, nxt, logits)

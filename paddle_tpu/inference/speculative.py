@@ -67,7 +67,6 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import time
 
 import numpy as np
 
@@ -508,7 +507,8 @@ class SpeculativeServingEngine(PagedServingEngine):
 
     # ------------------------------------------------------------ driving
     def _step_inner(self):
-        self._admit()
+        with timeline.span("serving.admit"):
+            self._admit()
         self._advance_chunks()
         if not self._active.any():
             return
@@ -562,57 +562,57 @@ class SpeculativeServingEngine(PagedServingEngine):
                 example_args=operands, topology=self._topology())
             self._inc("decode_compiles")
         finished = []
-        t0 = time.perf_counter()
-        with timeline.span("serving.decode_step",
+        # the decode span IS the serving.decode_step_s interval: from the
+        # jitted call to the end of the commit loop
+        with timeline.span("serving.decode",
                            active=int(self._active.sum()), paged=True,
-                           spec=self._spec_mode_val):
+                           spec=self._spec_mode_val) as decode:
             out = self._decode_jit(*operands)
-        self._set_cache(out[:self._n_cache])
-        # ptl: disable-next=PTL004 -- capture_logits debug mode readback
-        logits_np = (np.asarray(out[self._n_cache + 2])
-                     if self.capture_logits else None)
-        self._inc("decode_steps")
-        self._count_quant_matmuls()
-        # committed-token readback: THE designed device->host sync of
-        # the speculative decode loop (same role as the non-spec
-        # engine's sampled-token fetch, amortized over the whole window)
-        # ptl: disable-next=PTL004 -- committed-token readback
-        out_np = np.asarray(out[self._n_cache])
-        # ptl: disable-next=PTL004 -- committed-count readback
-        ncom_np = np.asarray(out[self._n_cache + 1])
-        committed, rows = 0, 0
-        for s in range(self.slots):
-            if not self._active[s]:
-                continue
-            req = self._slot_req[s]
-            nc = int(ncom_np[s])
-            rows += 1
-            toks_row = [int(t) for t in out_np[s, :nc]]
-            self._lens[s] += nc
-            self._append_tokens(req, toks_row,
-                                logits_np[s] if logits_np is not None
-                                else None)
-            self._last_tok[s] = toks_row[-1]
-            committed += nc
-            self._inc("drafted_tokens", k)
-            self._inc("accepted_tokens", nc - 1)
-            self._inc("rejected_tokens", k - (nc - 1))
-            if self._spec_mode_val == "draft" and not req.done:
-                req.pending_draft = toks_row
-            if req.done:
-                finished.append(req)
-        self._inc("spec_steps")
-        if not self._warming:
-            self._commit_sum += committed
-            self._rowstep_sum += rows
-            if self._rowstep_sum:
-                self._g_accept.set(round(
-                    self._commit_sum / self._rowstep_sum, 4))
-        dt = time.perf_counter() - t0
+            self._set_cache(out[:self._n_cache])
+            # ptl: disable-next=PTL004 -- capture_logits debug mode readback
+            logits_np = (np.asarray(out[self._n_cache + 2])
+                         if self.capture_logits else None)
+            self._inc("decode_steps")
+            self._count_quant_matmuls()
+            # committed-token readback: THE designed device->host sync of
+            # the speculative decode loop (same role as the non-spec
+            # engine's sampled-token fetch, amortized over the whole window)
+            # ptl: disable-next=PTL004 -- committed-token readback
+            out_np = np.asarray(out[self._n_cache])
+            # ptl: disable-next=PTL004 -- committed-count readback
+            ncom_np = np.asarray(out[self._n_cache + 1])
+            committed, rows = 0, 0
+            for s in range(self.slots):
+                if not self._active[s]:
+                    continue
+                req = self._slot_req[s]
+                nc = int(ncom_np[s])
+                rows += 1
+                toks_row = [int(t) for t in out_np[s, :nc]]
+                self._lens[s] += nc
+                self._append_tokens(req, toks_row,
+                                    logits_np[s] if logits_np is not None
+                                    else None)
+                self._last_tok[s] = toks_row[-1]
+                committed += nc
+                self._inc("drafted_tokens", k)
+                self._inc("accepted_tokens", nc - 1)
+                self._inc("rejected_tokens", k - (nc - 1))
+                if self._spec_mode_val == "draft" and not req.done:
+                    req.pending_draft = toks_row
+                if req.done:
+                    finished.append(req)
+            self._inc("spec_steps")
+            if not self._warming:
+                self._commit_sum += committed
+                self._rowstep_sum += rows
+                if self._rowstep_sum:
+                    self._g_accept.set(round(
+                        self._commit_sum / self._rowstep_sum, 4))
+        dt = decode.dur
         if not self._warming:
             self._h_decode.observe(dt)
         self._g_occ.set(int(self._active.sum()))
-        self._update_tps()
         if not self._warming and timeline.telemetry_dir():
             timeline.emit({"event": "serving_step",
                            "active": int(self._active.sum()),

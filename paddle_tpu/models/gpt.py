@@ -406,14 +406,17 @@ def _pallas_layer_norm(x, g, b, eps):
 def _attention(q, k, v, cfg):
     # q,k,v: [B, N, nh, hd]
     if cfg.use_flash:
-        return flash_attention(q, k, v, True)
-    d = q.shape[-1]
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(d)
-    n = logits.shape[-1]
-    mask = jnp.tril(jnp.ones((n, n), bool))
-    logits = jnp.where(mask, logits, -1e30)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+        with jax.named_scope("flash_attn"):
+            return flash_attention(q, k, v, True)
+    with jax.named_scope("attn"):
+        d = q.shape[-1]
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(d)
+        n = logits.shape[-1]
+        mask = jnp.tril(jnp.ones((n, n), bool))
+        logits = jnp.where(mask, logits, -1e30)
+        probs = jax.nn.softmax(logits.astype(jnp.float32),
+                               -1).astype(q.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
 def _moe_ffn(cfg: GPTConfig, x, blk):
@@ -472,30 +475,43 @@ def block_apply(cfg: GPTConfig, x, blk, attn_fn=None):
     the attention inner loop (KV-cache decode passes one; default is the
     training causal attention, aux=None).  The hybrid-parallel path has its
     own tp-sharded block (models/gpt_hybrid.py::_sharded_block) — keep the
-    math in sync."""
+    math in sync.
+
+    The ``jax.named_scope``s (``ln_qkv``, the attention's own, ``attn_out``,
+    ``ffn``) are metadata: they name each op's ``op_name`` path in the
+    compiled program and the profiler's trace, and add no operation."""
     cd = jnp.dtype(cfg.dtype)
     B, N, H = x.shape
     nh, hd = cfg.num_heads, cfg.head_dim
 
     ln = _pallas_layer_norm if cfg.use_pallas_norm else _layer_norm
-    h = ln(x, blk["ln1_g"], blk["ln1_b"], cfg.layer_norm_eps)
-    if _is_qweight(blk["qkv_w"]):
-        qkv = _q_matmul(h, blk["qkv_w"], cd)
-    else:
-        qkv = jnp.einsum("bnh,hcd->bncd", h, blk["qkv_w"].astype(cd))
-    qkv = qkv + blk["qkv_b"].astype(cd)
-    q, k, v = [qkv[:, :, i].reshape(B, N, nh, hd) for i in range(3)]
+    with jax.named_scope("ln_qkv"):
+        h = ln(x, blk["ln1_g"], blk["ln1_b"], cfg.layer_norm_eps)
+        if _is_qweight(blk["qkv_w"]):
+            qkv = _q_matmul(h, blk["qkv_w"], cd)
+        else:
+            qkv = jnp.einsum("bnh,hcd->bncd", h, blk["qkv_w"].astype(cd))
+        qkv = qkv + blk["qkv_b"].astype(cd)
+        q, k, v = [qkv[:, :, i].reshape(B, N, nh, hd) for i in range(3)]
     if attn_fn is None:
         a, aux = _attention(q, k, v, cfg), None
     else:
         a, aux = attn_fn(q, k, v)
-    a = a.reshape(B, N, -1)
-    if _is_qweight(blk["proj_w"]):
-        a = _q_matmul(a, blk["proj_w"], cd) + blk["proj_b"].astype(cd)
-    else:
-        a = a @ blk["proj_w"].astype(cd) + blk["proj_b"].astype(cd)
-    x = x + a
+    with jax.named_scope("attn_out"):
+        a = a.reshape(B, N, -1)
+        if _is_qweight(blk["proj_w"]):
+            a = _q_matmul(a, blk["proj_w"], cd) + blk["proj_b"].astype(cd)
+        else:
+            a = a @ blk["proj_w"].astype(cd) + blk["proj_b"].astype(cd)
+        x = x + a
+    with jax.named_scope("ffn"):
+        x = x + _ffn(cfg, ln, x, blk, cd)
+    return x if attn_fn is None else (x, aux)
 
+
+def _ffn(cfg, ln, x, blk, cd):
+    """ln2 and the block's feed-forward half (the residual is the
+    caller's)."""
     h = ln(x, blk["ln2_g"], blk["ln2_b"], cfg.layer_norm_eps)
     if "moe_w1" in blk:
         h = _moe_ffn(cfg, h, blk)
@@ -513,17 +529,17 @@ def block_apply(cfg: GPTConfig, x, blk, attn_fn=None):
         h = jax.nn.gelu(h @ blk["fc1_w"].astype(cd)
                         + blk["fc1_b"].astype(cd), approximate=True)
         h = h @ blk["fc2_w"].astype(cd) + blk["fc2_b"].astype(cd)
-    x = x + h
-    return x if attn_fn is None else (x, aux)
+    return h
 
 
 def embed(cfg: GPTConfig, params, tokens, pos_offset=0):
     cd = jnp.dtype(cfg.dtype)
     N = tokens.shape[-1]
-    pos = pos_offset + jnp.arange(N)
-    x = jnp.take(params["wte"], tokens, axis=0) + jnp.take(
-        params["wpe"], pos, axis=0)
-    return x.astype(cd)
+    with jax.named_scope("embed"):
+        pos = pos_offset + jnp.arange(N)
+        x = jnp.take(params["wte"], tokens, axis=0) + jnp.take(
+            params["wpe"], pos, axis=0)
+        return x.astype(cd)
 
 
 def forward(params, tokens, cfg: GPTConfig):
@@ -569,20 +585,22 @@ def _cached_block(cfg, x, blk, k_cache, v_cache, cur_len):
 
     def cached_attn(q, k, v):
         T = q.shape[1]
-        kc = jax.lax.dynamic_update_slice(
-            k_cache, k.astype(k_cache.dtype), (0, cur_len, 0, 0))
-        vc = jax.lax.dynamic_update_slice(
-            v_cache, v.astype(v_cache.dtype), (0, cur_len, 0, 0))
+        with jax.named_scope("kv_write"):
+            kc = jax.lax.dynamic_update_slice(
+                k_cache, k.astype(k_cache.dtype), (0, cur_len, 0, 0))
+            vc = jax.lax.dynamic_update_slice(
+                v_cache, v.astype(v_cache.dtype), (0, cur_len, 0, 0))
         # attend over the whole cache buffer, masking beyond cur_len+T and
         # the causal future (query i at absolute position cur_len+i)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                            kc.astype(jnp.float32)) / math.sqrt(hd)
-        q_pos = cur_len + jnp.arange(T)[:, None]      # [T,1]
-        k_pos = jnp.arange(max_len)[None, :]          # [1,max_len]
-        mask = k_pos <= q_pos                         # causal + fill bound
-        logits = jnp.where(mask[None, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, -1).astype(cd)
-        a = jnp.einsum("bhqk,bkhd->bqhd", probs, vc.astype(cd))
+        with jax.named_scope("attn"):
+            logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                                kc.astype(jnp.float32)) / math.sqrt(hd)
+            q_pos = cur_len + jnp.arange(T)[:, None]      # [T,1]
+            k_pos = jnp.arange(max_len)[None, :]          # [1,max_len]
+            mask = k_pos <= q_pos                     # causal + fill bound
+            logits = jnp.where(mask[None, None], logits, -1e30)
+            probs = jax.nn.softmax(logits, -1).astype(cd)
+            a = jnp.einsum("bhqk,bkhd->bqhd", probs, vc.astype(cd))
         return a, (kc, vc)
 
     x, (k_cache, v_cache) = block_apply(cfg, x, blk, attn_fn=cached_attn)
@@ -605,13 +623,16 @@ def forward_cached(params, tokens, cfg: GPTConfig, cache):
     def scan_body(carry, layer):
         xx = carry
         blk, kc, vc = layer
-        xx, kc, vc = _cached_block(cfg, xx, blk, kc, vc, cur)
+        with jax.named_scope("layer"):
+            xx, kc, vc = _cached_block(cfg, xx, blk, kc, vc, cur)
         return xx, (kc, vc)
 
     x, (ks, vs) = jax.lax.scan(scan_body, x,
                                (params["blocks"], cache["k"], cache["v"]))
-    x = _layer_norm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
-    logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
+    with jax.named_scope("head_sample"):
+        x = _layer_norm(x, params["lnf_g"], params["lnf_b"],
+                        cfg.layer_norm_eps)
+        logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
     return logits, {"k": ks, "v": vs, "len": cur + tokens.shape[1]}
 
 
@@ -849,11 +870,13 @@ def _paged_slot_block(cfg, x, blk, k_pages, v_pages, page_table,
     from ..ops.pallas.paged_attn import paged_attention
 
     def pattn(q, k, v):
-        kc = k_pages.at[write_pages, write_offs].set(
-            k[:, 0].astype(k_pages.dtype))
-        vc = v_pages.at[write_pages, write_offs].set(
-            v[:, 0].astype(v_pages.dtype))
-        a = paged_attention(q, kc, vc, page_table, lens, mesh=mesh)
+        with jax.named_scope("kv_write"):
+            kc = k_pages.at[write_pages, write_offs].set(
+                k[:, 0].astype(k_pages.dtype))
+            vc = v_pages.at[write_pages, write_offs].set(
+                v[:, 0].astype(v_pages.dtype))
+        with jax.named_scope("paged_attn"):
+            a = paged_attention(q, kc, vc, page_table, lens, mesh=mesh)
         return a, (kc, vc)
 
     x, (k_pages, v_pages) = block_apply(cfg, x, blk, attn_fn=pattn)
@@ -871,22 +894,26 @@ def decode_step_paged(params, tokens, cfg: GPTConfig, cache_k, cache_v,
     ``mesh``: the 'tp' serving mesh of a head-sharded pool, which the
     paged-attention kernel needs to run per shard (GSPMD cannot split
     it; ops/pallas/paged_attn.py::_over_heads)."""
-    x = jnp.take(params["wte"], tokens, axis=0) \
-        + jnp.take(params["wpe"], lens, axis=0)
-    x = x[:, None, :].astype(jnp.dtype(cfg.dtype))        # [S, 1, H]
+    with jax.named_scope("embed"):
+        x = jnp.take(params["wte"], tokens, axis=0) \
+            + jnp.take(params["wpe"], lens, axis=0)
+        x = x[:, None, :].astype(jnp.dtype(cfg.dtype))    # [S, 1, H]
 
     def scan_body(carry, layer):
         blk, kp, vp = layer
-        xx, kp, vp = _paged_slot_block(cfg, carry, blk, kp, vp,
-                                       page_table, write_pages,
-                                       write_offs, lens, mesh)
+        with jax.named_scope("layer"):
+            xx, kp, vp = _paged_slot_block(cfg, carry, blk, kp, vp,
+                                           page_table, write_pages,
+                                           write_offs, lens, mesh)
         return xx, (kp, vp)
 
     x, (ks, vs) = jax.lax.scan(scan_body, x,
                                (params["blocks"], cache_k, cache_v))
-    x = _layer_norm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
-    logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
-    return logits[:, 0], ks, vs
+    with jax.named_scope("head_sample"):
+        x = _layer_norm(x, params["lnf_g"], params["lnf_b"],
+                        cfg.layer_norm_eps)
+        logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
+        return logits[:, 0], ks, vs
 
 
 def forward_paged_chunk(params, tokens, cfg: GPTConfig, cache_k, cache_v,

@@ -343,16 +343,24 @@ def make_train_step(cfg: GPTConfig, mesh, n_microbatch=1,
     # unless a cache directory is named from outside — the one rule)
     enable_persistent_cache()
 
+    def fwd_loss(p, tokens, labels):
+        # metadata only: forward ops read jvp(forward)/..., and autodiff
+        # names their transposes transpose(jvp(forward))/... — that IS
+        # the backward (and the remat's recomputed forward inside it)
+        with jax.named_scope("forward"):
+            return _fwd_loss(cfg, sp_size, pp_size, n_microbatch,
+                             p, tokens, labels, xent_chunks=xent_chunks)
+
     def step(params, m, v, t, tokens, labels, lr):
         loss, grads = jax.value_and_grad(
-            lambda p: _fwd_loss(cfg, sp_size, pp_size, n_microbatch,
-                                p, tokens, labels,
-                                xent_chunks=xent_chunks))(params)
-        grads = _sync_grads(grads, specs, mesh.size)
-        if clip_norm:
-            gn = _global_norm(grads, specs)
-            scale = jnp.minimum(1.0, clip_norm / jnp.maximum(gn, 1e-12))
-            grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
+            lambda p: fwd_loss(p, tokens, labels))(params)
+        with jax.named_scope("grad_sync_clip"):
+            grads = _sync_grads(grads, specs, mesh.size)
+            if clip_norm:
+                gn = _global_norm(grads, specs)
+                scale = jnp.minimum(1.0,
+                                    clip_norm / jnp.maximum(gn, 1e-12))
+                grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
         tf = t.astype(jnp.float32)
 
         def upd(path, p, g, mm, vv):
@@ -360,7 +368,8 @@ def make_train_step(cfg: GPTConfig, mesh, n_microbatch=1,
             decay = leaf not in NO_DECAY and leaf not in LN_NAMES
             return adamw_update(p, g, mm, vv, lr, tf, beta1, beta2, eps,
                                 weight_decay, decay)
-        out = jax.tree_util.tree_map_with_path(upd, params, grads, m, v)
+        with jax.named_scope("adamw"):
+            out = jax.tree_util.tree_map_with_path(upd, params, grads, m, v)
         new_p = jax.tree_util.tree_map(lambda o: o[0], out,
                                        is_leaf=lambda o: isinstance(o, tuple))
         new_m = jax.tree_util.tree_map(lambda o: o[1], out,

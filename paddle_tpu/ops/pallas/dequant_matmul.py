@@ -70,6 +70,7 @@ def _dqmm_tpu(x2d, w_q, s, block_m, block_n, interpret):
         # every (m, n) tile is independent: no cross-step accumulator
         compiler_params=_compiler_params(
             pltpu, dimension_semantics=("parallel", "parallel")),
+        name="dequant_matmul",
         interpret=interpret,
     )(x2d, w_q, s.reshape(1, N))
 

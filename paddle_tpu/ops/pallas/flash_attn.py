@@ -213,6 +213,7 @@ def _flash_attention_tpu(q, k, v, causal, block_q=None, block_k=None,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         compiler_params=_COMPILER_PARAMS,
+        name="flash_attn_fwd",
         interpret=interpret,
     )(qh, kh, vh)
     out = jnp.swapaxes(out[:, :, :N], 1, 2)
@@ -461,6 +462,7 @@ def _flash_attention_bwd_tpu(q, k, v, out, lse, do, causal,
             scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                             pltpu.VMEM((block_k, D), jnp.float32)],
             compiler_params=_COMPILER_PARAMS,
+            name="flash_attn_bwd_fused",
             interpret=interpret,
         )(qh, kh, vh, doh, lse, delta)
         dq = jnp.sum(dqp, axis=2).astype(q.dtype)   # reduce K partials
@@ -488,6 +490,7 @@ def _flash_attention_bwd_tpu(q, k, v, out, lse, do, causal,
         out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
+        name="flash_attn_bwd_dq",
         interpret=interpret,
     )(qh, kh, vh, doh, lse, delta)
 
@@ -513,6 +516,7 @@ def _flash_attention_bwd_tpu(q, k, v, out, lse, do, causal,
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
+        name="flash_attn_bwd_dkv",
         interpret=interpret,
     )(qh, kh, vh, doh, lse, delta)
 

@@ -78,6 +78,7 @@ def _fused_ffn_tpu(x2d, w1, b1, w2, b2, block_m, block_f, interpret):
         # row blocks are independent; only the f (accumulator) axis carries
         compiler_params=_compiler_params(pltpu, 
             dimension_semantics=("parallel", "arbitrary")),
+        name="fused_ffn",
         interpret=interpret,
     )(x2d, w1, b1.reshape(1, F), w2, b2.reshape(1, H))
 

@@ -75,7 +75,7 @@ def _tileable(rows, H, dtype):
     return H % 128 == 0 and _rows_block(rows, dtype) is not None
 
 
-def _pallas_norm(kernel, out_dtype, x2d, *scale_args, interpret):
+def _pallas_norm(kernel, out_dtype, x2d, *scale_args, interpret, name=None):
     rows, H = x2d.shape
     br = _rows_block(rows, x2d.dtype)
     grid = (pl.cdiv(rows, br),)
@@ -91,6 +91,7 @@ def _pallas_norm(kernel, out_dtype, x2d, *scale_args, interpret):
         # every row block is independent — let Mosaic pipeline them
         compiler_params=_compiler_params(pltpu, 
             dimension_semantics=("parallel",)),
+        name=name,
         interpret=interpret,
     )(x2d, *scale_args)
 
@@ -105,7 +106,8 @@ def layer_norm(x, g, b, eps=1e-5, interpret=False):
     if not use:
         return _ref_layer_norm(x, g, b, eps)
     out = _pallas_norm(functools.partial(_ln_kernel, eps=eps), x.dtype,
-                       x.reshape(rows, H), g, b, interpret=interpret)
+                       x.reshape(rows, H), g, b, interpret=interpret,
+                       name="layer_norm")
     return out.reshape(x.shape)
 
 
@@ -133,7 +135,8 @@ def rms_norm(x, g, eps=1e-6, interpret=False):
     if not use:
         return _ref_rms_norm(x, g, eps)
     out = _pallas_norm(functools.partial(_rms_kernel, eps=eps), x.dtype,
-                       x.reshape(rows, H), g, interpret=interpret)
+                       x.reshape(rows, H), g, interpret=interpret,
+                       name="rms_norm")
     return out.reshape(x.shape)
 
 

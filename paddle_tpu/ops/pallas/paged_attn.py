@@ -172,6 +172,7 @@ def _paged_call(q, pages, page_table, lens, interpret):
                           quant=len(pages) == 4),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, nh, hd), q.dtype),
+        name="paged_attn_decode",
         interpret=interpret,
     )(pt_flat, lens32, qs, *pages)
     return out[:, None]

@@ -141,3 +141,22 @@ def test_layer_norm(chip, rows=8192, h=2048):
         bf16, interpret=False)
     assert compiles(fn, chip((rows, h), bf16), chip((h,), bf16),
                     chip((h,), bf16)) == 1
+
+
+def test_kernel_names_and_scopes_reach_the_compiled_program(chip):
+    """A ``pl.pallas_call``'s ``name=`` becomes the HLO instruction's
+    name and, with the ``jax.named_scope``s around it, its ``op_name``:
+    what a profiler trace of the chip shows for the kernel."""
+    from paddle_tpu.ops.pallas.paged_attn import _paged_attention_tpu
+
+    def layer(q, k, v, table, lens):
+        with jax.named_scope("layer"), jax.named_scope("paged_attn"):
+            return _paged_attention_tpu(q, k, v, table, lens)
+
+    pool = chip((PAGES, 16, 32, 64), bf16)
+    text = jax.jit(layer).lower(
+        chip((SLOTS, 1, 32, 64), bf16), pool, pool, chip((SLOTS, MAXP), i32),
+        chip((SLOTS,), i32)).compile().as_text()
+    (call,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert call.lstrip().startswith("%paged_attn_decode")
+    assert 'op_name="jit(layer)/layer/paged_attn/paged_attn_decode' in call

@@ -349,7 +349,6 @@ def test_engine_stats_are_per_engine(tiny_model):
     sa, sb = a.stats(), b.stats()
     assert sa["requests_completed"] == 0 and sa["tokens_generated"] == 0
     assert sa["decode_compiles"] == 0 and sa["prefill_compiles"] == 0
-    assert sa["tokens_per_s"] == 0.0       # B's throughput is not A's
     assert sb["requests_completed"] == 1 and sb["tokens_generated"] == 3
 
 

@@ -225,8 +225,18 @@ class TestStepTimerAndSpans:
         assert inner and outer
         assert inner[0]["depth"] == outer[0]["depth"] + 1
 
-    def test_span_is_noop_when_inactive(self):
-        assert timeline.span("anything") is timeline._NULL
+    def test_span_is_noop_when_inactive(self, tmp_path, monkeypatch):
+        """With no sink a span writes no file and still lands in the
+        ring."""
+        monkeypatch.chdir(tmp_path)
+        timeline.reset_spans()
+        with timeline.span("anything", k=1) as sp:
+            pass
+        (rec,) = timeline.spans()
+        assert rec == (sp.id, None, "anything", sp.t0, sp.t1, {"k": 1})
+        assert sp.t1 >= sp.t0 and sp.dur == sp.t1 - sp.t0
+        assert list(tmp_path.iterdir()) == []
+        assert timeline.telemetry_dir() is None
 
     def test_event_log_rotates_at_cap(self, tmp_path, monkeypatch):
         timeline.configure(str(tmp_path))
