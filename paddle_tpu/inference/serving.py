@@ -61,6 +61,42 @@ DEFAULT_BATCH_BUCKETS = (1, 2, 4)
 _ENGINE_IDS = itertools.count()
 
 
+def pool_relayouts(hlo_text, pools):
+    """What a compiled serving program (``compiled.as_text()``) does to
+    the paged KV pool beyond reading and writing it in place, as a list
+    of findings: a page pool that does not enter major-to-minor, and
+    every instruction named ``copy`` / ``transpose`` (fusions of them
+    included) over a shape that holds the pool's page count and at
+    least one LAYER of pages' elements.  ``pools``: the program's pool
+    operands (arrays or shapes, the engine's ``_cache_operands()``).
+    An int8 pool's scale rows ([.., nh] minor, which the device stores
+    page-minor: PERF.md section 7) are still relaid out, a layer's slice
+    in decode and the array in a prefill wave — 4 / hd of the pages'
+    bytes, under the bar.  Read by tests/test_chip_compile.py (compiled
+    for a described chip) and by chip_smoke.py (on the chip)."""
+    import math
+    import re
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->",
+                      hlo_text).group(1)
+    pages = [p for p in pools if p.ndim == 4 and p.shape[-1] % 128 == 0]
+    found = []
+    for p in pages:
+        dims = ",".join(map(str, p.shape))
+        if f"[{dims}]{{3,2,1,0:" not in entry:
+            found.append(f"pool [{dims}] does not enter as {{3,2,1,0}}")
+    num_pages = pools[0].shape[1]
+    layer_elems = math.prod(pools[0].shape[1:])
+    for line in hlo_text.splitlines():
+        m = re.search(r"^\s*(?:ROOT )?%\S*(?:copy|transpose)\S* = "
+                      r"\w+\[([\d,]*)\]", line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        if num_pages in dims and math.prod(dims) >= layer_elems:
+            found.append(line.strip()[:160])
+    return found
+
+
 class ServingQueueFull(RuntimeError):
     """submit() back-pressure: the bounded admission queue is at
     ``max_queue`` — callers must retry/shed, exactly like a 429."""
@@ -1820,22 +1856,25 @@ class PagedServingEngine(ServingEngine):
                 fresh = gpt.init_cache(cfg, b, s, dtype=cache_k.dtype)
             logits, filled = gpt.forward_cached(params, tokens, cfg, fresh)
             L = cfg.num_layers
-            nh, hd = cfg.num_heads, cfg.head_dim
             flat = ptab.reshape(-1)
-            fk = filled["k"].reshape(L, b * pr, ps, nh, hd)
-            fv = filled["v"].reshape(L, b * pr, ps, nh, hd)
+
+            def chunks(x):
+                # [L, b, s, ...] -> page chunks [L, b*pr, ps, nh*hd | nh]:
+                # rows as the pool stores them, written where it lies
+                return x.reshape(L, b * pr, ps, -1)
+
             with jax.named_scope("kv_scatter"):
                 if kvq:
-                    fkq, fks = gpt.quantize_kv(fk)
-                    fvq, fvs = gpt.quantize_kv(fv)
-                    cache_k = cache_k.at[:, flat].set(fkq)
-                    k_scale = k_scale.at[:, flat].set(fks)
-                    cache_v = cache_v.at[:, flat].set(fvq)
-                    v_scale = v_scale.at[:, flat].set(fvs)
+                    fkq, fks = gpt.quantize_kv(filled["k"])
+                    fvq, fvs = gpt.quantize_kv(filled["v"])
+                    cache_k = cache_k.at[:, flat].set(chunks(fkq))
+                    k_scale = k_scale.at[:, flat].set(chunks(fks))
+                    cache_v = cache_v.at[:, flat].set(chunks(fvq))
+                    v_scale = v_scale.at[:, flat].set(chunks(fvs))
                     out_cache = (cache_k, k_scale, cache_v, v_scale)
                 else:
-                    cache_k = cache_k.at[:, flat].set(fk)
-                    cache_v = cache_v.at[:, flat].set(fv)
+                    cache_k = cache_k.at[:, flat].set(chunks(filled["k"]))
+                    cache_v = cache_v.at[:, flat].set(chunks(filled["v"]))
                     out_cache = (cache_k, cache_v)
                 out_cache = self._constrain_cache(out_cache)
             with jax.named_scope("head_sample"):

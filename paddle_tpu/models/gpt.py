@@ -217,8 +217,9 @@ def sharding_rules(cfg: GPTConfig = None):
 # below: GSPMD partitions them from the operand shardings, which is
 # exactly the pjit/NamedSharding recipe the training engine uses.
 
-# the KV pool sharding: head axis (axis 3 of [L, P, ps, nh, hd] pages,
-# [L, S, max_len, nh, hd] slots, and [L, P, ps, nh] int8 scales alike)
+# the KV pool sharding: head axis (axis 3 of [L, P, ps, nh * hd] pages —
+# a rank's heads are a contiguous column range of the merged axis — of
+# [L, S, max_len, nh, hd] slots, and of [L, P, ps, nh] int8 scales alike)
 KV_POOL_SPEC = (None, None, None, "tp")
 
 # stage-local pools on a ('pp','tp') serving mesh: the stacked layer
@@ -835,7 +836,7 @@ def decode_step_slots(params, tokens, cfg: GPTConfig, cache, active=None):
 #
 # The slot cache above still reserves a contiguous [max_len] strip per
 # slot.  The paged layout breaks the pool into fixed-size pages
-# ([L, num_pages, page_size, nh, hd]) and gives each slot a PAGE TABLE
+# ([L, num_pages, page_size, nh * hd]) and gives each slot a PAGE TABLE
 # (int32[maxP] of physical page ids, scratch page 0 padding the unused
 # tail): position p of a slot's sequence lives at
 # (table[p // page_size], p % page_size).  Attention gathers K/V through
@@ -844,43 +845,85 @@ def decode_step_slots(params, tokens, cfg: GPTConfig, cache, active=None):
 # a request pins is proportional to its LENGTH, not to max_len — and
 # identical prompt prefixes can share physical pages
 # (inference/kv_pager.py owns that bookkeeping).
+#
+# The pool is held IN PLACE by every step.  Its stored shape merges the
+# head axes: a minor axis of nh * hd (a multiple of the 128 lanes at
+# 32 x 64 and 16 x 128 alike) under page_size rows is what the device
+# lays out major-to-minor with no padding, which is also the only
+# layout a Mosaic kernel takes — a [.., nh, 64] tail would be stored
+# page-minor and relaid out around every kernel call.  And the layer
+# loops CARRY the whole pool, writing it with layer-indexed scatters:
+# as the ``xs``/``ys`` of a scan it would be a second pool, sliced and
+# restacked a layer at a time.
+
+
+def paged_pool_shape(cfg: GPTConfig, num_pages, page_size):
+    """The stored shape of one paged K or V pool."""
+    return (cfg.num_layers, num_pages, page_size,
+            cfg.num_heads * cfg.head_dim)
 
 
 def init_paged_cache(cfg: GPTConfig, num_pages, page_size, dtype=None,
                      mesh=None):
-    """Paged KV pool: {'k','v': [L, num_pages, page_size, nh, hd]}.
+    """Paged KV pool: {'k','v': [L, num_pages, page_size, nh * hd]} — a
+    page row is every head's hd values side by side, head-major.
     Page 0 is the scratch page (inactive lanes / padded prefill rows
     scatter there; nothing reads it).  With ``mesh`` the pages shard
     the head axis over 'tp' — page ids stay rank-invariant, each rank
     holds its nh/tp head slice of every page."""
     cd = jnp.dtype(dtype or cfg.dtype)
     sh = None if mesh is None else _kv_pool_sharding(mesh)
-    shape = (cfg.num_layers, num_pages, page_size, cfg.num_heads,
-             cfg.head_dim)
+    shape = paged_pool_shape(cfg, num_pages, page_size)
     return {"k": _pool_zeros(shape, cd, sh), "v": _pool_zeros(shape, cd, sh)}
 
 
-def _paged_slot_block(cfg, x, blk, k_pages, v_pages, page_table,
+def _merge_heads(x):
+    """[..., nh, hd] -> [..., nh * hd]: K/V as a page row stores them."""
+    return x.reshape(*x.shape[:-2], -1)
+
+
+def _layer_scan(body, x, blocks, pools):
+    """Run ``body(x, blk, layer, pools) -> (x, pools, out)`` over the
+    stacked layers with the weights as the scan's ``xs`` and the KV
+    pools as CARRIES, so each layer updates them where they lie.
+    Returns (x, pools, stacked outs)."""
+    n_layers = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+
+    def step(carry, layer):
+        xx, pp = carry
+        blk, i = layer
+        with jax.named_scope("layer"):
+            xx, pp, out = body(xx, blk, i, pp)
+        return (xx, pp), out
+
+    (x, pools), outs = jax.lax.scan(
+        step, (x, tuple(pools)),
+        (blocks, jnp.arange(n_layers, dtype=jnp.int32)))
+    return x, pools, outs
+
+
+def _paged_slot_block(cfg, x, blk, layer, k_pool, v_pool, page_table,
                       write_pages, write_offs, lens, mesh=None):
     """block_apply for the page-table single-token decode: slot s's new
-    K/V land at (write_pages[s], write_offs[s]) — a batched scatter into
-    the shared pool — and its query attends the gathered page view
-    masked to ``k_pos <= lens[s]``.  x: [S, 1, H]; k/v_pages:
-    [P, ps, nh, hd]; page_table: int32 [S, maxP]."""
+    K/V land at (layer, write_pages[s], write_offs[s]) — a batched
+    scatter into the shared pool — and its query attends the layer's
+    gathered page view masked to ``k_pos <= lens[s]``.  x: [S, 1, H];
+    k/v_pool: the whole [L, P, ps, nh * hd] pools; page_table: int32
+    [S, maxP]."""
     from ..ops.pallas.paged_attn import paged_attention
 
     def pattn(q, k, v):
         with jax.named_scope("kv_write"):
-            kc = k_pages.at[write_pages, write_offs].set(
-                k[:, 0].astype(k_pages.dtype))
-            vc = v_pages.at[write_pages, write_offs].set(
-                v[:, 0].astype(v_pages.dtype))
+            kc = k_pool.at[layer, write_pages, write_offs].set(
+                _merge_heads(k[:, 0]).astype(k_pool.dtype))
+            vc = v_pool.at[layer, write_pages, write_offs].set(
+                _merge_heads(v[:, 0]).astype(v_pool.dtype))
         with jax.named_scope("paged_attn"):
-            a = paged_attention(q, kc, vc, page_table, lens, mesh=mesh)
+            a = paged_attention(q, kc, vc, page_table, lens, layer,
+                                mesh=mesh)
         return a, (kc, vc)
 
-    x, (k_pages, v_pages) = block_apply(cfg, x, blk, attn_fn=pattn)
-    return x, k_pages, v_pages
+    return block_apply(cfg, x, blk, attn_fn=pattn)     # x, (k, v pools)
 
 
 def decode_step_paged(params, tokens, cfg: GPTConfig, cache_k, cache_v,
@@ -899,16 +942,14 @@ def decode_step_paged(params, tokens, cfg: GPTConfig, cache_k, cache_v,
             + jnp.take(params["wpe"], lens, axis=0)
         x = x[:, None, :].astype(jnp.dtype(cfg.dtype))    # [S, 1, H]
 
-    def scan_body(carry, layer):
-        blk, kp, vp = layer
-        with jax.named_scope("layer"):
-            xx, kp, vp = _paged_slot_block(cfg, carry, blk, kp, vp,
-                                           page_table, write_pages,
-                                           write_offs, lens, mesh)
-        return xx, (kp, vp)
+    def body(xx, blk, layer, pools):
+        xx, pools = _paged_slot_block(cfg, xx, blk, layer, *pools,
+                                      page_table, write_pages, write_offs,
+                                      lens, mesh)
+        return xx, pools, None
 
-    x, (ks, vs) = jax.lax.scan(scan_body, x,
-                               (params["blocks"], cache_k, cache_v))
+    x, (ks, vs), _ = _layer_scan(body, x, params["blocks"],
+                                 (cache_k, cache_v))
     with jax.named_scope("head_sample"):
         x = _layer_norm(x, params["lnf_g"], params["lnf_b"],
                         cfg.layer_norm_eps)
@@ -931,22 +972,21 @@ def forward_paged_chunk(params, tokens, cfg: GPTConfig, cache_k, cache_v,
     them, same contract as the slot-contiguous prefill pads."""
     maxP = pt_row.shape[0]
     ps = cache_k.shape[2]
+    heads = (cfg.num_heads, cfg.head_dim)
     x = embed(cfg, params, tokens, pos_offset=offset)
 
-    def scan_body(carry, layer):
-        xx = carry
-        blk, kp, vp = layer
-        tail = kp.shape[2:]                       # (nh, hd)
-        view_k = kp[pt_row].reshape(1, maxP * ps, *tail)
-        view_v = vp[pt_row].reshape(1, maxP * ps, *tail)
+    def body(xx, blk, layer, pools):
+        kp, vp = pools
+        view_k = kp[layer, pt_row].reshape(1, maxP * ps, *heads)
+        view_v = vp[layer, pt_row].reshape(1, maxP * ps, *heads)
         xx, view_k, view_v = _cached_block(cfg, xx, blk, view_k, view_v,
                                            offset)
-        kp = kp.at[pt_row].set(view_k[0].reshape(maxP, ps, *tail))
-        vp = vp.at[pt_row].set(view_v[0].reshape(maxP, ps, *tail))
-        return xx, (kp, vp)
+        kp = kp.at[layer, pt_row].set(view_k[0].reshape(maxP, ps, -1))
+        vp = vp.at[layer, pt_row].set(view_v[0].reshape(maxP, ps, -1))
+        return xx, (kp, vp), None
 
-    x, (ks, vs) = jax.lax.scan(scan_body, x,
-                               (params["blocks"], cache_k, cache_v))
+    x, (ks, vs), _ = _layer_scan(body, x, params["blocks"],
+                                 (cache_k, cache_v))
     x = _layer_norm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
     logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
     return logits, ks, vs
@@ -988,45 +1028,51 @@ def dequantize_kv(q, s, dtype):
 def init_paged_cache_quant(cfg: GPTConfig, num_pages, page_size,
                            mesh=None):
     """int8 paged KV pool + scale arrays: {'k','v': int8
-    [L, P, ps, nh, hd], 'k_scale','v_scale': fp32 [L, P, ps, nh]}.
+    [L, P, ps, nh * hd], 'k_scale','v_scale': fp32 [L, P, ps, nh]}.
     Page 0 stays the scratch page.  With ``mesh`` both the int8 pages
     and their scale rows shard the head axis (axis 3 in either rank)
     over 'tp' — a page's bytes AND scales live on the same rank, and
     the per-position-per-head absmax quantizer needs only its own
     heads, so the quantize-once byte contract holds per shard."""
     sh = None if mesh is None else _kv_pool_sharding(mesh)
-    shape = (cfg.num_layers, num_pages, page_size, cfg.num_heads,
-             cfg.head_dim)
+    shape = paged_pool_shape(cfg, num_pages, page_size)
+    scales = shape[:-1] + (cfg.num_heads,)
     return {"k": _pool_zeros(shape, jnp.int8, sh),
             "v": _pool_zeros(shape, jnp.int8, sh),
-            "k_scale": _pool_zeros(shape[:-1], jnp.float32, sh),
-            "v_scale": _pool_zeros(shape[:-1], jnp.float32, sh)}
+            "k_scale": _pool_zeros(scales, jnp.float32, sh),
+            "v_scale": _pool_zeros(scales, jnp.float32, sh)}
 
 
-def _paged_slot_block_quant(cfg, x, blk, k_pages, k_scale, v_pages,
+def _quantize_rows(x):
+    """:func:`quantize_kv` of [..., nh, hd] as the pool stores it:
+    (int8 [..., nh * hd], scale fp32 [..., nh])."""
+    q, s = quantize_kv(x)
+    return _merge_heads(q), s
+
+
+def _paged_slot_block_quant(cfg, x, blk, layer, k_pool, k_scale, v_pool,
                             v_scale, page_table, write_pages, write_offs,
                             lens, mesh=None):
     """:func:`_paged_slot_block` over the int8 pool: each slot's new K/V
-    quantize on write — int8 bytes into (write_pages[s], write_offs[s]),
-    the absmax scale into the scale arrays at the same coordinate — and
-    attention dequantizes on read through
+    quantize on write — int8 bytes into (layer, write_pages[s],
+    write_offs[s]), the absmax scale into the scale arrays at the same
+    coordinate — and attention dequantizes on read through
     ops/pallas/paged_attn.py::paged_attention_quant."""
     from ..ops.pallas.paged_attn import paged_attention_quant
 
     def pattn(q, k, v):
-        kq, ks = quantize_kv(k[:, 0])        # [S, nh, hd] -> int8, [S, nh]
-        vq, vs = quantize_kv(v[:, 0])
-        kc = k_pages.at[write_pages, write_offs].set(kq)
-        ksc = k_scale.at[write_pages, write_offs].set(ks)
-        vc = v_pages.at[write_pages, write_offs].set(vq)
-        vsc = v_scale.at[write_pages, write_offs].set(vs)
+        kq, ks = _quantize_rows(k[:, 0])     # [S, nh*hd] int8, [S, nh]
+        vq, vs = _quantize_rows(v[:, 0])
+        at = (layer, write_pages, write_offs)
+        kc = k_pool.at[at].set(kq)
+        ksc = k_scale.at[at].set(ks)
+        vc = v_pool.at[at].set(vq)
+        vsc = v_scale.at[at].set(vs)
         a = paged_attention_quant(q, kc, ksc, vc, vsc, page_table, lens,
-                                  mesh=mesh)
+                                  layer, mesh=mesh)
         return a, (kc, ksc, vc, vsc)
 
-    x, (k_pages, k_scale, v_pages, v_scale) = block_apply(
-        cfg, x, blk, attn_fn=pattn)
-    return x, k_pages, k_scale, v_pages, v_scale
+    return block_apply(cfg, x, blk, attn_fn=pattn)     # x, the four pools
 
 
 def decode_step_paged_quant(params, tokens, cfg: GPTConfig, cache_k,
@@ -1040,19 +1086,24 @@ def decode_step_paged_quant(params, tokens, cfg: GPTConfig, cache_k,
         + jnp.take(params["wpe"], lens, axis=0)
     x = x[:, None, :].astype(jnp.dtype(cfg.dtype))        # [S, 1, H]
 
-    def scan_body(carry, layer):
-        blk, kp, ksp, vp, vsp = layer
-        xx, kp, ksp, vp, vsp = _paged_slot_block_quant(
-            cfg, carry, blk, kp, ksp, vp, vsp, page_table, write_pages,
+    def body(xx, blk, layer, pools):
+        xx, pools = _paged_slot_block_quant(
+            cfg, xx, blk, layer, *pools, page_table, write_pages,
             write_offs, lens, mesh)
-        return xx, (kp, ksp, vp, vsp)
+        return xx, pools, None
 
-    x, (ks, kss, vs, vss) = jax.lax.scan(
-        scan_body, x,
-        (params["blocks"], cache_k, k_scale, cache_v, v_scale))
+    x, pools, _ = _layer_scan(body, x, params["blocks"],
+                              (cache_k, k_scale, cache_v, v_scale))
     x = _layer_norm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
     logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
-    return logits[:, 0], ks, kss, vs, vss
+    return (logits[:, 0], *pools)
+
+
+def _dequantize_view(pages, scales, dtype):
+    """Gathered int8 pages [..., ps, nh * hd] with their scales
+    [..., ps, nh] -> the dequantized [..., ps, nh, hd] view."""
+    heads = pages.reshape(*scales.shape, -1)
+    return dequantize_kv(heads, scales, dtype)
 
 
 def forward_paged_chunk_quant(params, tokens, cfg: GPTConfig, cache_k,
@@ -1072,38 +1123,34 @@ def forward_paged_chunk_quant(params, tokens, cfg: GPTConfig, cache_k,
     C = tokens.shape[1]
     cpages = C // ps
     cd = jnp.dtype(cfg.dtype)
+    heads = (cfg.num_heads, cfg.head_dim)
     x = embed(cfg, params, tokens, pos_offset=offset)
     j0 = offset // ps
 
-    def scan_body(carry, layer):
-        xx = carry
-        blk, kp, ksp, vp, vsp = layer
-        tail = kp.shape[2:]                       # (nh, hd)
-        view_k = dequantize_kv(kp[pt_row], ksp[pt_row], cd).reshape(
-            1, maxP * ps, *tail)
-        view_v = dequantize_kv(vp[pt_row], vsp[pt_row], cd).reshape(
-            1, maxP * ps, *tail)
+    def body(xx, blk, layer, pools):
+        kp, ksp, vp, vsp = pools
+        view_k = _dequantize_view(kp[layer, pt_row], ksp[layer, pt_row],
+                                  cd).reshape(1, maxP * ps, *heads)
+        view_v = _dequantize_view(vp[layer, pt_row], vsp[layer, pt_row],
+                                  cd).reshape(1, maxP * ps, *heads)
         xx, view_k, view_v = _cached_block(cfg, xx, blk, view_k, view_v,
                                            offset)
-        ck = jax.lax.dynamic_slice(view_k[0], (offset, 0, 0),
-                                   (C,) + tuple(tail))
-        cv = jax.lax.dynamic_slice(view_v[0], (offset, 0, 0),
-                                   (C,) + tuple(tail))
-        ckq, cks = quantize_kv(ck)                # [C, nh, hd], [C, nh]
-        cvq, cvs = quantize_kv(cv)
+        ck = jax.lax.dynamic_slice(view_k[0], (offset, 0, 0), (C,) + heads)
+        cv = jax.lax.dynamic_slice(view_v[0], (offset, 0, 0), (C,) + heads)
+        ckq, cks = _quantize_rows(ck)             # [C, nh*hd], [C, nh]
+        cvq, cvs = _quantize_rows(cv)
         pages = jax.lax.dynamic_slice(pt_row, (j0,), (cpages,))
-        kp = kp.at[pages].set(ckq.reshape(cpages, ps, *tail))
-        ksp = ksp.at[pages].set(cks.reshape(cpages, ps, tail[0]))
-        vp = vp.at[pages].set(cvq.reshape(cpages, ps, *tail))
-        vsp = vsp.at[pages].set(cvs.reshape(cpages, ps, tail[0]))
-        return xx, (kp, ksp, vp, vsp)
+        kp = kp.at[layer, pages].set(ckq.reshape(cpages, ps, -1))
+        ksp = ksp.at[layer, pages].set(cks.reshape(cpages, ps, -1))
+        vp = vp.at[layer, pages].set(cvq.reshape(cpages, ps, -1))
+        vsp = vsp.at[layer, pages].set(cvs.reshape(cpages, ps, -1))
+        return xx, (kp, ksp, vp, vsp), None
 
-    x, (ks, kss, vs, vss) = jax.lax.scan(
-        scan_body, x,
-        (params["blocks"], cache_k, k_scale, cache_v, v_scale))
+    x, pools, _ = _layer_scan(body, x, params["blocks"],
+                              (cache_k, k_scale, cache_v, v_scale))
     x = _layer_norm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
     logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
-    return logits, ks, kss, vs, vss
+    return (logits, *pools)
 
 
 # --------------------------------------------------------------------------
@@ -1127,7 +1174,8 @@ def forward_paged_chunk_quant(params, tokens, cfg: GPTConfig, cache_k,
 # what keeps the prefix-hash/page-byte determinism contract intact.
 
 
-def _paged_verify_block(cfg, x, blk, k_pages, v_pages, page_table, lens):
+def _paged_verify_block(cfg, x, blk, layer, k_pool, v_pool, page_table,
+                        lens):
     """block_apply for the W-token speculative verify window: queries at
     absolute positions ``lens[s] + j`` attend the gathered page view
     with the window's own K/V SPLICED IN at their true positions
@@ -1139,21 +1187,23 @@ def _paged_verify_block(cfg, x, blk, k_pages, v_pages, page_table, lens):
     activations — and therefore the K/V bytes the engine later commits —
     are bit-identical to a sequential decode, which is what the
     page-byte determinism regression demands.  x: [S, W, H];
-    k/v_pages: [P, ps, nh, hd]; page_table: int32 [S, maxP].  Returns
-    (x_out, win_k, win_v) with the window K/V in the POOL dtype (the
-    cast a committed write applies) — the pool itself is untouched."""
+    k/v_pool: the whole [L, P, ps, nh * hd] pools, read at ``layer``;
+    page_table: int32 [S, maxP].  Returns (x_out, win_k, win_v) with the
+    window K/V [S, W, nh * hd] as a page row stores them, in the POOL
+    dtype (the cast a committed write applies) — the pool itself is
+    untouched."""
     S, maxP = page_table.shape
-    ps = k_pages.shape[1]
-    hd = cfg.head_dim
+    ps = k_pool.shape[2]
+    nh, hd = cfg.num_heads, cfg.head_dim
     view = maxP * ps
     cd = jnp.dtype(cfg.dtype)
 
     def vattn(q, k, v):
         W = q.shape[1]
-        kw = k.astype(k_pages.dtype)
-        vw = v.astype(v_pages.dtype)
-        kc = k_pages[page_table].reshape(S, view, *k_pages.shape[2:])
-        vc = v_pages[page_table].reshape(S, view, *v_pages.shape[2:])
+        kw = k.astype(k_pool.dtype)
+        vw = v.astype(v_pool.dtype)
+        kc = k_pool[layer, page_table].reshape(S, view, nh, hd)
+        vc = v_pool[layer, page_table].reshape(S, view, nh, hd)
         rows = jnp.arange(S)[:, None]
         cols = lens[:, None] + jnp.arange(W)[None, :]
         kc = kc.at[rows, cols].set(kw)      # OOB window lanes drop
@@ -1181,7 +1231,7 @@ def _paged_verify_block(cfg, x, blk, k_pages, v_pages, page_table, lens):
             pj = jax.nn.softmax(lg, -1).astype(cd)
             outs.append(jnp.einsum("shqk,skhd->sqhd", pj, vcc))
         a = jnp.concatenate(outs, axis=1)             # [S, W, nh, hd]
-        return a, (kw, vw)
+        return a, (_merge_heads(kw), _merge_heads(vw))
 
     x, (win_k, win_v) = block_apply(cfg, x, blk, attn_fn=vattn)
     return x, win_k, win_v
@@ -1193,7 +1243,7 @@ def decode_step_paged_verify(params, tokens, cfg: GPTConfig, cache_k,
     (W = spec_k + 1 — the last committed token plus the k draft
     candidates) at absolute positions ``lens[s] + j`` through the paged
     pool, WITHOUT writing it.  Returns (logits [S, W, V] fp32,
-    win_k, win_v [L, S, W, nh, hd] in the pool dtype) — the caller
+    win_k, win_v [L, S, W, nh * hd] in the pool dtype) — the caller
     commits the accepted prefix with one masked scatter."""
     S, W = tokens.shape
     pos = lens[:, None] + jnp.arange(W)[None, :]
@@ -1201,20 +1251,19 @@ def decode_step_paged_verify(params, tokens, cfg: GPTConfig, cache_k,
         + jnp.take(params["wpe"], pos, axis=0)
     x = x.astype(jnp.dtype(cfg.dtype))                    # [S, W, H]
 
-    def scan_body(carry, layer):
-        blk, kp, vp = layer
-        xx, kw, vw = _paged_verify_block(cfg, carry, blk, kp, vp,
+    def body(xx, blk, layer, pools):
+        xx, kw, vw = _paged_verify_block(cfg, xx, blk, layer, *pools,
                                          page_table, lens)
-        return xx, (kw, vw)
+        return xx, pools, (kw, vw)
 
-    x, (wk, wv) = jax.lax.scan(scan_body, x,
-                               (params["blocks"], cache_k, cache_v))
+    x, _, (wk, wv) = _layer_scan(body, x, params["blocks"],
+                                 (cache_k, cache_v))
     x = _layer_norm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
     logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
     return logits, wk, wv
 
 
-def _paged_verify_block_quant(cfg, x, blk, k_pages, k_scale, v_pages,
+def _paged_verify_block_quant(cfg, x, blk, layer, k_pool, k_scale, v_pool,
                               v_scale, page_table, lens):
     """:func:`_paged_verify_block` over the int8 pool.  The window K/V
     quantize IMMEDIATELY (per-position absmax, exactly the bytes a
@@ -1224,8 +1273,8 @@ def _paged_verify_block_quant(cfg, x, blk, k_pages, k_scale, v_pages,
     so accepted positions reproduce the non-speculative logits and page
     bytes exactly.  Returns (x_out, win_kq, win_ks, win_vq, win_vs)."""
     S, maxP = page_table.shape
-    ps = k_pages.shape[1]
-    hd = cfg.head_dim
+    ps = k_pool.shape[2]
+    nh, hd = cfg.num_heads, cfg.head_dim
     view = maxP * ps
     cd = jnp.dtype(cfg.dtype)
 
@@ -1235,10 +1284,12 @@ def _paged_verify_block_quant(cfg, x, blk, k_pages, k_scale, v_pages,
         vq, vs = quantize_kv(v)
         kw = dequantize_kv(kq, ks, jnp.float32)
         vw = dequantize_kv(vq, vs, jnp.float32)
-        kc = dequantize_kv(k_pages[page_table], k_scale[page_table],
-                           jnp.float32).reshape(S, view, *k_pages.shape[2:])
-        vc = dequantize_kv(v_pages[page_table], v_scale[page_table],
-                           jnp.float32).reshape(S, view, *v_pages.shape[2:])
+        kc = _dequantize_view(
+            k_pool[layer, page_table], k_scale[layer, page_table],
+            jnp.float32).reshape(S, view, nh, hd)
+        vc = _dequantize_view(
+            v_pool[layer, page_table], v_scale[layer, page_table],
+            jnp.float32).reshape(S, view, nh, hd)
         rows = jnp.arange(S)[:, None]
         cols = lens[:, None] + jnp.arange(W)[None, :]
         kc = kc.at[rows, cols].set(kw)      # OOB window lanes drop
@@ -1256,7 +1307,7 @@ def _paged_verify_block_quant(cfg, x, blk, k_pages, k_scale, v_pages,
             pj = jax.nn.softmax(lg, -1).astype(cd)
             outs.append(jnp.einsum("shqk,skhd->sqhd", pj, vcc))
         a = jnp.concatenate(outs, axis=1)
-        return a, (kq, ks, vq, vs)
+        return a, (_merge_heads(kq), ks, _merge_heads(vq), vs)
 
     x, (kq, ks, vq, vs) = block_apply(cfg, x, blk, attn_fn=vattn)
     return x, kq, ks, vq, vs
@@ -1266,7 +1317,7 @@ def decode_step_paged_verify_quant(params, tokens, cfg: GPTConfig,
                                    cache_k, k_scale, cache_v, v_scale,
                                    page_table, lens):
     """:func:`decode_step_paged_verify` over the INT8 paged pool.
-    Returns (logits [S, W, V] fp32, win_kq [L, S, W, nh, hd] int8,
+    Returns (logits [S, W, V] fp32, win_kq [L, S, W, nh * hd] int8,
     win_ks [L, S, W, nh] fp32, win_vq, win_vs) — quantized exactly once
     per window position, so the caller's masked commit lands the same
     bytes AND scales a sequential int8 decode would have."""
@@ -1276,15 +1327,13 @@ def decode_step_paged_verify_quant(params, tokens, cfg: GPTConfig,
         + jnp.take(params["wpe"], pos, axis=0)
     x = x.astype(jnp.dtype(cfg.dtype))
 
-    def scan_body(carry, layer):
-        blk, kp, ksp, vp, vsp = layer
-        xx, kq, ks, vq, vs = _paged_verify_block_quant(
-            cfg, carry, blk, kp, ksp, vp, vsp, page_table, lens)
-        return xx, (kq, ks, vq, vs)
+    def body(xx, blk, layer, pools):
+        xx, *win = _paged_verify_block_quant(
+            cfg, xx, blk, layer, *pools, page_table, lens)
+        return xx, pools, tuple(win)
 
-    x, (wkq, wks, wvq, wvs) = jax.lax.scan(
-        scan_body, x,
-        (params["blocks"], cache_k, k_scale, cache_v, v_scale))
+    x, _, (wkq, wks, wvq, wvs) = _layer_scan(
+        body, x, params["blocks"], (cache_k, k_scale, cache_v, v_scale))
     x = _layer_norm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
     logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
     return logits, wkq, wks, wvq, wvs

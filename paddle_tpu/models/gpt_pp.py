@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from ..framework import jax_compat
 from ..framework.jax_compat import partition_spec as P
 from ..distributed.auto.pipeline import StageAssignment, pipeline_stage_loop
-from .gpt import KV_POOL_SPEC_PP, _layer_norm
+from .gpt import KV_POOL_SPEC_PP, _layer_norm, _layer_scan, _merge_heads
 
 
 def check_pp_config(cfg, pp):
@@ -80,13 +80,14 @@ def _vp_head(h, wte_l):
         loc, "tp", axis=loc.ndim - 1, tiled=True).astype(jnp.float32)
 
 
-def _pp_paged_block(cfg, x, blk, kp, vp, page_table, write_pages,
+def _pp_paged_block(cfg, x, blk, layer, kp, vp, page_table, write_pages,
                     write_offs, lens):
     """models/gpt.py::_paged_slot_block with the tp collectives made
     explicit: local-head attention over the stage-local page pool,
     psum('tp') closing the row-parallel proj and fc2 matmuls (the
     Megatron two-allreduces-per-block recipe, gpt_hybrid._sharded_block).
-    x: [S, 1, H]; kp/vp: this stage's [P, ps, nh/tp, hd] pool shard."""
+    x: [S, 1, H]; kp/vp: this stage's whole [L/pp, P, ps, nh/tp * hd]
+    pool shard, written and read at the stage-local ``layer``."""
     from ..ops.pallas.paged_attn import paged_attention
     cd = jnp.dtype(cfg.dtype)
     hd = cfg.head_dim
@@ -97,9 +98,10 @@ def _pp_paged_block(cfg, x, blk, kp, vp, page_table, write_pages,
         + blk["qkv_b"].astype(cd)
     nh_loc = qkv.shape[-1] // hd
     q, k, v = [qkv[:, :, i].reshape(S, T, nh_loc, hd) for i in range(3)]
-    kc = kp.at[write_pages, write_offs].set(k[:, 0].astype(kp.dtype))
-    vc = vp.at[write_pages, write_offs].set(v[:, 0].astype(vp.dtype))
-    a = paged_attention(q, kc, vc, page_table, lens)
+    at = (layer, write_pages, write_offs)
+    kc = kp.at[at].set(_merge_heads(k[:, 0]).astype(kp.dtype))
+    vc = vp.at[at].set(_merge_heads(v[:, 0]).astype(vp.dtype))
+    a = paged_attention(q, kc, vc, page_table, lens, layer)
     a = a.reshape(S, T, -1)
     a = jax.lax.psum(a @ blk["proj_w"].astype(cd), "tp") \
         + blk["proj_b"].astype(cd)
@@ -180,20 +182,18 @@ def make_decode_step(cfg, mesh, param_specs, n_microbatch):
         ln_r = lens.reshape(M, mb)
 
         def stage_fn(x, carry, m, valid):
-            kp, vp = carry
             ptm = jnp.where(valid, pt_r[m], 0)
             wpm = jnp.where(valid, wp_r[m], 0)
             wom = jnp.where(valid, wo_r[m], 0)
             lnm = jnp.where(valid, ln_r[m], 0)
 
-            def scan_body(cx, layer):
-                blk, kpl, vpl = layer
-                xx, kpl, vpl = _pp_paged_block(
-                    cfg, cx, blk, kpl, vpl, ptm, wpm, wom, lnm)
-                return xx, (kpl, vpl)
+            def body(cx, blk, layer, pools):
+                xx, *pools = _pp_paged_block(
+                    cfg, cx, blk, layer, *pools, ptm, wpm, wom, lnm)
+                return xx, tuple(pools), None
 
-            x, (kp, vp) = jax.lax.scan(scan_body, x, (blocks, kp, vp))
-            return x, (kp, vp)
+            x, carry, _ = _layer_scan(body, x, blocks, carry)
+            return x, carry
 
         outputs, (ck, cv) = pipeline_stage_loop(stage_fn, micro, (ck, cv))
         h = outputs.reshape(S, 1, -1)
@@ -238,21 +238,19 @@ def make_prefill_step(cfg, mesh, param_specs, b, s, page_size):
         flat = ptab.reshape(-1)                # [b*pr]
 
         def stage_fn(x, carry, m, valid):
-            kp, vp = carry
             fl = jnp.where(valid, flat, 0)     # bubble -> scratch page
 
-            def scan_body(cx, layer):
-                blk, kpl, vpl = layer
-                xx, kc, vc = _pp_prefill_block(cfg, cx, blk, kpl.dtype)
-                tail = kc.shape[2:]
-                kpl = kpl.at[fl].set(
-                    kc.reshape(b * pr, page_size, *tail))
-                vpl = vpl.at[fl].set(
-                    vc.reshape(b * pr, page_size, *tail))
-                return xx, (kpl, vpl)
+            def body(cx, blk, layer, pools):
+                kp, vp = pools
+                xx, kc, vc = _pp_prefill_block(cfg, cx, blk, kp.dtype)
+                kp = kp.at[layer, fl].set(
+                    kc.reshape(b * pr, page_size, -1))
+                vp = vp.at[layer, fl].set(
+                    vc.reshape(b * pr, page_size, -1))
+                return xx, (kp, vp), None
 
-            x, (kp, vp) = jax.lax.scan(scan_body, x, (blocks, kp, vp))
-            return x, (kp, vp)
+            x, carry, _ = _layer_scan(body, x, blocks, carry)
+            return x, carry
 
         outputs, (ck, cv) = pipeline_stage_loop(stage_fn, micro, (ck, cv))
         h = _layer_norm(outputs[0], params["lnf_g"], params["lnf_b"],

@@ -1,17 +1,23 @@
-"""The kernels of the two main paths, compiled for the chip without the
-chip: the installed TPU compiler takes a DESCRIBED ``v5e:2x2`` device, so
-each test AOT-compiles one Pallas kernel at the widths ``chip_smoke.py``
-serves and trains (the ``on-chip-measurement`` guide, section 2,
-rehearsal 3).  Interpret mode cannot see what Mosaic refuses — the paged
-decode kernels passed every interpret test while the chip's compiler
-rejected their ``dot_dimension_numbers`` — so these are the tests that
-guard a kernel between chip runs.  A compile that passes is not a chip
-run: it says nothing about results or times.
+"""The kernels of the two main paths, and the serving engine's whole
+decode and prefill programs, compiled for the chip without the chip: the
+installed TPU compiler takes a DESCRIBED ``v5e:2x2`` device, so each test
+AOT-compiles one Pallas kernel at the widths ``chip_smoke.py`` serves and
+trains, or one serving program at the benchmark's sizes (the
+``on-chip-measurement`` guide, section 2, rehearsal 3).  Interpret mode
+cannot see what Mosaic refuses — the paged decode kernels passed every
+interpret test while the chip's compiler rejected their
+``dot_dimension_numbers`` — so these are the tests that guard a kernel
+between chip runs.  A compile that passes is not a chip run: it says
+nothing about results or times.
 
-About a second each.  Skipped where the topology cannot be described (no
-libtpu).  The persistent compilation cache is turned off around them: a
-TPU executable written to it here cannot be read back without a chip,
-and the next run would warn and compile again."""
+The whole programs are read for what they do to the paged KV pool: they
+must take it in the layout the device stores it in, update it in place,
+and copy nothing of a layer's size (ISSUE 27).
+
+One to three seconds each.  Skipped where the topology cannot be
+described (no libtpu).  The persistent compilation cache is turned off
+around them: a TPU executable written to it here cannot be read back
+without a chip, and the next run would warn and compile again."""
 import functools
 import os
 
@@ -54,39 +60,122 @@ def compiles(fn, *args):
 
 
 # the serving shape of gpt3_1p3b() (32 x 64) and of the 1.3B flagship's
-# attention (16 x 128): 8 slots, 1024 pages, 64 pages per slot
-SLOTS, PAGES, MAXP = 8, 1024, 64
+# attention (16 x 128): 8 slots, 4 layers of 1024 pages, 64 pages a slot
+SLOTS, LAYERS, PAGES, MAXP = 8, 4, 1024, 64
 HEADS = [(32, 64), (16, 128)]
+
+
+def paged_args(chip, nh, hd, ps, dtype, pages=PAGES):
+    """(q, k_pool, v_pool), (page_table, lens, layer) of the kernel."""
+    pool = chip((LAYERS, pages, ps, nh * hd), dtype)
+    return ((chip((SLOTS, 1, nh, hd), bf16), pool, pool),
+            (chip((SLOTS, MAXP), i32), chip((SLOTS,), i32), chip((), i32)))
 
 
 @pytest.mark.parametrize("nh,hd", HEADS)
 @pytest.mark.parametrize("ps", [16, 32])
 def test_paged_attention_fp(chip, nh, hd, ps):
     from paddle_tpu.ops.pallas.paged_attn import _paged_attention_tpu
-    pool = chip((PAGES, ps, nh, hd), bf16)
-    assert compiles(_paged_attention_tpu, chip((SLOTS, 1, nh, hd), bf16),
-                    pool, pool, chip((SLOTS, MAXP), i32),
-                    chip((SLOTS,), i32)) == 1
+    qkv, rest = paged_args(chip, nh, hd, ps, bf16)
+    assert compiles(_paged_attention_tpu, *qkv, *rest) == 1
 
 
 @pytest.mark.parametrize("nh,hd", HEADS)
 def test_paged_attention_int8(chip, nh, hd, ps=32):
     from paddle_tpu.ops.pallas.paged_attn import _paged_attention_quant_tpu
-    pool = chip((PAGES, ps, nh, hd), i8)
+    qkv, rest = paged_args(chip, nh, hd, ps, i8)
     scale = chip((PAGES, ps, nh), f32)
-    assert compiles(_paged_attention_quant_tpu,
-                    chip((SLOTS, 1, nh, hd), bf16), pool, scale, pool,
-                    scale, chip((SLOTS, MAXP), i32),
-                    chip((SLOTS,), i32)) == 1
+    assert compiles(_paged_attention_quant_tpu, *qkv, scale, scale,
+                    *rest) == 1
 
 
 def test_paged_attention_one_tp_shard(chip):
     """What one rank of a tp=4 engine runs: 8 of the 32 heads."""
     from paddle_tpu.ops.pallas.paged_attn import _paged_attention_tpu
-    pool = chip((PAGES, 16, 8, 64), bf16)
-    assert compiles(_paged_attention_tpu, chip((SLOTS, 1, 8, 64), bf16),
-                    pool, pool, chip((SLOTS, MAXP), i32),
-                    chip((SLOTS,), i32)) == 1
+    qkv, rest = paged_args(chip, 8, 64, 16, bf16)
+    assert compiles(_paged_attention_tpu, *qkv, *rest) == 1
+
+
+def test_paged_attention_largest_admitted_page(chip):
+    """The corner of ``_use_pallas_paged``'s VMEM bound: 32 heads x 256
+    at 64 positions, float32 pool (the widest working copies)."""
+    from paddle_tpu.ops.pallas import paged_attn
+    qkv, rest = paged_args(chip, 32, 256, 64, f32, pages=128)
+    assert (4 * 32 + 4 * 64) * 32 * 256 * 4 == paged_attn._MAX_ROWS_F32_BYTES
+    assert compiles(paged_attn._paged_attention_tpu, *qkv, *rest) == 1
+
+
+# --------------------------------------------------------------------------
+# the whole serving programs at the benchmark's sizes: the pool is held in
+# place (ISSUE 27).  Each case builds the engine as a user does, with shapes
+# in place of weights and a two-page stand-in pool, and compiles the
+# engine's OWN decode / prefill builders against the real pool's shape.
+# --------------------------------------------------------------------------
+
+BENCH_PAGES, BENCH_SLOTS, BENCH_LAYERS, MAX_LEN = 1664, 32, 24, 2048
+POOLS = [pytest.param(32, 64, 16, None, id="bf16-32x64"),
+         pytest.param(16, 128, 16, None, id="bf16-16x128"),
+         pytest.param(32, 64, 32, "int8", id="int8-32x64")]
+PROGRAMS = ["decode", "prefill_1x512", "prefill_4x1024"]
+
+
+@pytest.fixture
+def served(chip, monkeypatch):
+    """``served(nh, hd, ps, kv_dtype) -> (engine, params, pools)``: a
+    ``PagedServingEngine`` over the 1.3B widths with the chip's Pallas
+    branch and buffer donation steered on here, in the test (what
+    ``jax.devices()`` says is the CPU), and the pool operands' shapes at
+    the benchmark's page count."""
+    from paddle_tpu.inference.serving import PagedServingEngine
+    from paddle_tpu.models import gpt
+    from paddle_tpu.ops.pallas import utils as pallas_utils
+    monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
+    monkeypatch.setenv("PADDLE_TPU_SERVING_DONATE", "1")
+
+    def build(nh, hd, ps, kv_dtype):
+        cfg = gpt.GPTConfig(vocab_size=50304, hidden_size=nh * hd,
+                            num_layers=BENCH_LAYERS, num_heads=nh,
+                            ffn_size=8192, max_seq_len=MAX_LEN,
+                            dtype="bfloat16", param_dtype="bfloat16")
+        params = jax.tree_util.tree_map(
+            lambda x: chip(x.shape, x.dtype),
+            jax.eval_shape(lambda k: gpt.init_params(cfg, k),
+                           jax.random.PRNGKey(0)))
+        eng = PagedServingEngine(
+            (params, cfg), slots=BENCH_SLOTS, max_len=MAX_LEN, page_size=ps,
+            num_pages=2, kv_dtype=kv_dtype, seq_buckets=(128, 512, 1024),
+            batch_buckets=(1, 4), capture_logits=False)
+        pools = tuple(
+            chip((a.shape[0], BENCH_PAGES) + a.shape[2:], a.dtype)
+            for a in eng._cache_operands())
+        return eng, params, pools
+
+    return build
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("nh,hd,ps,kv_dtype", POOLS)
+def test_serving_programs_hold_the_pool_in_place(chip, served, nh, hd, ps,
+                                                 kv_dtype, program):
+    from paddle_tpu.inference.serving import pool_relayouts
+    eng, params, pools = served(nh, hd, ps, kv_dtype)
+    if program == "decode":
+        fn = eng._build_decode()
+        args = (chip((BENCH_SLOTS, MAX_LEN // ps), i32),
+                *[chip((BENCH_SLOTS,), i32)] * 4)
+    else:
+        b, s = map(int, program.split("_")[1].split("x"))
+        fn = eng._build_prefill(b, s)
+        args = (chip((b, s), i32), chip((b,), i32), chip((b, s // ps), i32))
+    compiled = fn.lower(params, *pools, *args).compile()
+    assert pool_relayouts(compiled.as_text(), pools) == []
+    mem = compiled.memory_analysis()
+    # every pool operand comes back donated, in place
+    assert mem.alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools)
+    if program == "decode":
+        assert compiled.as_text().count("tpu_custom_call") == 1
+        assert mem.temp_size_in_bytes < (1 << 30)
 
 
 @pytest.mark.parametrize("m", [16, 256])   # decode rows (padded), prefill
@@ -149,14 +238,12 @@ def test_kernel_names_and_scopes_reach_the_compiled_program(chip):
     what a profiler trace of the chip shows for the kernel."""
     from paddle_tpu.ops.pallas.paged_attn import _paged_attention_tpu
 
-    def layer(q, k, v, table, lens):
+    def layer(*args):
         with jax.named_scope("layer"), jax.named_scope("paged_attn"):
-            return _paged_attention_tpu(q, k, v, table, lens)
+            return _paged_attention_tpu(*args)
 
-    pool = chip((PAGES, 16, 32, 64), bf16)
-    text = jax.jit(layer).lower(
-        chip((SLOTS, 1, 32, 64), bf16), pool, pool, chip((SLOTS, MAXP), i32),
-        chip((SLOTS,), i32)).compile().as_text()
+    qkv, rest = paged_args(chip, 32, 64, 16, bf16)
+    text = jax.jit(layer).lower(*qkv, *rest).compile().as_text()
     (call,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert call.lstrip().startswith("%paged_attn_decode")
     assert 'op_name="jit(layer)/layer/paged_attn/paged_attn_decode' in call

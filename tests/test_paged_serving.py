@@ -839,43 +839,114 @@ class TestPrefixStickyRouting:
 
 
 # --------------------------------------------------------------------------
-# Pallas paged-attention kernel (interpret mode) — slow tier
+# Pallas paged-attention kernel (interpret mode)
 # --------------------------------------------------------------------------
 
-@pytest.mark.slow
-class TestPagedAttentionKernel:
+def _kernel_case(rng, S, nh, hd, L, P, ps, maxP, dtype, quant=False):
+    """Random pools as the engine stores them ([L, P, ps, nh * hd], and
+    for int8 the [L, P, ps, nh] scale rows), queries, tables and lengths
+    (one lane at length 0, one ending mid-page)."""
+    import jax.numpy as jnp
+    C = nh * hd
+    if quant:
+        pools = [jnp.asarray(rng.randint(-127, 128, (L, P, ps, C))
+                             .astype(np.int8)) for _ in range(2)]
+        scales = [jnp.asarray((rng.rand(L, P, ps, nh).astype(np.float32)
+                               + 0.05) / 64) for _ in range(2)]
+    else:
+        pools = [jnp.asarray(rng.randn(L, P, ps, C), dtype)
+                 for _ in range(2)]
+        scales = []
+    q = jnp.asarray(rng.randn(S, 1, nh, hd), dtype)
+    pt = jnp.asarray(rng.randint(0, P, (S, maxP)).astype(np.int32))
+    lens = rng.randint(0, maxP * ps, (S,)).astype(np.int32)
+    lens[0], lens[-1] = 0, maxP * ps - ps // 2 - 1
+    return q, pools, scales, pt, jnp.asarray(lens)
+
+
+def _kernel_reference(q, pools, scales, pt, lens, layer):
+    """The lax fallback on float32 copies of the same values: the
+    kernel's contract is float32 products, softmax and sums over the
+    stored (bf16 / int8 x scale) numbers."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import paged_attn
+    f32 = jnp.float32
+    k, v = (p[layer].astype(f32) for p in pools)
+    if scales:
+        return paged_attn._ref_paged_attention_quant(
+            q.astype(f32), k, scales[0][layer], v, scales[1][layer],
+            pt, lens)
+    return paged_attn._ref_paged_attention(q.astype(f32), k, v, pt, lens)
+
+
+def _kernel_run(q, pools, scales, pt, lens, layer, mesh=None):
+    import functools
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import paged_attn
+    if scales:
+        fn = functools.partial(paged_attn._paged_attention_quant_tpu,
+                               interpret=True)
+        scales = [s[layer] for s in scales]
+    else:
+        fn = functools.partial(paged_attn._paged_attention_tpu,
+                               interpret=True)
+    return paged_attn._over_heads(fn, mesh, q, pools, scales, pt, lens,
+                                  jnp.int32(layer))
+
+
+class TestPagedKernelOnTheStoredPool:
+    """The kernel against the float32 fallback at the served head
+    splits, reading layer 1 of a three-layer pool in place.
+
+    Tolerance: q and the pool hold bf16 (or int8 x fp32 scale) values
+    and both sides multiply and sum them in float32, so they differ by
+    the order of the sums (1e-5) and by the kernel's ONE rounding, of
+    its output to bf16: half an ulp, 2**-8 of the value.  A product
+    rounded to bf16 on the way (a ``q . k`` of 64..128 terms at 2**-8
+    each, through the softmax) would show as 1e-1."""
+
+    @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+    @pytest.mark.parametrize("ps", [16, 32])
+    @pytest.mark.parametrize("nh,hd", [(32, 64), (16, 128)])
+    def test_matches_float32_reference(self, nh, hd, ps, quant):
+        import jax.numpy as jnp
+        rng = np.random.RandomState(nh + ps + quant)
+        case = _kernel_case(rng, 3, nh, hd, 3, 7, ps, 3, jnp.bfloat16,
+                            quant)
+        ref = _kernel_reference(*case, layer=1)
+        got = _kernel_run(*case, layer=1)
+        assert got.dtype == jnp.bfloat16 and got.shape == ref.shape
+        over = (jnp.abs(got.astype(jnp.float32) - ref)
+                - (2.0 ** -8 * jnp.abs(ref) + 1e-5))
+        assert float(over.max()) <= 0, float(over.max())
+
+    def test_two_tp_shards(self):
+        """Under a 'tp' mesh each rank runs the kernel on its own
+        contiguous half of the merged axis (its nh/2 heads) and of the
+        scale rows; the halves reassemble the unsharded result."""
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.framework import jax_compat
+        mesh = jax_compat.make_mesh(np.array(jax.devices()[:2]), ("tp",))
+        rng = np.random.RandomState(5)
+        for quant in (False, True):
+            case = _kernel_case(rng, 2, 8, 64, 2, 5, 16, 2, jnp.bfloat16,
+                                quant)
+            whole = _kernel_run(*case, layer=1)
+            split = _kernel_run(*case, layer=1, mesh=mesh)
+            assert jnp.array_equal(whole, split)
+
     @pytest.mark.parametrize("S,nh,hd,P,ps,maxP", [
         (4, 4, 16, 12, 8, 4),
         (2, 2, 64, 6, 16, 2),
         (3, 4, 32, 16, 8, 6),
     ])
-    def test_kernel_matches_lax_fallback(self, S, nh, hd, P, ps, maxP):
+    def test_float32_pool(self, S, nh, hd, P, ps, maxP):
+        """float32 pools (the CPU engines' dtype) take the HIGHEST
+        matmul path: equal to the fallback to summation order."""
         import jax.numpy as jnp
-        from paddle_tpu.ops.pallas.paged_attn import (
-            _paged_attention_tpu, _ref_paged_attention)
         rng = np.random.RandomState(S + P)
-        q = jnp.asarray(rng.randn(S, 1, nh, hd).astype(np.float32))
-        k = jnp.asarray(rng.randn(P, ps, nh, hd).astype(np.float32))
-        v = jnp.asarray(rng.randn(P, ps, nh, hd).astype(np.float32))
-        pt = jnp.asarray(rng.randint(0, P, (S, maxP)).astype(np.int32))
-        lens = jnp.asarray(
-            rng.randint(0, maxP * ps, (S,)).astype(np.int32))
-        ref = _ref_paged_attention(q, k, v, pt, lens)
-        got = _paged_attention_tpu(q, k, v, pt, lens, interpret=True)
-        assert float(jnp.abs(ref - got).max()) < 1e-5
-
-    def test_kernel_len_zero_lane(self):
-        """A lens[s]==0 lane attends only its just-written position —
-        the softmax denominator must not divide by zero."""
-        import jax.numpy as jnp
-        from paddle_tpu.ops.pallas.paged_attn import (
-            _paged_attention_tpu, _ref_paged_attention)
-        rng = np.random.RandomState(7)
-        q = jnp.asarray(rng.randn(2, 1, 2, 16).astype(np.float32))
-        k = jnp.asarray(rng.randn(5, 8, 2, 16).astype(np.float32))
-        v = jnp.asarray(rng.randn(5, 8, 2, 16).astype(np.float32))
-        pt = jnp.asarray(rng.randint(0, 5, (2, 2)).astype(np.int32))
-        lens = jnp.asarray(np.array([0, 9], np.int32))
-        ref = _ref_paged_attention(q, k, v, pt, lens)
-        got = _paged_attention_tpu(q, k, v, pt, lens, interpret=True)
+        case = _kernel_case(rng, S, nh, hd, 2, P, ps, maxP, jnp.float32)
+        ref = _kernel_reference(*case, layer=1)
+        got = _kernel_run(*case, layer=1)
         assert float(jnp.abs(ref - got).max()) < 1e-5

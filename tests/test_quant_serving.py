@@ -520,6 +520,12 @@ class TestDequantMatmulKernel:
         assert float(jnp.abs(ref - got).max()) / denom < 1e-5
 
 
+def _pool_of(pages):
+    """One layer's [P, ps, nh, hd] pages as the one-layer pool the
+    kernel takes: [1, P, ps, nh * hd], the same bytes."""
+    return pages.reshape(1, *pages.shape[:2], -1)
+
+
 @pytest.mark.slow
 class TestPagedAttentionQuantKernel:
     @pytest.mark.parametrize("S,nh,hd,P,ps,maxP", [
@@ -545,8 +551,9 @@ class TestPagedAttentionQuantKernel:
         lens = jnp.asarray(
             rng.randint(0, maxP * ps, (S,)).astype(np.int32))
         ref = _ref_paged_attention_quant(q, kq, ks, vq, vs, pt, lens)
-        got = _paged_attention_quant_tpu(q, kq, ks, vq, vs, pt, lens,
-                                         interpret=True)
+        got = _paged_attention_quant_tpu(
+            q, _pool_of(kq), _pool_of(vq), ks, vs, pt, lens, jnp.int32(0),
+            interpret=True)
         assert float(jnp.abs(ref - got).max()) < 1e-5
 
     def test_kernel_matches_fallback_bf16(self):
@@ -569,8 +576,9 @@ class TestPagedAttentionQuantKernel:
         pt = jnp.asarray(rng.randint(0, 8, (3, 3)).astype(np.int32))
         lens = jnp.asarray(rng.randint(0, 24, (3,)).astype(np.int32))
         ref = _ref_paged_attention_quant(q, kq, ks, vq, vs, pt, lens)
-        got = _paged_attention_quant_tpu(q, kq, ks, vq, vs, pt, lens,
-                                         interpret=True)
+        got = _paged_attention_quant_tpu(
+            q, _pool_of(kq), _pool_of(vq), ks, vs, pt, lens, jnp.int32(0),
+            interpret=True)
         diff = jnp.abs(ref.astype(jnp.float32)
                        - got.astype(jnp.float32))
         # bf16 accumulate: identical dtype semantics, bf16-ulp noise
@@ -591,6 +599,7 @@ class TestPagedAttentionQuantKernel:
         pt = jnp.asarray(rng.randint(0, 5, (2, 2)).astype(np.int32))
         lens = jnp.asarray(np.array([0, 9], np.int32))
         ref = _ref_paged_attention_quant(q, kq, ks, vq, vs, pt, lens)
-        got = _paged_attention_quant_tpu(q, kq, ks, vq, vs, pt, lens,
-                                         interpret=True)
+        got = _paged_attention_quant_tpu(
+            q, _pool_of(kq), _pool_of(vq), ks, vs, pt, lens, jnp.int32(0),
+            interpret=True)
         assert float(jnp.abs(ref - got).max()) < 1e-5

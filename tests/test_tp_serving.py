@@ -64,10 +64,14 @@ class TestTPEngine:
         # the megatron splits shard the overwhelming share of the bytes
         assert per_dev < 0.75 * full, (per_dev, full)
         assert eng.stats()["tp"] == 2
-        # the pool really shards the head axis: each device holds nh/2
+        # the pool really shards the head axis: each device holds the
+        # nh/2 heads' contiguous half of every page row [ps, nh * hd]
         shards = eng._cache_k.addressable_shards
+        cfg = tiny_model[1]
         assert len(shards) == 2
-        assert shards[0].data.shape[3] == tiny_model[1].num_heads // 2
+        assert eng._cache_k.shape[2:] == (eng._page_size,
+                                          cfg.num_heads * cfg.head_dim)
+        assert shards[0].data.shape[3] == cfg.num_heads // 2 * cfg.head_dim
 
     @pytest.mark.slow      # ~18s; tier-1 budget (per-shard bytes
                            # + handoff roundtrip keep tp covered)
