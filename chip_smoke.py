@@ -15,7 +15,10 @@ seeded random weights:
 * serve — ``PagedServingEngine`` over ``gpt.gpt3_1p3b()`` (32 heads x
   64) answering requests of mixed prompt length through
   ``submit``/``step``, once over the bf16 page pool and once over the
-  int8 pool with int8 weights.
+  int8 pool with int8 weights; then the ``deepseek_v3`` family at
+  kanana-2-30b-a3b's widths (8 of 48 layers, every expert) through the
+  same engine over its latent page pool, against its own plain float32
+  reference.
 
 It checks what comes out (falling finite loss, Pallas flash against XLA
 attention, engine logits against the float32 model) and that the Pallas
@@ -73,6 +76,17 @@ FLASH_LOSS_TOL = 5e-4
 # row's own spread, 1.  Logits, not tokens: seeded random weights flip
 # the argmax on rounding (ROADMAP ground rules).
 LOGIT_TOL = {"fp": 0.15, "int8": 0.4}
+# The deepseek_v3 family's rows fall in two kinds (PERF.md section 6,
+# PR 28).  Top-6 of 128 experts is a discrete choice: where the sixth
+# and seventh scores are a near-tie, bf16 rounding of the router's
+# input picks another expert than float32 does and the row moves by a
+# whole expert's output — 0.4 to 1.7 row-std, on the chip and in a
+# bf16-against-float32 run on the CPU alike, in about a third of the
+# rows.  The other rows read 0.06-0.09, as GPT's bf16 engine does.  So
+# the LOWER QUARTILE of the row errors is held to the bf16 bound (a
+# precision slip moves every row, those too), and the worst row to half
+# of what an unrelated row reads (about 6: a paging fault).
+LATENT_QUARTILE_TOL, LATENT_MAX_TOL = 0.15, 3.0
 # what the chip read in PR 21, printed beside every later reading: a
 # precision slip in the paged kernel's reductions (a bf16 rounding of
 # q.k products, say) moves the reading long before it reaches the bound
@@ -112,6 +126,8 @@ class Sizes:
     prompt_lens: tuple
     new_tokens: int
     later_row: int           # which decode step's logits are compared
+    latent_cfg: dict         # the deepseek_v3 family's served config
+    latent_page_size: int
 
 
 FULL = Sizes(
@@ -126,7 +142,12 @@ FULL = Sizes(
     slots=8, max_len=2048,
     seq_buckets=(128, 512, 1024), batch_buckets=(1, 4),
     prompt_lens=(64, 120, 200, 333, 512, 700, 900, 1024),
-    new_tokens=32, later_row=16)
+    new_tokens=32, later_row=16,
+    # kanana-2-30b-a3b at its published widths, cut in depth as the
+    # benchmark's configuration is (1 dense + 7 expert layers, every
+    # expert, the whole vocabulary: 10.1 GB in bf16)
+    latent_cfg=dict(num_hidden_layers=8, max_position_embeddings=2048),
+    latent_page_size=64)
 
 # the no-chip rehearsal: same control flow, toy widths
 REHEARSE = Sizes(
@@ -137,7 +158,14 @@ REHEARSE = Sizes(
     slots=4, max_len=128,
     seq_buckets=(32, 64), batch_buckets=(1, 4),
     prompt_lens=(5, 9, 17, 30, 33, 47, 60, 64),
-    new_tokens=8, later_row=4)
+    new_tokens=8, later_row=4,
+    latent_cfg=dict(vocab_size=512, hidden_size=64, num_hidden_layers=3,
+                    num_attention_heads=4, qk_nope_head_dim=16,
+                    qk_rope_head_dim=8, v_head_dim=16, kv_lora_rank=32,
+                    intermediate_size=96, moe_intermediate_size=24,
+                    n_routed_experts=8, num_experts_per_tok=2,
+                    n_shared_experts=1, max_position_embeddings=128),
+    latent_page_size=16)
 
 
 def emit(**fields):
@@ -338,7 +366,8 @@ def prompts_for(run, cfg, n=None):
             for ln in lens]
 
 
-def serve_requests(run, params, cfg, prompts, *, pool, tp=None):
+def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
+                   page_size=16):
     """Build one engine, warm it, and answer ``prompts`` (then two of
     them again, so the prefix cache has something to hit) as a client
     would: ``submit`` + ``step``.  Returns the finished requests and the
@@ -347,7 +376,7 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None):
 
     sz = run.sz
     kw = (dict(kv_dtype="int8", page_size=32, quant="int8")
-          if pool == "int8" else dict(page_size=16))
+          if pool == "int8" else dict(page_size=page_size))
     kernel_ctr = {"paged": "serving.paged_kernel_calls",
                   "dequant_matmul": "serving.dequant_kernel_calls_matmul"}
     k0 = {k: run.counter(n) for k, n in kernel_ctr.items()}
@@ -526,6 +555,62 @@ def phase_serve(run, params, cfg, pool):
             f"row-std (> {LOGIT_TOL[pool]})")
 
 
+def phase_serve_latent(run):
+    """The deepseek_v3 family (latent page pool, dropless experts)
+    through the same engine: logits of the first and a later generated
+    row against the family's plain float32 reference, upcast a layer at
+    a time from the bf16 weights (their float32 copy does not fit); the
+    latent kernel in the executables; no pool relayout in them."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models import deepseek_v3
+    from paddle_tpu.testing import reference_deepseek_v3 as reference
+
+    cfg = deepseek_v3.DeepseekV3Config(**run.sz.latent_cfg)
+    params = jax.block_until_ready(jax.jit(
+        lambda k: deepseek_v3.init_params(cfg, k))(
+            jax.random.PRNGKey(run.seed + 3)))
+    rows = (0, run.sz.later_row)
+    reqs, report = serve_requests(run, params, cfg, prompts_for(run, cfg),
+                                  pool="latent",
+                                  page_size=run.sz.latent_page_size)
+    rows_of = reference.layer_at_a_time(dataclasses.asdict(cfg))
+    width = max(run.sz.prompt_lens) + max(rows)
+    errs = []
+    with jax.default_matmul_precision("highest"):
+        for r in reqs:
+            seq = np.zeros((width,), np.int32)
+            hist = r.output[:len(r.prompt) + max(rows)]
+            seq[:len(hist)] = hist
+            ref = np.asarray(rows_of(
+                params, jnp.asarray(seq),
+                jnp.asarray([len(r.prompt) - 1 + k for k in rows])))
+            errs.append([row_error(r.logits[k], ref[i])
+                         for i, k in enumerate(rows)])
+    flat = sorted(x for e in errs for x in e)
+    worst, quartile = flat[-1], flat[len(flat) // 4]
+    emit(phase="serve_latent", note="smoke, not a measurement",
+         device_kind=run.kind,
+         shape=dict(hidden=cfg.hidden_size, layers=cfg.num_hidden_layers,
+                    heads=cfg.num_attention_heads,
+                    latent=cfg.kv_lora_rank + cfg.qk_rope_head_dim,
+                    experts=cfg.n_routed_experts,
+                    experts_per_token=cfg.num_experts_per_tok,
+                    vocab=cfg.vocab_size, slots=run.sz.slots,
+                    max_len=run.sz.max_len),
+         logit_rows=rows, logit_err_first=[e[0] for e in errs],
+         logit_err_later=[e[1] for e in errs], logit_err_max=worst,
+         logit_err_lower_quartile=quartile,
+         tol=dict(lower_quartile=LATENT_QUARTILE_TOL, max=LATENT_MAX_TOL),
+         memory=run.memory(), **report)
+    if quartile > LATENT_QUARTILE_TOL or worst > LATENT_MAX_TOL:
+        raise AssertionError(
+            f"serve_latent: logits off the float32 reference by {quartile} "
+            f"row-std at the lower quartile (> {LATENT_QUARTILE_TOL}) or "
+            f"{worst} at worst (> {LATENT_MAX_TOL})")
+
+
 # --------------------------------------------------------------------------
 # --chips 4: the mesh phases and what they are compared with, nothing else
 # --------------------------------------------------------------------------
@@ -674,6 +759,7 @@ def main():
         phase_serve(run, params, cfg, "fp")
         phase_serve(run, params, cfg, "int8")
         del params
+        phase_serve_latent(run)
     emit(phase="compile_cache", dir=cache_dir, **run.counters(),
          total_s=round(time.perf_counter() - t0, 1))
     result = {"ok": True,

@@ -97,6 +97,38 @@ def pool_relayouts(hlo_text, pools):
     return found
 
 
+# What a model family gives the paged engine (models/gpt.py and
+# models/deepseek_v3.py both do): everything else in this file is the
+# same scheduler, pager, spans and counters for every family.
+FAMILY_INTERFACE = (
+    "check_serving",            # raise, by name, for unbuilt compositions
+    "shard_params_for_serving", "kv_pool_spec",
+    "init_paged_pools",         # the donated pool arrays, operand order
+    "prefill_paged", "chunk_paged", "decode_paged",
+    "kv_bytes_per_position", "prefix_salt")
+# (a family whose ``decode_paged`` returns counts beside the logits also
+# gives ``decode_extra_stats(cfg, flat) -> {counter: increment}``)
+
+
+def family_of(cfg):
+    """The model family that serves ``cfg``: the module that defines
+    its config class.  Selected by the type of ``cfg`` alone — no
+    engine argument names a family."""
+    import sys
+    mod = sys.modules[type(cfg).__module__]
+    missing = [n for n in FAMILY_INTERFACE if not hasattr(mod, n)]
+    if missing:
+        raise TypeError(
+            f"{type(cfg).__name__} ({mod.__name__}) is not a served model "
+            f"family: the module lacks {missing}")
+    return mod
+
+
+def _cfg_of(model):
+    return model[1] if (isinstance(model, (tuple, list))
+                        and len(model) == 2) else model.cfg
+
+
 class ServingQueueFull(RuntimeError):
     """submit() back-pressure: the bounded admission queue is at
     ``max_queue`` — callers must retry/shed, exactly like a 429."""
@@ -145,6 +177,13 @@ def _stats_family():
         # Pallas paged-attention kernel instantiations, fp and int8
         # pools alike (same trace-time meaning; 0 off-TPU)
         "paged_kernel_calls": 0,
+        # expert-layer family (models/deepseek_v3.py; zero elsewhere):
+        # what the decode step counts on the device and hands back
+        # with its sampled tokens — assignments routed by the active
+        # slots, distinct experts hit and the fullest expert's load,
+        # each summed over expert layers and decode steps
+        "moe_assignments": 0, "moe_experts_touched": 0,
+        "moe_max_expert_load": 0,
         # speculative-decoding family (SpeculativeServingEngine,
         # ISSUE 13; zero on non-speculative engines): candidates the
         # drafter proposed, how many of those the verify accepted /
@@ -317,6 +356,11 @@ class ServingEngine:
             from ..ops import dispatch as _dispatch
             params = _dispatch.unwrap(model._tree())
         self.cfg = cfg
+        self._family = family_of(cfg)
+        self._family.check_serving(
+            cfg, engine=type(self).__name__, quant=quant,
+            tp=tp or os.environ.get("PADDLE_SERVE_TP") or 1,
+            pp=pp or os.environ.get("PADDLE_SERVE_PP") or 1)
         # weight-only quantization (ISSUE 9): the param pytree is
         # quantized ONCE here — every executable built below closes over
         # int8/fp8 weights + scales as ordinary pytree operands, and
@@ -376,9 +420,10 @@ class ServingEngine:
                     f"{self._pp} — stages take contiguous equal layer "
                     "ranges (distributed/auto/pipeline.py)")
             self._mesh = gpt.serving_mesh(self._tp, pp=self._pp)
-            params, self._param_specs = gpt.shard_params_for_serving(
-                params, cfg, self._mesh)
-        self._kv_spec = gpt.kv_pool_spec(self._mesh)
+            params, self._param_specs = (
+                self._family.shard_params_for_serving(params, cfg,
+                                                      self._mesh))
+        self._kv_spec = self._family.kv_pool_spec(self._mesh)
         self.params = params
 
         self.slots = int(slots)
@@ -1246,6 +1291,7 @@ class ServingEngine:
         "requests_completed", "tokens_generated",
         "prefill_chunks", "prefix_page_hits", "prefix_page_misses",
         "cow_copies", "preemptions", "quant_matmuls",
+        "moe_assignments", "moe_experts_touched", "moe_max_expert_load",
         "drafted_tokens", "accepted_tokens", "rejected_tokens",
         "spec_steps", "kv_extracts", "kv_injects", "kv_handoff_bytes",
         "pages_spilled", "spill_bytes", "pages_faulted_back",
@@ -1450,6 +1496,13 @@ class PagedServingEngine(ServingEngine):
         from .kv_pager import KVPager, PagesExhausted  # noqa: F401
         self._KVPager, self._PagesExhausted = KVPager, PagesExhausted
         self._page_size = int(page_size)
+        cfg = _cfg_of(model)
+        family_of(cfg).check_serving(
+            cfg, engine=type(self).__name__, kv_dtype=kv_dtype,
+            kv_handoff=kv_handoff,
+            host_tier_mb=(host_tier_mb if host_tier_mb is not None
+                          else os.environ.get("PADDLE_KV_HOST_TIER_MB")
+                          or 0))
         # host-RAM page tier (ISSUE 17): evicted prefix pages spill
         # their bytes (hash-stamped) into a byte-bounded host LRU, and
         # a later prefix hit on the spilled chain faults them back
@@ -1580,7 +1633,8 @@ class PagedServingEngine(ServingEngine):
             self._num_pages, ps, self.slots,
             prefix_cache=self._prefix_cache_on,
             hash_key=f"quant={self.quant or 'none'}"
-                     f"/kv={'int8' if self._kv_quant else 'fp'}")
+                     f"/kv={'int8' if self._kv_quant else 'fp'}"
+                     f"{self._family.prefix_salt(self.cfg)}")
         # host-tier spill capture rides the pager's eviction hook; the
         # tier itself SURVIVES rebuilds (its entries are content-
         # addressed host bytes, valid independent of device state)
@@ -1593,30 +1647,23 @@ class PagedServingEngine(ServingEngine):
             self._host_tier = _HostKVTier(
                 int(self._host_tier_mb * (1 << 20)),
                 hash_key=self._pager.hash_key)
-        if self._kv_quant:
-            cache = gpt.init_paged_cache_quant(self.cfg, self._num_pages,
-                                               ps, mesh=self._mesh)
-            self._cache_ks = cache["k_scale"]
-            self._cache_vs = cache["v_scale"]
-            if not self._kv_saved_counted:
-                # bytes the int8+scale pool saves vs the SAME pool at
-                # the compute dtype (what a rebuild without kv_dtype
-                # would have allocated) — counted once, not per rebuild.
-                # The first build happens inside the base constructor
-                # before the counters exist; park it for __init__'s tail.
-                fp_bytes = 2 * (cache["k"].size
-                                * self._jnp.dtype(self._cache_dtype
-                                                  or self.cfg.dtype).itemsize)
-                q_bytes = sum(int(cache[n].nbytes) for n in
-                              ("k", "v", "k_scale", "v_scale"))
-                self._kv_saved_pending = max(0, fp_bytes - q_bytes)
-                self._kv_saved_counted = True
-        else:
-            cache = gpt.init_paged_cache(self.cfg, self._num_pages, ps,
-                                         dtype=self._cache_dtype,
-                                         mesh=self._mesh)
-            self._cache_ks = self._cache_vs = None
-        self._cache_k, self._cache_v = cache["k"], cache["v"]
+        pools = self._family.init_paged_pools(
+            self.cfg, self._num_pages, ps, dtype=self._cache_dtype,
+            mesh=self._mesh, kv_quant=self._kv_quant)
+        self._cache_ks = self._cache_vs = None
+        self._set_cache(pools)
+        if self._kv_quant and not self._kv_saved_counted:
+            # bytes the int8+scale pool saves vs the SAME pool at
+            # the compute dtype (what a rebuild without kv_dtype
+            # would have allocated) — counted once, not per rebuild.
+            # The first build happens inside the base constructor
+            # before the counters exist; park it for __init__'s tail.
+            fp_bytes = 2 * (self._cache_k.size
+                            * self._jnp.dtype(self._cache_dtype
+                                              or self.cfg.dtype).itemsize)
+            q_bytes = sum(int(a.nbytes) for a in pools)
+            self._kv_saved_pending = max(0, fp_bytes - q_bytes)
+            self._kv_saved_counted = True
         self._tables_np = np.zeros((self.slots, self._pages_per_slot),
                                    np.int32)
         self._chunk_jobs.clear()
@@ -1804,22 +1851,13 @@ class PagedServingEngine(ServingEngine):
             self._h_prefill.observe(wave.dur)
 
     def _build_prefill(self, b, s):
-        """Paged prefill executable: causal forward over the padded
-        prompts, then one batched scatter of the filled K/V page chunks
-        into the DONATED pool through the page tables (pad rows target
-        the scratch page; shared pages receive content identical to
-        what they already hold, so duplicate indices are benign).
-
-        With ``kv_dtype="int8"`` the forward still runs — and attends
-        its own prompt — in the compute dtype; the K/V QUANTIZE ON
-        WRITE (per-position-per-head absmax, models/gpt.py::quantize_kv)
-        as they scatter into the int8 pool, scales landing in the scale
-        arrays at the same page coordinates.  Quantization error only
-        ever enters on later reads."""
+        """Paged prefill executable: the family's causal forward over
+        the padded prompts, writing the DONATED pool through the page
+        tables (``prefill_paged`` of models/gpt.py or
+        models/deepseek_v3.py), then the first token of each row."""
         jax, jnp = self._jax, self._jnp
         cfg = self.cfg
         ps = self._page_size
-        pr = s // ps
         cap = self.capture_logits
         kvq = self._kv_quant
 
@@ -1844,49 +1882,20 @@ class PagedServingEngine(ServingEngine):
             donate = ((1, 2) if _donation_enabled() else ())
             return jax.jit(prefill_pp, donate_argnums=donate)
 
+        n = self._n_cache
+        family = self._family
+
         def prefill(params, *args):
-            if kvq:
-                cache_k, k_scale, cache_v, v_scale = args[:4]
-                tokens, lens, ptab = args[4:]
-                fresh = gpt.init_cache(cfg, b, s,
-                                       dtype=jnp.dtype(cfg.dtype))
-            else:
-                cache_k, cache_v = args[:2]
-                tokens, lens, ptab = args[2:]
-                fresh = gpt.init_cache(cfg, b, s, dtype=cache_k.dtype)
-            logits, filled = gpt.forward_cached(params, tokens, cfg, fresh)
-            L = cfg.num_layers
-            flat = ptab.reshape(-1)
-
-            def chunks(x):
-                # [L, b, s, ...] -> page chunks [L, b*pr, ps, nh*hd | nh]:
-                # rows as the pool stores them, written where it lies
-                return x.reshape(L, b * pr, ps, -1)
-
-            with jax.named_scope("kv_scatter"):
-                if kvq:
-                    fkq, fks = gpt.quantize_kv(filled["k"])
-                    fvq, fvs = gpt.quantize_kv(filled["v"])
-                    cache_k = cache_k.at[:, flat].set(chunks(fkq))
-                    k_scale = k_scale.at[:, flat].set(chunks(fks))
-                    cache_v = cache_v.at[:, flat].set(chunks(fvq))
-                    v_scale = v_scale.at[:, flat].set(chunks(fvs))
-                    out_cache = (cache_k, k_scale, cache_v, v_scale)
-                else:
-                    cache_k = cache_k.at[:, flat].set(chunks(filled["k"]))
-                    cache_v = cache_v.at[:, flat].set(chunks(filled["v"]))
-                    out_cache = (cache_k, cache_v)
-                out_cache = self._constrain_cache(out_cache)
+            tokens, lens, ptab = args[n:]
+            last, out_cache = family.prefill_paged(
+                params, cfg, args[:n], tokens, lens, ptab, kv_quant=kvq)
+            out_cache = self._constrain_cache(out_cache)
             with jax.named_scope("head_sample"):
-                idx = jnp.clip(lens - 1, 0, s - 1)
-                last = jnp.take_along_axis(
-                    logits, idx[:, None, None], axis=1)[:, 0]  # [b, V]
                 first_tok = jnp.argmax(last, -1).astype(jnp.int32)
             if cap:
                 return (*out_cache, first_tok, last)
             return (*out_cache, first_tok)
 
-        n = self._n_cache
         donate = tuple(range(1, 1 + n)) if _donation_enabled() else ()
         return self._jax.jit(prefill, donate_argnums=donate)
 
@@ -1995,23 +2004,21 @@ class PagedServingEngine(ServingEngine):
         """ONE executable serves every chunk of every long prompt: the
         absolute position offset and the chunk's true token count are
         traced scalars, so chunk index never changes the signature.
-        int8 pools route through ``gpt.forward_paged_chunk_quant``
-        (dequantized gather view in, quantized chunk-only scatter
+        The family's ``chunk_paged`` is the program (GPT's int8 pool:
+        dequantized gather view in, quantized chunk-only scatter
         out)."""
         jax, jnp = self._jax, self._jnp
         cfg = self.cfg
         cap = self.capture_logits
         kvq = self._kv_quant
 
+        n = self._n_cache
+        family = self._family
+
         def chunk(params, *args):
-            if kvq:
-                cache, (toks, ptab_row, offset, tlen) = args[:4], args[4:]
-                logits, *cache = gpt.forward_paged_chunk_quant(
-                    params, toks, cfg, *cache, ptab_row, offset)
-            else:
-                cache, (toks, ptab_row, offset, tlen) = args[:2], args[2:]
-                logits, *cache = gpt.forward_paged_chunk(
-                    params, toks, cfg, *cache, ptab_row, offset)
+            toks, ptab_row, offset, tlen = args[n:]
+            logits, cache = family.chunk_paged(
+                params, cfg, args[:n], toks, ptab_row, offset, kv_quant=kvq)
             last = jax.lax.dynamic_index_in_dim(logits[0], tlen - 1, 0,
                                                 keepdims=False)    # [V]
             tok = jnp.argmax(last, -1).astype(jnp.int32)
@@ -2606,6 +2613,12 @@ class PagedServingEngine(ServingEngine):
                 # of the paged decode loop
                 # ptl: disable-next=PTL004 -- sampled-token readback
                 nxt_np = np.asarray(nxt)
+                if nxt_np.shape[0] > self.slots:
+                    # the family's per-step counts, behind the tokens
+                    extra = self._family.decode_extra_stats(
+                        self.cfg, nxt_np[self.slots:])
+                    for k, v in extra.items():
+                        self._inc(k, v)
             with timeline.span("serving.decode.commit"):
                 for s in range(self.slots):
                     if not self._active[s]:
@@ -2675,16 +2688,21 @@ class PagedServingEngine(ServingEngine):
             donate = ((1, 2) if _donation_enabled() else ())
             return jax.jit(decode_pp, donate_argnums=donate)
 
+        n = self._n_cache
+        family = self._family
+
         def decode(params, *args):
-            n = 4 if kvq else 2
-            cache = args[:n]
             page_table, wpages, woffs, lens, toks = args[n:]
-            step = (gpt.decode_step_paged_quant if kvq
-                    else gpt.decode_step_paged)
-            logits, *cache = step(params, toks, cfg, *cache, page_table,
-                                  wpages, woffs, lens, mesh=self._mesh)
+            logits, cache, extra = family.decode_paged(
+                params, cfg, args[:n], page_table, wpages, woffs, lens,
+                toks, mesh=self._mesh, kv_quant=kvq)
             with jax.named_scope("head_sample"):
                 nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+                if extra is not None:
+                    # what the family counts a step (expert loads) rides
+                    # the sampled tokens' readback: one array, one sync
+                    nxt = jnp.concatenate(
+                        [nxt, extra.reshape(-1).astype(jnp.int32)])
             cache = self._constrain_cache(cache)
             if cap:
                 return (*cache, nxt, logits)
@@ -2813,6 +2831,10 @@ class PagedServingEngine(ServingEngine):
             int(getattr(r, "_chunk_pos", 0)) for r in self._chunk_jobs)
         return {"kv_bytes_reserved": int(in_use * page_bytes),
                 "kv_bytes_total": total,
+                # what a cached position NEEDS (the family's count), not
+                # what its page row occupies (kv_bytes_total / positions)
+                "kv_bytes_per_position": self._family.kv_bytes_per_position(
+                    self.cfg, self._cache_k.dtype.itemsize),
                 "kv_tokens_held": held,
                 "page_utilization": round(held / max(1, in_use * ps), 4)}
 

@@ -1154,6 +1154,101 @@ def forward_paged_chunk_quant(params, tokens, cfg: GPTConfig, cache_k,
 
 
 # --------------------------------------------------------------------------
+# the paged engine's family interface
+# --------------------------------------------------------------------------
+#
+# What differs by model family behind ``PagedServingEngine``
+# (inference/serving.py::family_of names the functions and finds the
+# module by the type of ``cfg``): the pools, the three paged programs,
+# what a cached position costs, the prefix-hash salt, and which engine
+# compositions exist.  ``pools`` is the donated pool arrays in operand
+# order: (k, v), or (k, k_scale, v, v_scale) for the int8 pool.
+
+def check_serving(cfg, **composition):
+    """Every engine composition is built for this family; the engine's
+    own checks (pp x quant, chunk sizes, ...) name the rest."""
+
+
+def prefix_salt(cfg):
+    return ""
+
+
+def kv_bytes_per_position(cfg: GPTConfig, itemsize):
+    """Bytes of K and V one cached position holds, all layers."""
+    return 2 * cfg.num_layers * cfg.hidden_size * itemsize
+
+
+def init_paged_pools(cfg: GPTConfig, num_pages, page_size, dtype=None,
+                     mesh=None, kv_quant=False):
+    if kv_quant:
+        c = init_paged_cache_quant(cfg, num_pages, page_size, mesh=mesh)
+        return (c["k"], c["k_scale"], c["v"], c["v_scale"])
+    c = init_paged_cache(cfg, num_pages, page_size, dtype=dtype, mesh=mesh)
+    return (c["k"], c["v"])
+
+
+def prefill_paged(params, cfg: GPTConfig, pools, tokens, lens, ptab,
+                  kv_quant=False):
+    """Causal forward over the padded prompts ``tokens`` [b, s], then
+    one batched scatter of the filled K/V page chunks into the pools
+    through the page tables ``ptab`` [b, s / page_size] (pad rows target
+    the scratch page; shared pages receive content identical to what
+    they already hold, so duplicate indices are benign).  Returns
+    (logits of each row's last true position [b, V], pools).
+
+    On the int8 pool the forward still runs — and attends its own
+    prompt — in the compute dtype; K/V QUANTIZE ON WRITE
+    (:func:`quantize_kv`), scales landing in the scale arrays at the
+    same page coordinates.  Quantization error only ever enters on
+    later reads."""
+    b, s = tokens.shape
+    ps = pools[0].shape[2]
+    L = cfg.num_layers
+    fresh = init_cache(cfg, b, s, dtype=jnp.dtype(cfg.dtype) if kv_quant
+                       else pools[0].dtype)
+    logits, filled = forward_cached(params, tokens, cfg, fresh)
+    flat = ptab.reshape(-1)
+
+    def chunks(x):
+        # [L, b, s, ...] -> page chunks [L, b * s/ps, ps, nh*hd | nh]:
+        # rows as the pool stores them, written where it lies
+        return x.reshape(L, b * (s // ps), ps, -1)
+
+    with jax.named_scope("kv_scatter"):
+        if kv_quant:
+            new = (*quantize_kv(filled["k"]), *quantize_kv(filled["v"]))
+        else:
+            new = (filled["k"], filled["v"])
+        pools = tuple(p.at[:, flat].set(chunks(x))
+                      for p, x in zip(pools, new))
+    with jax.named_scope("head_sample"):
+        idx = jnp.clip(lens - 1, 0, s - 1)
+        last = jnp.take_along_axis(logits, idx[:, None, None],
+                                   axis=1)[:, 0]
+    return last, pools
+
+
+def chunk_paged(params, cfg: GPTConfig, pools, tokens, pt_row, offset,
+                kv_quant=False):
+    """(logits [1, C, V], pools): :func:`forward_paged_chunk` or its
+    int8 twin."""
+    fn = forward_paged_chunk_quant if kv_quant else forward_paged_chunk
+    logits, *pools = fn(params, tokens, cfg, *pools, pt_row, offset)
+    return logits, tuple(pools)
+
+
+def decode_paged(params, cfg: GPTConfig, pools, page_table, write_pages,
+                 write_offs, lens, tokens, mesh=None, kv_quant=False):
+    """(logits [S, V], pools, None): :func:`decode_step_paged` or its
+    int8 twin.  The third value is what a family returns WITH the
+    sampled tokens in the step's one readback; this one has nothing."""
+    step = decode_step_paged_quant if kv_quant else decode_step_paged
+    logits, *pools = step(params, tokens, cfg, *pools, page_table,
+                          write_pages, write_offs, lens, mesh=mesh)
+    return logits, tuple(pools), None
+
+
+# --------------------------------------------------------------------------
 # speculative verify + draft plumbing (ISSUE 13)
 # --------------------------------------------------------------------------
 #

@@ -247,3 +247,87 @@ def test_kernel_names_and_scopes_reach_the_compiled_program(chip):
     (call,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert call.lstrip().startswith("%paged_attn_decode")
     assert 'op_name="jit(layer)/layer/paged_attn/paged_attn_decode' in call
+
+
+# --------------------------------------------------------------------------
+# the deepseek_v3 family at kanana-2-30b-a3b's published widths, cut in
+# depth as benchmark/configs/kanana2-30b-a3b-serve.json is (ISSUE 28)
+# --------------------------------------------------------------------------
+
+KANANA_SLOTS, KANANA_PS, KANANA_PAGES, KANANA_LAYERS = 64, 64, 1017, 8
+# the sizing rule the configuration states: both programs, weights and
+# pool included, under 90% of the chip's 15.75 GiB bytes_limit
+KANANA_BUDGET = int(16_909_336_064 * 0.9)
+
+
+def test_paged_mla_decode_kernel(chip):
+    """The latent kernel alone at 64 slots x 32 pages of 64: 32 heads
+    against one shared 512 + 64 wide key."""
+    from paddle_tpu.ops.pallas.paged_mla import _paged_mla_tpu
+    maxp = MAX_LEN // KANANA_PS
+    fn = functools.partial(_paged_mla_tpu, scale=192 ** -0.5)
+    text = jax.jit(fn).lower(
+        chip((KANANA_SLOTS, 32, 512), bf16), chip((KANANA_SLOTS, 32, 64), bf16),
+        chip((KANANA_LAYERS, KANANA_PAGES, KANANA_PS, 512), bf16),
+        chip((KANANA_LAYERS, KANANA_PAGES, KANANA_PS, 128), bf16),
+        chip((KANANA_SLOTS, maxp), i32), chip((KANANA_SLOTS,), i32),
+        chip((), i32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "paged_mla_decode" in text
+
+
+@pytest.fixture
+def served_kanana(chip, monkeypatch):
+    """(engine, params, pools) of the benchmark's own configuration,
+    shapes for weights."""
+    from paddle_tpu.inference.serving import PagedServingEngine
+    from paddle_tpu.models import deepseek_v3
+    from paddle_tpu.ops.pallas import utils as pallas_utils
+    monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
+    monkeypatch.setenv("PADDLE_TPU_SERVING_DONATE", "1")
+    cfg = deepseek_v3.DeepseekV3Config(
+        num_hidden_layers=KANANA_LAYERS, max_position_embeddings=MAX_LEN)
+    params = jax.tree_util.tree_map(
+        lambda x: chip(x.shape, x.dtype),
+        jax.eval_shape(lambda k: deepseek_v3.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    eng = PagedServingEngine(
+        (params, cfg), slots=KANANA_SLOTS, max_len=MAX_LEN,
+        page_size=KANANA_PS, num_pages=2, seq_buckets=(128, 512, 1024),
+        batch_buckets=(1, 4), capture_logits=False)
+    pools = tuple(chip(s, bf16) for s in deepseek_v3.paged_pool_shapes(
+        cfg, KANANA_PAGES, KANANA_PS))
+    return eng, params, pools
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_4x1024"])
+def test_kanana_serving_programs_fit_and_hold_the_pool_in_place(
+        chip, served_kanana, program):
+    from paddle_tpu.inference.serving import pool_relayouts
+    eng, params, pools = served_kanana
+    if program == "decode":
+        fn = eng._build_decode()
+        args = (chip((KANANA_SLOTS, MAX_LEN // KANANA_PS), i32),
+                *[chip((KANANA_SLOTS,), i32)] * 4)
+    else:
+        fn = eng._build_prefill(4, 1024)
+        args = (chip((4, 1024), i32), chip((4,), i32),
+                chip((4, 1024 // KANANA_PS), i32))
+    compiled = fn.lower(params, *pools, *args).compile()
+    text = compiled.as_text()
+    assert pool_relayouts(text, pools) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools)
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total <= KANANA_BUDGET, total / 2 ** 30
+    if program == "decode":
+        # layer 0 and the scan's body: the latent kernel twice, and no
+        # expert stack sliced out for the grouped matmul (1.2 GB a layer
+        # of temporaries when it was)
+        assert text.count("paged_mla_decode") >= 2
+        assert mem.temp_size_in_bytes < (64 << 20)
+    for scope in ("mla_q", "mla_latent", "mla_attn", "mla_out",
+                  "moe_router", "moe_routed", "moe_shared", "dense_mlp"):
+        assert scope in text, scope
