@@ -396,13 +396,19 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
     c1 = run.counters()
 
     t0 = time.perf_counter()
+    live_share = []    # of the decode kernel's grid steps, step by step
+
+    def answer(reqs):
+        while not all(r.done for r in reqs):
+            eng.step()
+            live_share.append(
+                eng.stats().get("paged_attn_live_step_share"))
+
     reqs = [eng.submit(p, sz.new_tokens) for p in prompts]
-    while not all(r.done for r in reqs):
-        eng.step()
+    answer(reqs)
     again = [eng.submit(prompts[i], sz.new_tokens)
              for i in (0, len(prompts) // 2)]
-    while not all(r.done for r in again):
-        eng.step()
+    answer(again)
     traffic_s = time.perf_counter() - t0
     c2 = run.counters()
     st = eng.stats()
@@ -434,6 +440,10 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
         "matmul": ("pallas_dequant" if engaged["dequant_matmul"]
                    else "xla"),
         "kernel_instances": engaged,
+        # (None for a family whose kernel is not paged_attn_decode)
+        "paged_attn_group_pages": st.get("paged_attn_group_pages"),
+        "paged_attn_live_step_share": (None if None in live_share
+                                       else max(live_share)),
         "pool_relayouts": {k: len(v) for k, v in relayouts.items()},
         "requests": len(reqs) + len(again),
         "prompt_lens": [len(p) for p in prompts],

@@ -107,7 +107,9 @@ FAMILY_INTERFACE = (
     "prefill_paged", "chunk_paged", "decode_paged",
     "kv_bytes_per_position", "prefix_salt")
 # (a family whose ``decode_paged`` returns counts beside the logits also
-# gives ``decode_extra_stats(cfg, flat) -> {counter: increment}``)
+# gives ``decode_extra_stats(cfg, flat) -> {counter: increment}``; one
+# whose decode kernel's grid is (slot, page group) by a rule of shapes
+# gives ``decode_group_pages(cfg, pools, table_width, tp) -> G``)
 
 
 def family_of(cfg):
@@ -2847,6 +2849,18 @@ class PagedServingEngine(ServingEngine):
         for k in ("prefix_page_hits", "prefix_page_misses", "cow_copies"):
             pg.pop(k)    # the engine-mirrored (warmup-quiet) counts win
         out.update(pg)
+        group_of = getattr(self._family, "decode_group_pages", None)
+        if group_of is not None:
+            # the decode kernel's grid is slots x (table width / G) steps
+            # a layer; a step is live while its first row is at or
+            # under the slot's length
+            G = group_of(self.cfg, self._cache_operands(),
+                         self._pages_per_slot, self._tp)
+            live = self._lens[self._active] // (G * self._page_size) + 1
+            out["paged_attn_group_pages"] = G
+            out["paged_attn_live_step_share"] = round(
+                int(live.sum())
+                / (self.slots * (self._pages_per_slot // G)), 4)
         tier = self._host_tier
         out["host_tier_bytes"] = int(tier.bytes) if tier else 0
         out["host_tier_entries"] = len(tier) if tier else 0

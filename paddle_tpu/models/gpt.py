@@ -1178,6 +1178,15 @@ def kv_bytes_per_position(cfg: GPTConfig, itemsize):
     return 2 * cfg.num_layers * cfg.hidden_size * itemsize
 
 
+def decode_group_pages(cfg: GPTConfig, pools, table_width, tp=1):
+    """Pages a grid step of the decode kernel takes over these pools
+    (what one 'tp' rank runs; ops/pallas/paged_attn.py::group_pages)."""
+    from ..ops.pallas.paged_attn import group_pages
+    page_size, width = pools[0].shape[2:]
+    return group_pages(table_width, page_size, width // tp,
+                       pools[0].dtype.itemsize, cfg.num_heads // tp)
+
+
 def init_paged_pools(cfg: GPTConfig, num_pages, page_size, dtype=None,
                      mesh=None, kv_quant=False):
     if kv_quant:

@@ -8,12 +8,12 @@ a page is ``page_size`` rows of all heads side by side, so the minor
 axis is a multiple of the 128 lanes and the device keeps the array
 major-to-minor with no padding), and each decode lane's logical sequence
 is the concatenation of the pages its table names.  The TPU kernel takes
-the WHOLE pool and the layer index and streams one *physical page* per
-grid step — layer and page id come through scalar prefetch, so the
-BlockSpec index map turns the logical ``(slot, page_j)`` coordinate into
-the physical page's HBM block and Mosaic DMAs exactly the pages a lane
-references: the pool is never sliced, transposed or copied around the
-call.
+the WHOLE pool and the layer index, which stay in HBM, and works on a
+GROUP of physical pages per grid step as one block of rows — layer,
+page table and lengths come through scalar prefetch, and the kernel
+copies exactly the pages a lane's length reaches, the next step's under
+this step's arithmetic: the pool is never sliced, transposed or copied
+around the call, and a dead table entry is never read.
 
 A pure-lax fallback (gather pages into the contiguous per-slot view,
 then the exact `_slot_block` masked-attention math) serves
@@ -105,38 +105,79 @@ def _dot_f32(a, b, contract_b):
                                precision=jax.lax.Precision.HIGHEST)
 
 
-def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, k_ref, *rest,
-                         page_size, head_dim, quant):
-    """Grid (slot, page_j).  One physical page of K/V per step, online
-    softmax across a lane's pages exactly like flash_attn's streamed
+def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, *rest,
+                         page_size, head_dim, group, table_width, quant):
+    """Grid (slot, page group).  A step works on ``group`` physical
+    pages of K and of V as ONE block of rows, [group * ps, C], online
+    softmax across a lane's groups exactly like flash_attn's streamed
     K-blocks.  q_ref: [1, C] with C = nh * hd, every head's query side
-    by side; k_ref/v_ref: [ps, C] — the page the scalar-prefetched table
-    names for this (slot, j) in the layer ``layer_ref`` names, as the
-    pool stores it.  With ``quant`` the pages are int8 and each is
-    followed by its [ps, nh] fp32 scale block (HBM traffic per page is
-    1 byte/element plus the scale row).
+    by side.  The K and V pools stay in HBM (``pl.ANY``) and the kernel
+    copies the pages itself, each to its rows of a twice-buffered
+    block: a live step first starts the copies of the NEXT live step
+    (the slot's next group, or the next slot's first), then waits for
+    its own, so the copies run under the arithmetic; only pages at or
+    under the slot's length are copied, a dead table entry is never
+    dereferenced, and a group wholly past the length costs a grid step
+    and nothing else.  (One BlockSpec a page instead pays its index map
+    and the pipeline's bookkeeping for every entry of the table, live
+    or not: 0.41 ms of a 0.65 ms call; PERF.md section 6, PR 29.)  Rows
+    past the length in the last live group are whatever the buffer held
+    — pool rows, or the zeros it starts with — and weigh exactly zero.
+    With ``quant`` the pages are int8 and their fp32 scale rows follow
+    the pools as ``group`` [ps, nh] blocks of K's and ``group`` of V's:
+    32 heads on the minor axis is no shape a copy out of HBM takes, so
+    those come through BlockSpecs (a page past the last live one
+    re-names that one).
 
     The per-head reduction is the block-diagonal trick: the slot's query
     row is spread into ``qbd [nh, C]`` (row h holds head h's query over
-    its own hd columns and zeros elsewhere), so ``qbd . page^T`` is
+    its own hd columns and zeros elsewhere), so ``qbd . rows^T`` is
     flash attention's NT matmul and gives scores[h, p] with every
-    off-head product an exact zero; ``p . page_v`` gives [nh, C] of
+    off-head product an exact zero; ``p . rows_v`` gives [nh, C] of
     which row h's own hd columns are head h's output, and the rest is
     dropped at the end.  The MXU does nh times the needed products of a
     matrix-VECTOR problem it would otherwise idle through, and nothing
     is reshaped or moved between lanes and sublanes, whatever the head
-    split.  Products and sums are float32 (:func:`_dot_f32`); int8 pages
-    widen to bf16 exactly, and their scales are [ps, nh] like the
+    split.  Products and sums are float32 (:func:`_dot_f32`); int8 rows
+    widen to bf16 exactly, and their scales are [rows, nh] like the
     transposed scores and probabilities, so they fold in there
     (``q . (k*s) == (q . k) * s``) and the pages are never dequantized
     elementwise."""
+    G, ps = group, page_size
+    pools, rest = rest[:2], rest[2:]
     if quant:
-        ks_ref, v_ref, vs_ref, o_ref, qbd_scr, m_scr, l_scr, acc_scr = rest
-    else:
-        v_ref, o_ref, qbd_scr, m_scr, l_scr, acc_scr = rest
+        ks_refs, vs_refs, rest = rest[:G], rest[G:2 * G], rest[2 * G:]
+    o_ref, k_buf, v_buf, sem, turn, qbd_scr, m_scr, l_scr, acc_scr = rest
+    bufs = (k_buf, v_buf)
     s = pl.program_id(0)
     j = pl.program_id(1)
     nh, C = acc_scr.shape
+    first = j * G * ps
+    ln = lens_ref[s]
+
+    def copies(slot_s, group_j, buf, start):
+        """Start, or wait for, the copy of each live page of
+        (slot_s, group_j) into half ``buf`` of the block buffers."""
+        last = lens_ref[slot_s] // ps
+        for g in range(G):
+            entry = group_j * G + g
+
+            @pl.when(entry <= last)
+            def _page(g=g, entry=entry):
+                # a wait needs the copy's size, not its source
+                page = pt_ref[slot_s * table_width + entry] if start else 0
+                for i, (pool, rows) in enumerate(zip(pools, bufs)):
+                    copy = pltpu.make_async_copy(
+                        pool.at[layer_ref[0], page],
+                        rows.at[buf, pl.ds(g * ps, ps)], sem.at[buf, i])
+                    copy.start() if start else copy.wait()
+
+    @pl.when((s == 0) & (j == 0))
+    def _first():
+        for rows in bufs:
+            rows[:] = jnp.zeros_like(rows)
+        turn[0] = 0
+        copies(0, 0, 0, start=True)
 
     @pl.when(j == 0)
     def _init():
@@ -147,34 +188,45 @@ def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, k_ref, *rest,
         qbd_scr[:] = jnp.where(_head_mask(nh, head_dim), q,
                                0.0).astype(qbd_scr.dtype)
 
-    ln = lens_ref[s]
-    # pages entirely past the fill bound contribute nothing; skipping
+    # groups entirely past the fill bound contribute nothing; skipping
     # them is the paged analogue of the causal block skip
-    @pl.when(j * page_size <= ln)
+    @pl.when(first <= ln)
     def _body():
-        k = k_ref[:]                                     # [ps, C]
-        v = v_ref[:]
+        buf = turn[0]
+        more = (first + G * ps <= ln) & (j + 1 < pl.num_programs(1))
+
+        @pl.when(more)
+        def _next_group():
+            copies(s, j + 1, 1 - buf, start=True)
+
+        @pl.when(jnp.logical_not(more) & (s + 1 < pl.num_programs(0)))
+        def _next_slot():
+            copies(s + 1, 0, 1 - buf, start=True)
+
+        copies(s, j, buf, start=False)
+        turn[0] = 1 - buf
+
+        k, v = k_buf[buf], v_buf[buf]                    # [G * ps, C]
         if quant:
             k = k.astype(jnp.bfloat16)
             v = v.astype(jnp.bfloat16)
         # scores[h, p] = q[h, :] . k[p, h, :]
         scr = _dot_f32(qbd_scr[:], k, 1) / math.sqrt(head_dim)
         if quant:
-            scr = scr * ks_ref[:].T
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, scr.shape, 1)
+            scr = scr * jnp.concatenate([r[:] for r in ks_refs], 0).T
+        pos = first + jax.lax.broadcasted_iota(jnp.int32, scr.shape, 1)
         scr = jnp.where(pos <= ln, scr, NEG_INF)
 
         m_prev = m_scr[:, :1]                            # [nh, 1]
         m_new = jnp.maximum(m_prev, jnp.max(scr, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scr - m_new)                         # [nh, ps]
+        p = jnp.exp(scr - m_new)                         # [nh, G * ps]
         l_scr[:] = jnp.broadcast_to(
             alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True),
             l_scr.shape)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         if quant:
-            p = p * vs_ref[:].T
+            p = p * jnp.concatenate([r[:] for r in vs_refs], 0).T
         # acc[h, h*hd + d] += sum_p p[h, p] * v[p, h*hd + d]
         acc_scr[:] = acc_scr[:] * alpha + _dot_f32(p, v, 0)
 
@@ -185,43 +237,86 @@ def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, k_ref, *rest,
         o_ref[:] = jnp.sum(own, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
+# The largest grid step the AOT compiles for a described v5e have been
+# shown to fit in scoped VMEM, by :func:`_step_vmem_bytes`' count: 32
+# heads x 256 at 64 positions of a float32 pool, one page a step.
+# Nothing larger has been tried, so nothing larger is admitted.
+_MAX_STEP_VMEM_BYTES = (4 * 32 * 4 + 4 * 64 * 4) * 32 * 256
+
+# Rows of the merged axis a grid step takes at most (GROUP_ROWS //
+# page_size pages).  On the chip at the benchmark cell's shapes a call
+# took 424 / 304 / 280 / 285 us at 64 / 128 / 256 / 512 rows (1,405 one
+# page a step; least by bytes 237), and 256 was first or within 4% of
+# it at 16 x 128 heads, pages of 32, int8 and one rank of tp=4 (PERF.md
+# section 6, PR 29).
+GROUP_ROWS = 256
+
+
+def _step_vmem_bytes(group, page_size, width, itemsize, heads):
+    """What one grid step keeps in VMEM, as ``_use_pallas_paged`` counts
+    it: the K and V blocks twice buffered (4 x rows at the pool's
+    itemsize) and the kernel's float32 working rows of the merged axis
+    (the accumulator and the three-term p . v product, 4 x heads)."""
+    return (4 * heads * 4 + 4 * group * page_size * itemsize) * width
+
+
+def group_pages(table_width, page_size, width, itemsize, heads):
+    """Pages a grid step takes: the largest power of two that divides
+    the page table's width, keeps the step's rows at or under
+    ``GROUP_ROWS`` and its VMEM under ``_MAX_STEP_VMEM_BYTES``.  A page
+    that is not whole packed tiles of its dtype (8 rows of float32, 16
+    of bf16, 32 of int8) would land inside a tile of the block, and
+    goes one a step.  ``width`` and ``heads`` are what one 'tp' rank
+    holds.  Shapes in, G out: nothing else selects it."""
+    if page_size % (32 // itemsize):
+        return 1
+    g = 1
+    while (table_width % (2 * g) == 0
+           and 2 * g * page_size <= GROUP_ROWS
+           and _step_vmem_bytes(2 * g, page_size, width, itemsize,
+                                heads) <= _MAX_STEP_VMEM_BYTES):
+        g *= 2
+    return g
+
+
 def _paged_call(q, pools, scales, page_table, lens, layer, interpret):
     """The one ``pallas_call`` both pools go through.  ``pools`` is the
     engine's whole (k, v) pool, [L, P, ps, nh * hd] each; ``scales`` is
     () for the fp pool and the layer's (k_scale, v_scale), [P, ps, nh],
     for int8.  Page table, lengths and layer index ride the
-    scalar-prefetch channel so BlockSpec index maps can translate
-    logical page coordinates into physical pool blocks before the DMA is
-    issued: each grid step DMAs exactly one page (and, for int8, its
-    scale rows) out of the pool where it lies."""
+    scalar-prefetch channel; the pools are handed over where they lie
+    and the kernel copies G pages a grid step (:func:`group_pages`) out
+    of them, so the pool is never sliced, transposed or copied around
+    the call; an int8 page's scale rows come through G BlockSpecs over
+    each scale array."""
     S, T, nh, hd = q.shape
     assert T == 1, "paged decode kernel is single-token"
     ps, C = pools[0].shape[2:]
     assert C == nh * hd, (pools[0].shape, q.shape)
     maxP = page_table.shape[1]
+    G = group_pages(maxP, ps, C, pools[0].dtype.itemsize, nh)
     pt_flat = page_table.reshape(-1).astype(jnp.int32)
     lens32 = lens.astype(jnp.int32)
     layer1 = jnp.reshape(layer, (1,)).astype(jnp.int32)
     quant = bool(scales)
 
-    row = pl.BlockSpec((None, 1, C), lambda s, j, pt, ln, ly: (s, 0, 0))
-    page = pl.BlockSpec(
-        (None, None, ps, C),
-        lambda s, j, pt, ln, ly: (ly[0], pt[s * maxP + j], 0, 0))
-    scale = pl.BlockSpec(
-        (None, ps, nh), lambda s, j, pt, ln, ly: (pt[s * maxP + j], 0, 0))
-    if quant:
-        operands = (pools[0], scales[0], pools[1], scales[1])
-        specs = [page, scale, page, scale]
-    else:
-        operands, specs = pools, [page, page]
+    def scale_rows(g):
+        def index(s, j, pt, ln, ly):
+            return pt[s * maxP + jnp.minimum(j * G + g, ln[s] // ps)], 0, 0
+        return pl.BlockSpec((None, ps, nh), index)
 
+    row = pl.BlockSpec((None, 1, C), lambda s, j, pt, ln, ly: (s, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(S, maxP),
-        in_specs=[row, *specs],
+        grid=(S, maxP // G),
+        in_specs=[row] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        + [scale_rows(g) for _ in scales for g in range(G)],
         out_specs=row,
         scratch_shapes=[
+            # the group's rows of K and of V, twice buffered
+            *[pltpu.VMEM((2, G * ps, C), pools[0].dtype)] * 2,
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),         # which half is current
             pltpu.VMEM((nh, C), q.dtype),        # block-diagonal query
             pltpu.VMEM((nh, 128), jnp.float32),  # running max, lanes equal
             pltpu.VMEM((nh, 128), jnp.float32),  # running sum, lanes equal
@@ -230,12 +325,13 @@ def _paged_call(q, pools, scales, page_table, lens, layer, interpret):
     )
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, page_size=ps, head_dim=hd,
-                          quant=quant),
+                          group=G, table_width=maxP, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, 1, C), q.dtype),
         name="paged_attn_decode",
         interpret=interpret,
-    )(pt_flat, lens32, layer1, q.reshape(S, 1, C), *operands)
+    )(pt_flat, lens32, layer1, q.reshape(S, 1, C), *pools,
+      *(x for x in scales for _ in range(G)))
     return out.reshape(S, 1, nh, hd)
 
 
@@ -269,27 +365,21 @@ def _paged_attention_tpu(q, k_pool, v_pool, page_table, lens, layer,
                        interpret)
 
 
-# The largest page the AOT compiles for a described v5e have been shown
-# to fit in scoped VMEM, counted as the kernel's float32 working rows of
-# the merged axis (the accumulator and the three-term p . v product,
-# 4 x nh, plus the two pages twice buffered, 4 x ps): 32 heads x 256 at
-# 64 positions.  Nothing larger has been tried, so nothing larger is
-# admitted.
-_MAX_ROWS_F32_BYTES = (4 * 32 + 4 * 64) * 32 * 256 * 4
-
-
 def _use_pallas_paged(k_pool, nh, mesh=None):
-    """Shape gate of the compiled kernel.  Mosaic takes every page the
-    sweep tried (head_dim 16..256, 1..32 heads, page_size 4..64, bf16,
-    fp32 and int8 pools), which leaves only the VMEM bound above, on
-    the heads one rank holds."""
+    """Shape gate of the compiled kernel, on what one rank holds.
+    Mosaic takes every page the sweep tried (1..32 heads x 16..256,
+    page_size 4..64, bf16, fp32 and int8 pools) whose merged axis is
+    whole 128-lane rows — a narrower page is no shape a copy out of HBM
+    takes — which leaves the VMEM bound at one page a step
+    (:func:`group_pages` takes more only under the same bound)."""
     if not pallas_enabled():
         return False
     ps, C = k_pool.shape[2:]
     if mesh is not None:
         nh //= mesh.shape["tp"]
         C //= mesh.shape["tp"]
-    return (4 * nh + 4 * ps) * C * 4 <= _MAX_ROWS_F32_BYTES
+    return C % 128 == 0 and _step_vmem_bytes(
+        1, ps, C, k_pool.dtype.itemsize, nh) <= _MAX_STEP_VMEM_BYTES
 
 
 def _layer_pages(pool, layer):

@@ -12,13 +12,13 @@ The pool is two arrays, ``[L, P, ps, rank]`` and ``[L, P, ps, 128]``
 with the layer index, the page table and the lengths by scalar prefetch
 exactly as ``paged_attn.py`` takes its K and V pools.
 
-Grid: (slot, page group).  ``paged_attn_decode`` spends a grid step
-(about 0.3 us, PERF.md section 5) on every slot x page whether the page
-is live or not; here a step takes ``GROUP`` pages (one BlockSpec each,
-the same pool operand passed ``GROUP`` times), and a group past the
-slot's last live page re-names that page, which the pipeline does not
-fetch again.  At 64 slots, 2,048 positions a slot and pages of 64 that
-is 64 x 4 = 256 steps a layer, not 64 x 128.
+Grid: (slot, page group), as ``paged_attn_decode``'s since PR 29 (which
+copies its pages itself; PERF.md section 6).  Here a step takes
+``GROUP`` pages through one BlockSpec each, the same pool operand passed
+``GROUP`` times, and a group past the slot's last live page re-names
+that page, which the pipeline does not fetch again.  At 64 slots, 2,048
+positions a slot and pages of 64 that is 64 x 4 = 256 steps a layer,
+not 64 x 128.
 
 The XLA fallback is the reference form: gather the slot's view, mask,
 float32 softmax.

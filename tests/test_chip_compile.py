@@ -59,17 +59,19 @@ def compiles(fn, *args):
     return text.count("tpu_custom_call")
 
 
-# the serving shape of gpt3_1p3b() (32 x 64) and of the 1.3B flagship's
-# attention (16 x 128): 8 slots, 4 layers of 1024 pages, 64 pages a slot
-SLOTS, LAYERS, PAGES, MAXP = 8, 4, 1024, 64
+# the benchmark's own serving shape, at the head split of gpt3_1p3b()
+# (32 x 64) and of the 1.3B flagship's attention (16 x 128)
+BENCH_PAGES, BENCH_SLOTS, BENCH_LAYERS, MAX_LEN = 1664, 32, 24, 2048
 HEADS = [(32, 64), (16, 128)]
 
 
-def paged_args(chip, nh, hd, ps, dtype, pages=PAGES):
+def paged_args(chip, nh, hd, ps, dtype, slots=BENCH_SLOTS,
+               layers=BENCH_LAYERS, pages=BENCH_PAGES, max_len=MAX_LEN):
     """(q, k_pool, v_pool), (page_table, lens, layer) of the kernel."""
-    pool = chip((LAYERS, pages, ps, nh * hd), dtype)
-    return ((chip((SLOTS, 1, nh, hd), bf16), pool, pool),
-            (chip((SLOTS, MAXP), i32), chip((SLOTS,), i32), chip((), i32)))
+    pool = chip((layers, pages, ps, nh * hd), dtype)
+    return ((chip((slots, 1, nh, hd), bf16), pool, pool),
+            (chip((slots, max_len // ps), i32), chip((slots,), i32),
+             chip((), i32)))
 
 
 @pytest.mark.parametrize("nh,hd", HEADS)
@@ -84,7 +86,7 @@ def test_paged_attention_fp(chip, nh, hd, ps):
 def test_paged_attention_int8(chip, nh, hd, ps=32):
     from paddle_tpu.ops.pallas.paged_attn import _paged_attention_quant_tpu
     qkv, rest = paged_args(chip, nh, hd, ps, i8)
-    scale = chip((PAGES, ps, nh), f32)
+    scale = chip((BENCH_PAGES, ps, nh), f32)
     assert compiles(_paged_attention_quant_tpu, *qkv, scale, scale,
                     *rest) == 1
 
@@ -96,12 +98,39 @@ def test_paged_attention_one_tp_shard(chip):
     assert compiles(_paged_attention_tpu, *qkv, *rest) == 1
 
 
-def test_paged_attention_largest_admitted_page(chip):
-    """The corner of ``_use_pallas_paged``'s VMEM bound: 32 heads x 256
-    at 64 positions, float32 pool (the widest working copies)."""
+@pytest.mark.parametrize("dtype,group", [(f32, 1), (bf16, 2)],
+                         ids=["float32-1-page", "bf16-2-pages"])
+def test_paged_attention_largest_admitted_step(chip, dtype, group):
+    """The corners of the VMEM bound that ``_use_pallas_paged`` and
+    ``group_pages`` share, 32 heads x 256 at 64 positions a page: a
+    float32 pool one page a step (the widest working copies), a bf16
+    pool two pages a step — the rule stops there though 256 rows would
+    allow four."""
     from paddle_tpu.ops.pallas import paged_attn
-    qkv, rest = paged_args(chip, 32, 256, 64, f32, pages=128)
-    assert (4 * 32 + 4 * 64) * 32 * 256 * 4 == paged_attn._MAX_ROWS_F32_BYTES
+    qkv, rest = paged_args(chip, 32, 256, 64, dtype, slots=8, layers=4,
+                           pages=128, max_len=4096)
+    itemsize = jnp.dtype(dtype).itemsize
+    assert paged_attn.group_pages(64, 64, 32 * 256, itemsize, 32) == group
+    assert paged_attn._step_vmem_bytes(
+        group, 64, 32 * 256, itemsize, 32) == paged_attn._MAX_STEP_VMEM_BYTES
+    assert compiles(paged_attn._paged_attention_tpu, *qkv, *rest) == 1
+
+
+def test_paged_attention_gate_follows_the_compiler(chip, monkeypatch):
+    """``_use_pallas_paged`` admits what compiles at one page a step (8
+    bf16 rows of 2 x 64: half a packed tile, one 128-lane row) and
+    turns away the merged axis Mosaic refuses to copy out of HBM (2
+    heads x 16: "must be aligned to tiling (128)")."""
+    from paddle_tpu.ops.pallas import paged_attn, utils as pallas_utils
+    monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
+    small = dict(slots=4, layers=2, pages=64)
+    qkv, rest = paged_args(chip, 2, 16, 8, bf16, **small)
+    assert not paged_attn._use_pallas_paged(qkv[1], 2)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        compiles(paged_attn._paged_attention_tpu, *qkv, *rest)
+    qkv, rest = paged_args(chip, 2, 64, 8, bf16, **small)
+    assert paged_attn._use_pallas_paged(qkv[1], 2)
+    assert paged_attn.group_pages(MAX_LEN // 8, 8, 128, 2, 2) == 1
     assert compiles(paged_attn._paged_attention_tpu, *qkv, *rest) == 1
 
 
@@ -112,7 +141,6 @@ def test_paged_attention_largest_admitted_page(chip):
 # engine's OWN decode / prefill builders against the real pool's shape.
 # --------------------------------------------------------------------------
 
-BENCH_PAGES, BENCH_SLOTS, BENCH_LAYERS, MAX_LEN = 1664, 32, 24, 2048
 POOLS = [pytest.param(32, 64, 16, None, id="bf16-32x64"),
          pytest.param(16, 128, 16, None, id="bf16-16x128"),
          pytest.param(32, 64, 32, "int8", id="int8-32x64")]

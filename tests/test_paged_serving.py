@@ -299,6 +299,22 @@ class TestPagedEngine:
         assert st["pages_in_use"] == 0
         assert st["kv_tokens_held"] == 0
 
+    def test_stats_report_the_decode_kernels_grid(self, tiny_model):
+        """``paged_attn_group_pages`` is the rule's G for the engine's
+        pool (float32, page 8, table width 4 -> 4 pages a step) and
+        ``paged_attn_live_step_share`` the share of slots x width / G
+        grid steps whose first row an ACTIVE slot's length reaches."""
+        for ps, want in ((8, 4), (2, 1)):    # 2 rows: not a whole tile
+            eng = _make_engine(tiny_model, page_size=ps)
+            st = eng.stats()
+            assert st["paged_attn_group_pages"] == want
+            assert st["paged_attn_live_step_share"] == 0.0
+        eng = _make_engine(tiny_model, page_size=2, slots=4)
+        eng._active[:] = [True, True, False, True]
+        eng._lens[:] = [0, 5, 31, 31]        # live steps 1, 3, (16), 16
+        assert eng.stats()["paged_attn_live_step_share"] == round(
+            (1 + 3 + 16) / (4 * 16), 4)
+
     def test_prefix_reuse_attestation(self, tiny_model):
         """The ISSUE's attestation: a second request with the same
         system prompt allocates ZERO new prefix pages."""
@@ -842,10 +858,12 @@ class TestPrefixStickyRouting:
 # Pallas paged-attention kernel (interpret mode)
 # --------------------------------------------------------------------------
 
-def _kernel_case(rng, S, nh, hd, L, P, ps, maxP, dtype, quant=False):
+def _kernel_case(rng, S, nh, hd, L, P, ps, maxP, dtype, quant=False,
+                 lens=None):
     """Random pools as the engine stores them ([L, P, ps, nh * hd], and
     for int8 the [L, P, ps, nh] scale rows), queries, tables and lengths
-    (one lane at length 0, one ending mid-page)."""
+    (``lens``, or random with one lane at length 0 and one ending
+    mid-page)."""
     import jax.numpy as jnp
     C = nh * hd
     if quant:
@@ -859,9 +877,27 @@ def _kernel_case(rng, S, nh, hd, L, P, ps, maxP, dtype, quant=False):
         scales = []
     q = jnp.asarray(rng.randn(S, 1, nh, hd), dtype)
     pt = jnp.asarray(rng.randint(0, P, (S, maxP)).astype(np.int32))
-    lens = rng.randint(0, maxP * ps, (S,)).astype(np.int32)
-    lens[0], lens[-1] = 0, maxP * ps - ps // 2 - 1
-    return q, pools, scales, pt, jnp.asarray(lens)
+    if lens is None:
+        lens = rng.randint(0, maxP * ps, (S,)).astype(np.int32)
+        lens[0], lens[-1] = 0, maxP * ps - ps // 2 - 1
+    return q, pools, scales, pt, jnp.asarray(lens, jnp.int32)
+
+
+def _group_edges(nh, hd, ps, itemsize, groups):
+    """(table width, lengths) that walk the grid of the kernel as the
+    committed rule builds it: ``groups`` grid steps a slot (0: a width
+    of 3, which no power of two divides — one page a step), and lanes
+    at length 0, inside the first group, on a group's last row, on the
+    next group's first row (the table's last row where there is one
+    group) and in the last page."""
+    from paddle_tpu.ops.pallas.paged_attn import group_pages
+    G = group_pages(1 << 10, ps, nh * hd, itemsize, nh)
+    maxP = groups * G if groups else 3
+    G = group_pages(maxP, ps, nh * hd, itemsize, nh)
+    assert maxP // G == (groups or 3)
+    rows, view = G * ps, maxP * ps
+    return maxP, [0, rows // 2 - 3, rows - 1, min(rows, view - 1),
+                  view - ps // 2 - 1]
 
 
 def _kernel_reference(q, pools, scales, pt, lens, layer):
@@ -905,20 +941,81 @@ class TestPagedKernelOnTheStoredPool:
     rounded to bf16 on the way (a ``q . k`` of 64..128 terms at 2**-8
     each, through the softmax) would show as 1e-1."""
 
-    @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-    @pytest.mark.parametrize("ps", [16, 32])
-    @pytest.mark.parametrize("nh,hd", [(32, 64), (16, 128)])
-    def test_matches_float32_reference(self, nh, hd, ps, quant):
+    # every pool at two groups a slot; the other table widths (3: no
+    # power of two divides it, one page a step; one group; four) at one
+    # bf16 and one int8 pool — interpret mode is slow at these widths
+    @pytest.mark.parametrize("nh,hd,ps,quant,groups", [
+        (nh, hd, ps, quant, 2)
+        for nh, hd in ((32, 64), (16, 128)) for ps in (16, 32)
+        for quant in (False, True)
+    ] + [(nh, hd, ps, quant, groups)
+         for nh, hd, ps, quant in ((32, 64, 16, False), (16, 128, 32, True))
+         for groups in (0, 1, 4)])
+    def test_matches_float32_reference(self, nh, hd, ps, quant, groups):
         import jax.numpy as jnp
         rng = np.random.RandomState(nh + ps + quant)
-        case = _kernel_case(rng, 3, nh, hd, 3, 7, ps, 3, jnp.bfloat16,
-                            quant)
+        maxP, lens = _group_edges(nh, hd, ps, 1 if quant else 2, groups)
+        case = _kernel_case(rng, len(lens), nh, hd, 3, 7, ps, maxP,
+                            jnp.bfloat16, quant, lens)
         ref = _kernel_reference(*case, layer=1)
         got = _kernel_run(*case, layer=1)
         assert got.dtype == jnp.bfloat16 and got.shape == ref.shape
         over = (jnp.abs(got.astype(jnp.float32) - ref)
                 - (2.0 ** -8 * jnp.abs(ref) + 1e-5))
         assert float(over.max()) <= 0, float(over.max())
+
+    @pytest.mark.parametrize("pool", ["bf16", "int8", "float32"])
+    def test_dead_table_entries_are_never_read(self, pool):
+        """Every table entry past a slot's last live page names a page
+        of NaN (for int8, of NaN scales): the result is finite and is
+        the reference's over a table whose dead entries name page 0."""
+        import jax.numpy as jnp
+        nh, hd, ps, P = 8, 64, 32 if pool == "int8" else 16, 6
+        dtype = jnp.float32 if pool == "float32" else jnp.bfloat16
+        maxP, lens = _group_edges(nh, hd, ps, {"bf16": 2, "int8": 1,
+                                               "float32": 4}[pool], 2)
+        rng = np.random.RandomState(11)
+        q, pools, scales, pt, lens = _kernel_case(
+            rng, len(lens), nh, hd, 2, P, ps, maxP, dtype, pool == "int8",
+            lens)
+        dead = np.arange(maxP)[None, :] > np.asarray(lens)[:, None] // ps
+        clean = jnp.asarray(np.where(dead, 0, np.asarray(pt) % (P - 1)))
+        poisoned = jnp.asarray(np.where(dead, P - 1, np.asarray(clean)))
+        if scales:
+            scales = [a.at[:, P - 1].set(jnp.nan) for a in scales]
+        else:
+            pools = [a.at[:, P - 1].set(jnp.nan) for a in pools]
+        ref = _kernel_reference(q, pools, scales, clean, lens, layer=1)
+        got = _kernel_run(q, pools, scales, poisoned, lens,
+                          layer=1).astype(jnp.float32)
+        assert bool(jnp.isfinite(got).all())
+        tol = 1e-5 if pool == "float32" else 2.0 ** -8 * jnp.abs(ref) + 1e-5
+        assert float((jnp.abs(got - ref) - tol).max()) <= 0
+
+    @pytest.mark.parametrize("table_width,ps,width,itemsize,heads,want", [
+        (128, 16, 2048, 2, 32, 16),    # the benchmark's cell: 256 rows
+        (64, 32, 2048, 2, 32, 8),
+        (64, 32, 2048, 1, 32, 8),      # the int8 engine
+        (128, 16, 512, 2, 8, 16),      # one rank of tp=4
+        (128, 16, 2048, 4, 32, 16),    # a float32 pool
+        (3, 16, 2048, 2, 32, 1),       # no power of two divides 3
+        (6, 16, 2048, 2, 32, 2),
+        (128, 8, 2048, 2, 32, 1),      # 8 bf16 rows: half a packed tile
+        (128, 16, 2048, 1, 32, 1),     # 16 int8 rows: half a packed tile
+        (64, 64, 8192, 2, 32, 2),      # the VMEM bound binds, not rows
+        (64, 64, 8192, 4, 32, 1),
+    ])
+    def test_group_rule(self, table_width, ps, width, itemsize, heads,
+                        want):
+        """Shapes in, G out: a power of two that divides the table's
+        width, within the row target and the VMEM bound."""
+        from paddle_tpu.ops.pallas import paged_attn
+        G = paged_attn.group_pages(table_width, ps, width, itemsize, heads)
+        assert G == want
+        assert table_width % G == 0 and G & (G - 1) == 0
+        assert G * ps <= max(ps, paged_attn.GROUP_ROWS)
+        assert paged_attn._step_vmem_bytes(
+            G, ps, width, itemsize, heads) <= paged_attn._MAX_STEP_VMEM_BYTES
 
     def test_two_tp_shards(self):
         """Under a 'tp' mesh each rank runs the kernel on its own
