@@ -1479,10 +1479,10 @@ class PagedServingEngine(ServingEngine):
 
     * **quantized KV** (``kv_dtype="int8"``, ISSUE 9) — the page pool
       stores K/V int8 with per-position-per-head fp32 scale arrays
-      alongside (models/gpt.py::init_paged_cache_quant): prefill and
+      alongside (models/gpt.py::init_paged_pools): prefill and
       chunk scatters quantize on write, decode attention dequantizes on
       read (in-kernel on TPU: ops/pallas/paged_attn.py::
-      paged_attention_quant).  ~4x the tokens per KV byte; COW copies
+      paged_attention).  ~4x the tokens per KV byte; COW copies
       page+scale pairs; the prefix hash is salted with the numeric
       contract so int8 pages never alias fp pages.  Composes with
       ``quant=`` (weight-only int8/fp8 executables) — together they are
@@ -1649,18 +1649,16 @@ class PagedServingEngine(ServingEngine):
             self._host_tier = _HostKVTier(
                 int(self._host_tier_mb * (1 << 20)),
                 hash_key=self._pager.hash_key)
-        pools = self._family.init_paged_pools(
+        self._pools = pools = tuple(self._family.init_paged_pools(
             self.cfg, self._num_pages, ps, dtype=self._cache_dtype,
-            mesh=self._mesh, kv_quant=self._kv_quant)
-        self._cache_ks = self._cache_vs = None
-        self._set_cache(pools)
+            mesh=self._mesh, kv_quant=self._kv_quant))
         if self._kv_quant and not self._kv_saved_counted:
             # bytes the int8+scale pool saves vs the SAME pool at
             # the compute dtype (what a rebuild without kv_dtype
             # would have allocated) — counted once, not per rebuild.
             # The first build happens inside the base constructor
             # before the counters exist; park it for __init__'s tail.
-            fp_bytes = 2 * (self._cache_k.size
+            fp_bytes = 2 * (pools[0].size
                             * self._jnp.dtype(self._cache_dtype
                                               or self.cfg.dtype).itemsize)
             q_bytes = sum(int(a.nbytes) for a in pools)
@@ -1672,23 +1670,16 @@ class PagedServingEngine(ServingEngine):
         self._chunk_slots.clear()
 
     def _cache_operands(self):
-        """The donated KV pool arrays in executable-operand order:
-        (k, v) for the fp pool, (k, k_scale, v, v_scale) for int8."""
-        if self._kv_quant:
-            return (self._cache_k, self._cache_ks,
-                    self._cache_v, self._cache_vs)
-        return (self._cache_k, self._cache_v)
+        """The donated KV pool arrays in executable-operand order, as
+        the family's ``init_paged_pools`` returned them."""
+        return self._pools
 
     def _set_cache(self, arrs):
-        if self._kv_quant:
-            (self._cache_k, self._cache_ks,
-             self._cache_v, self._cache_vs) = arrs
-        else:
-            self._cache_k, self._cache_v = arrs
+        self._pools = tuple(arrs)
 
     @property
     def _n_cache(self):
-        return 4 if self._kv_quant else 2
+        return len(self._pools)
 
     def _chunk_eligible(self, req):
         return (self._prefill_chunk is not None
@@ -1861,7 +1852,6 @@ class PagedServingEngine(ServingEngine):
         cfg = self.cfg
         ps = self._page_size
         cap = self.capture_logits
-        kvq = self._kv_quant
 
         if self._pp > 1:
             # stage-partitioned wave: one shard_map over the ('pp','tp')
@@ -1890,7 +1880,7 @@ class PagedServingEngine(ServingEngine):
         def prefill(params, *args):
             tokens, lens, ptab = args[n:]
             last, out_cache = family.prefill_paged(
-                params, cfg, args[:n], tokens, lens, ptab, kv_quant=kvq)
+                params, cfg, args[:n], tokens, lens, ptab)
             out_cache = self._constrain_cache(out_cache)
             with jax.named_scope("head_sample"):
                 first_tok = jnp.argmax(last, -1).astype(jnp.int32)
@@ -2012,15 +2002,13 @@ class PagedServingEngine(ServingEngine):
         jax, jnp = self._jax, self._jnp
         cfg = self.cfg
         cap = self.capture_logits
-        kvq = self._kv_quant
-
         n = self._n_cache
         family = self._family
 
         def chunk(params, *args):
             toks, ptab_row, offset, tlen = args[n:]
             logits, cache = family.chunk_paged(
-                params, cfg, args[:n], toks, ptab_row, offset, kv_quant=kvq)
+                params, cfg, args[:n], toks, ptab_row, offset)
             last = jax.lax.dynamic_index_in_dim(logits[0], tlen - 1, 0,
                                                 keepdims=False)    # [V]
             tok = jnp.argmax(last, -1).astype(jnp.int32)
@@ -2666,7 +2654,6 @@ class PagedServingEngine(ServingEngine):
         jax, jnp = self._jax, self._jnp
         cfg = self.cfg
         cap = self.capture_logits
-        kvq = self._kv_quant
 
         if self._pp > 1:
             # stage-partitioned decode: the 1F1B microbatch tick loop
@@ -2697,7 +2684,7 @@ class PagedServingEngine(ServingEngine):
             page_table, wpages, woffs, lens, toks = args[n:]
             logits, cache, extra = family.decode_paged(
                 params, cfg, args[:n], page_table, wpages, woffs, lens,
-                toks, mesh=self._mesh, kv_quant=kvq)
+                toks, mesh=self._mesh)
             with jax.named_scope("head_sample"):
                 nxt = jnp.argmax(logits, -1).astype(jnp.int32)
                 if extra is not None:
@@ -2836,7 +2823,7 @@ class PagedServingEngine(ServingEngine):
                 # what a cached position NEEDS (the family's count), not
                 # what its page row occupies (kv_bytes_total / positions)
                 "kv_bytes_per_position": self._family.kv_bytes_per_position(
-                    self.cfg, self._cache_k.dtype.itemsize),
+                    self.cfg, self._pools[0].dtype.itemsize),
                 "kv_tokens_held": held,
                 "page_utilization": round(held / max(1, in_use * ps), 4)}
 

@@ -465,19 +465,14 @@ class SpeculativeServingEngine(PagedServingEngine):
         jax, jnp = self._jax, self._jnp
         cfg = self.cfg
         cap = self.capture_logits
-        kvq = self._kv_quant
         n = self._n_cache
 
         def verify(params, *args):
             cache = args[:n]
             (toks, ptab, wpages, woffs, lens, caps, eos_ids,
              force) = args[n:]
-            if kvq:
-                logits, wk, wks, wv, wvs = gpt.decode_step_paged_verify_quant(
-                    params, toks, cfg, *cache, ptab, lens)
-            else:
-                logits, wk, wv = gpt.decode_step_paged_verify(
-                    params, toks, cfg, *cache, ptab, lens)
+            logits, windows = gpt.decode_step_paged_verify(
+                params, cfg, cache, toks, ptab, lens)
             greedy = jnp.argmax(logits, -1).astype(jnp.int32)  # [S, W]
             out_toks, n_commit = accept_commit(toks[:, 1:], greedy, caps,
                                                eos_ids, force)
@@ -489,15 +484,8 @@ class SpeculativeServingEngine(PagedServingEngine):
             mask = jnp.arange(toks.shape[1])[None, :] < n_commit[:, None]
             wp = jnp.where(mask, wpages, 0)
             wo = jnp.where(mask, woffs, 0)
-            if kvq:
-                out_cache = (cache[0].at[:, wp, wo].set(wk),
-                             cache[1].at[:, wp, wo].set(wks),
-                             cache[2].at[:, wp, wo].set(wv),
-                             cache[3].at[:, wp, wo].set(wvs))
-            else:
-                out_cache = (cache[0].at[:, wp, wo].set(wk),
-                             cache[1].at[:, wp, wo].set(wv))
-            out_cache = self._constrain_cache(out_cache)
+            out_cache = self._constrain_cache(tuple(
+                c.at[:, wp, wo].set(w) for c, w in zip(cache, windows)))
             if cap:
                 return (*out_cache, out_toks, n_commit, logits)
             return (*out_cache, out_toks, n_commit)

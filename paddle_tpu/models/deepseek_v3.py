@@ -504,7 +504,7 @@ def _pad_rope(kr, dtype):
     return jnp.pad(kr.astype(dtype), pad)
 
 
-def prefill_paged(params, cfg, pools, tokens, lens, ptab, kv_quant=False):
+def prefill_paged(params, cfg, pools, tokens, lens, ptab):
     """Causal forward over padded prompts ``tokens`` [b, s]; every
     layer scatters its latents into the pools through ``ptab`` [b,
     s / page_size] (pad rows and pad pages target the scratch page).
@@ -533,13 +533,13 @@ def prefill_paged(params, cfg, pools, tokens, lens, ptab, kv_quant=False):
     return _head(cfg, params, last), pools
 
 
-def chunk_paged(params, cfg, pools, tokens, pt_row, offset, kv_quant=False):
+def chunk_paged(params, cfg, pools, tokens, pt_row, offset):
     """One chunked-prefill piece for one slot: ``tokens`` [1, C] from
     absolute position ``offset`` (traced), attending the slot's filled
     pages and the chunk's causal prefix.  Returns (logits [1, C, V],
     pools).  Each layer gathers the slot's page view, splices the
-    chunk's latents in and scatters the view back, as
-    ``gpt.forward_paged_chunk`` does with K and V."""
+    chunk's latents in and scatters the view back: every page of the
+    table's row is rewritten, the earlier ones with what they held."""
     C = tokens.shape[1]
     maxP = pt_row.shape[0]
     ps = pools[0].shape[2]
@@ -565,7 +565,7 @@ def chunk_paged(params, cfg, pools, tokens, pt_row, offset, kv_quant=False):
 
 
 def decode_paged(params, cfg, pools, page_table, write_pages, write_offs,
-                 lens, tokens, mesh=None, kv_quant=False, absorbed=True):
+                 lens, tokens, mesh=None, absorbed=True):
     """One decode iteration for every slot: one token per slot at its
     own ``lens[s]``.  Returns (logits [S, V] float32, pools, counts
     int32 [expert layers, E]: the assignments each expert received from

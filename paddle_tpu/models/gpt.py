@@ -533,14 +533,27 @@ def _ffn(cfg, ln, x, blk, cd):
     return h
 
 
+def _embed_at(params, tokens, pos):
+    """Token plus learned position embeddings of ``tokens`` at the
+    absolute positions ``pos``, in the parameters' dtype."""
+    return jnp.take(params["wte"], tokens, axis=0) + jnp.take(
+        params["wpe"], pos, axis=0)
+
+
 def embed(cfg: GPTConfig, params, tokens, pos_offset=0):
-    cd = jnp.dtype(cfg.dtype)
     N = tokens.shape[-1]
     with jax.named_scope("embed"):
-        pos = pos_offset + jnp.arange(N)
-        x = jnp.take(params["wte"], tokens, axis=0) + jnp.take(
-            params["wpe"], pos, axis=0)
-        return x.astype(cd)
+        x = _embed_at(params, tokens, pos_offset + jnp.arange(N))
+        return x.astype(jnp.dtype(cfg.dtype))
+
+
+def _head(cfg: GPTConfig, params, x):
+    """Final norm and the tied output head (logits = x @ wte^T) of the
+    serving programs: float32 logits."""
+    with jax.named_scope("head_sample"):
+        x = _layer_norm(x, params["lnf_g"], params["lnf_b"],
+                        cfg.layer_norm_eps)
+        return (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
 
 
 def forward(params, tokens, cfg: GPTConfig):
@@ -630,11 +643,8 @@ def forward_cached(params, tokens, cfg: GPTConfig, cache):
 
     x, (ks, vs) = jax.lax.scan(scan_body, x,
                                (params["blocks"], cache["k"], cache["v"]))
-    with jax.named_scope("head_sample"):
-        x = _layer_norm(x, params["lnf_g"], params["lnf_b"],
-                        cfg.layer_norm_eps)
-        logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
-    return logits, {"k": ks, "v": vs, "len": cur + tokens.shape[1]}
+    return (_head(cfg, params, x),
+            {"k": ks, "v": vs, "len": cur + tokens.shape[1]})
 
 
 def generate(params, cfg: GPTConfig, prompt, max_new_tokens,
@@ -855,6 +865,25 @@ def decode_step_slots(params, tokens, cfg: GPTConfig, cache, active=None):
 # loops CARRY the whole pool, writing it with layer-indexed scatters:
 # as the ``xs``/``ys`` of a scan it would be a second pool, sliced and
 # restacked a layer at a time.
+#
+# A page holds K/V in the compute dtype (4 bytes on the CPU bench path,
+# 2 on TPU bf16), or — the quantized pool, ISSUE 9 — int8 with an fp32
+# absmax scale PER (page, position, head): position granularity because
+# pages are written position-at-a-time (decode appends, chunked
+# prefill), and a page-granular scale would need the whole page
+# requantized on every append, which drifts.  Each position's scale is
+# written exactly once, together with its K/V bytes, and never touched
+# again — which also keeps shared prefix pages byte-deterministic (same
+# prompt, same params => same int8 bytes + scales), the property the
+# pager's content hash relies on.  Reads dequantize: the Pallas
+# paged-attention kernel does it inside the copied block
+# (ops/pallas/paged_attn.py), the lax fallback on the gathered view.
+#
+# ``pools`` is the donated pool arrays in executable-operand order,
+# (k, v) or (k, k_scale, v, v_scale).  WHICH of the two a program was
+# handed is read off the pools, by the five helpers under
+# :func:`init_paged_pools` and by ``paged_attention``; every paged
+# program below is written once over them.
 
 
 def paged_pool_shape(cfg: GPTConfig, num_pages, page_size):
@@ -863,23 +892,87 @@ def paged_pool_shape(cfg: GPTConfig, num_pages, page_size):
             cfg.num_heads * cfg.head_dim)
 
 
-def init_paged_cache(cfg: GPTConfig, num_pages, page_size, dtype=None,
-                     mesh=None):
-    """Paged KV pool: {'k','v': [L, num_pages, page_size, nh * hd]} — a
-    page row is every head's hd values side by side, head-major.
-    Page 0 is the scratch page (inactive lanes / padded prefill rows
-    scatter there; nothing reads it).  With ``mesh`` the pages shard
-    the head axis over 'tp' — page ids stay rank-invariant, each rank
-    holds its nh/tp head slice of every page."""
-    cd = jnp.dtype(dtype or cfg.dtype)
+def quantize_kv(x):
+    """Per-position-per-head absmax int8: x [..., nh, hd] float ->
+    (q int8 same shape, scale fp32 [..., nh])."""
+    xf = x.astype(jnp.float32)
+    s = jnp.maximum(jnp.max(jnp.abs(xf), axis=-1) / 127.0, 1e-8)
+    q = jnp.clip(jnp.round(xf / s[..., None]), -127, 127).astype(jnp.int8)
+    return q, s
+
+
+def dequantize_kv(q, s, dtype):
+    """Inverse of :func:`quantize_kv` (up to rounding)."""
+    return (q.astype(jnp.float32) * s[..., None]).astype(dtype)
+
+
+def init_paged_pools(cfg: GPTConfig, num_pages, page_size, dtype=None,
+                     mesh=None, kv_quant=False):
+    """The paged KV pool, zeros, as the donated arrays in operand
+    order: (k, v), each [L, num_pages, page_size, nh * hd] in ``dtype``
+    — a page row is every head's hd values side by side, head-major —
+    or with ``kv_quant`` (k, k_scale, v, v_scale), int8 pages and fp32
+    scales [L, P, ps, nh].  Page 0 is the scratch page (inactive lanes
+    / padded prefill rows scatter there; nothing reads it).  With
+    ``mesh`` pages and scale rows alike shard the head axis (axis 3 in
+    either rank) over 'tp' — page ids stay rank-invariant, a page's
+    bytes AND scales live on the same rank, and the absmax quantizer
+    needs only its own heads, so the quantize-once byte contract holds
+    per shard."""
     sh = None if mesh is None else _kv_pool_sharding(mesh)
     shape = paged_pool_shape(cfg, num_pages, page_size)
-    return {"k": _pool_zeros(shape, cd, sh), "v": _pool_zeros(shape, cd, sh)}
+    if not kv_quant:
+        cd = jnp.dtype(dtype or cfg.dtype)
+        return (_pool_zeros(shape, cd, sh), _pool_zeros(shape, cd, sh))
+    scales = shape[:-1] + (cfg.num_heads,)
+    return (_pool_zeros(shape, jnp.int8, sh),
+            _pool_zeros(scales, jnp.float32, sh),
+            _pool_zeros(shape, jnp.int8, sh),
+            _pool_zeros(scales, jnp.float32, sh))
 
 
-def _merge_heads(x):
-    """[..., nh, hd] -> [..., nh * hd]: K/V as a page row stores them."""
-    return x.reshape(*x.shape[:-2], -1)
+def _halves(pools):
+    """(K's arrays, V's arrays) of the pool operands: (pages,) each, or
+    (pages, scales) each for the int8 pool."""
+    n = len(pools) // 2
+    return pools[:n], pools[n:]
+
+
+def _float_dtype(cfg, pools):
+    """The dtype a program holds K/V in on their way into and out of
+    the pool: the pool's own, or the compute dtype over int8 pages."""
+    return jnp.dtype(cfg.dtype) if len(pools) == 4 else pools[0].dtype
+
+
+def _rows(half, x, lead):
+    """What a committed write of fresh K or V ``x`` [..., nh, hd] stores
+    in ``half``, one array for each of its arrays, laid out
+    ``[*lead, nh * hd | nh]`` as the pool's rows are: the cast to the
+    pool's dtype, or — quantized exactly once — the int8 bytes and
+    their per-position-per-head scales."""
+    if len(half) == 2:
+        q, s = quantize_kv(x)
+        return q.reshape(*lead, -1), s.reshape(*lead, -1)
+    return (x.reshape(*lead, -1).astype(half[0].dtype),)
+
+
+def _write(half, index, x, lead):
+    """``half`` with fresh K or V ``x`` written at ``index``, whose
+    result is ``lead`` rows: every array of the half at the same
+    coordinate (:func:`_rows`)."""
+    return tuple(p.at[index].set(r)
+                 for p, r in zip(half, _rows(half, x, lead)))
+
+
+def _unrows(rows, lead, heads, dtype):
+    """What attention reads back from ``rows`` as a pool half stores
+    them (its gathered pages, or :func:`_rows` of a window not yet
+    written): [*lead, nh, hd] in ``dtype``, dequantized where the rows
+    are int8 bytes with their scales."""
+    x = rows[0].reshape(*lead, *heads)
+    if len(rows) == 2:
+        return dequantize_kv(x, rows[1].reshape(*lead, heads[0]), dtype)
+    return x.astype(dtype)
 
 
 def _layer_scan(body, x, blocks, pools):
@@ -902,255 +995,28 @@ def _layer_scan(body, x, blocks, pools):
     return x, pools, outs
 
 
-def _paged_slot_block(cfg, x, blk, layer, k_pool, v_pool, page_table,
-                      write_pages, write_offs, lens, mesh=None):
+def _paged_slot_block(cfg, x, blk, layer, pools, page_table, write_pages,
+                      write_offs, lens, mesh=None):
     """block_apply for the page-table single-token decode: slot s's new
     K/V land at (layer, write_pages[s], write_offs[s]) — a batched
-    scatter into the shared pool — and its query attends the layer's
-    gathered page view masked to ``k_pos <= lens[s]``.  x: [S, 1, H];
-    k/v_pool: the whole [L, P, ps, nh * hd] pools; page_table: int32
-    [S, maxP]."""
+    scatter into the shared pool, an int8 pool's scales at the same
+    coordinate — and its query attends the layer's pages through the
+    table, masked to ``k_pos <= lens[s]``.  x: [S, 1, H]; pools: the
+    whole pool; page_table: int32 [S, maxP].  Returns (x, pools)."""
     from ..ops.pallas.paged_attn import paged_attention
+    S = x.shape[0]
+    k_half, v_half = _halves(pools)
+    at = (layer, write_pages, write_offs)
 
     def pattn(q, k, v):
         with jax.named_scope("kv_write"):
-            kc = k_pool.at[layer, write_pages, write_offs].set(
-                _merge_heads(k[:, 0]).astype(k_pool.dtype))
-            vc = v_pool.at[layer, write_pages, write_offs].set(
-                _merge_heads(v[:, 0]).astype(v_pool.dtype))
+            new = (*_write(k_half, at, k[:, 0], (S,)),
+                   *_write(v_half, at, v[:, 0], (S,)))
         with jax.named_scope("paged_attn"):
-            a = paged_attention(q, kc, vc, page_table, lens, layer,
-                                mesh=mesh)
-        return a, (kc, vc)
+            a = paged_attention(q, new, page_table, lens, layer, mesh=mesh)
+        return a, new
 
-    return block_apply(cfg, x, blk, attn_fn=pattn)     # x, (k, v pools)
-
-
-def decode_step_paged(params, tokens, cfg: GPTConfig, cache_k, cache_v,
-                      page_table, write_pages, write_offs, lens, mesh=None):
-    """One decode iteration for every slot through the paged pool:
-    consume one token per slot (at its own ``lens[s]``), return
-    (logits [S, V] fp32, k_pool, v_pool).  Inactive slots point their
-    write coordinates at the scratch page and their table rows at
-    scratch, so the batch shape stays static and their garbage never
-    lands on a real page — the host advances only active lens.
-    ``mesh``: the 'tp' serving mesh of a head-sharded pool, which the
-    paged-attention kernel needs to run per shard (GSPMD cannot split
-    it; ops/pallas/paged_attn.py::_over_heads)."""
-    with jax.named_scope("embed"):
-        x = jnp.take(params["wte"], tokens, axis=0) \
-            + jnp.take(params["wpe"], lens, axis=0)
-        x = x[:, None, :].astype(jnp.dtype(cfg.dtype))    # [S, 1, H]
-
-    def body(xx, blk, layer, pools):
-        xx, pools = _paged_slot_block(cfg, xx, blk, layer, *pools,
-                                      page_table, write_pages, write_offs,
-                                      lens, mesh)
-        return xx, pools, None
-
-    x, (ks, vs), _ = _layer_scan(body, x, params["blocks"],
-                                 (cache_k, cache_v))
-    with jax.named_scope("head_sample"):
-        x = _layer_norm(x, params["lnf_g"], params["lnf_b"],
-                        cfg.layer_norm_eps)
-        logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
-        return logits[:, 0], ks, vs
-
-
-def forward_paged_chunk(params, tokens, cfg: GPTConfig, cache_k, cache_v,
-                        pt_row, offset):
-    """One chunked-prefill piece for a single slot: consume ``tokens``
-    [1, C] starting at absolute position ``offset`` (a traced scalar, so
-    every chunk of every prompt reuses ONE executable), attending the
-    slot's already-filled pages plus the in-chunk causal prefix.
-    Returns (logits [1, C, V] fp32, k_pool, v_pool).
-
-    Per layer: gather the slot's page view, splice the chunk in with
-    the exact `_cached_block` math, scatter the view back to its pages.
-    Padded tail rows of the final chunk write garbage at positions past
-    the true prompt length — masked by ``len`` until decode overwrites
-    them, same contract as the slot-contiguous prefill pads."""
-    maxP = pt_row.shape[0]
-    ps = cache_k.shape[2]
-    heads = (cfg.num_heads, cfg.head_dim)
-    x = embed(cfg, params, tokens, pos_offset=offset)
-
-    def body(xx, blk, layer, pools):
-        kp, vp = pools
-        view_k = kp[layer, pt_row].reshape(1, maxP * ps, *heads)
-        view_v = vp[layer, pt_row].reshape(1, maxP * ps, *heads)
-        xx, view_k, view_v = _cached_block(cfg, xx, blk, view_k, view_v,
-                                           offset)
-        kp = kp.at[layer, pt_row].set(view_k[0].reshape(maxP, ps, -1))
-        vp = vp.at[layer, pt_row].set(view_v[0].reshape(maxP, ps, -1))
-        return xx, (kp, vp), None
-
-    x, (ks, vs), _ = _layer_scan(body, x, params["blocks"],
-                                 (cache_k, cache_v))
-    x = _layer_norm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
-    logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
-    return logits, ks, vs
-
-
-# --------------------------------------------------------------------------
-# quantized paged KV (ISSUE 9): int8 pool + per-position-per-head scales
-# --------------------------------------------------------------------------
-#
-# The fp paged pool above stores K/V in the compute dtype (4 bytes on
-# the CPU bench path, 2 on TPU bf16).  The quantized pool stores them
-# int8 with an fp32 absmax scale PER (page, position, head) — position
-# granularity because pages are written position-at-a-time (decode
-# appends, chunked prefill): a page-granular scale would need the whole
-# page requantized on every append, and requantizing from already-
-# quantized content drifts.  Each position's scale is written exactly
-# once, together with its K/V bytes, and never touched again — which
-# also keeps shared prefix pages byte-deterministic (same prompt, same
-# params => same int8 bytes + scales), the property the pager's content
-# hash relies on.  Reads dequantize: the Pallas paged-attention kernel
-# does it inside the DMA'd block (ops/pallas/paged_attn.py), the lax
-# fallback on the gathered view.
-
-
-def quantize_kv(x):
-    """Per-position-per-head absmax int8: x [..., nh, hd] float ->
-    (q int8 same shape, scale fp32 [..., nh])."""
-    xf = x.astype(jnp.float32)
-    s = jnp.maximum(jnp.max(jnp.abs(xf), axis=-1) / 127.0, 1e-8)
-    q = jnp.clip(jnp.round(xf / s[..., None]), -127, 127).astype(jnp.int8)
-    return q, s
-
-
-def dequantize_kv(q, s, dtype):
-    """Inverse of :func:`quantize_kv` (up to rounding)."""
-    return (q.astype(jnp.float32) * s[..., None]).astype(dtype)
-
-
-def init_paged_cache_quant(cfg: GPTConfig, num_pages, page_size,
-                           mesh=None):
-    """int8 paged KV pool + scale arrays: {'k','v': int8
-    [L, P, ps, nh * hd], 'k_scale','v_scale': fp32 [L, P, ps, nh]}.
-    Page 0 stays the scratch page.  With ``mesh`` both the int8 pages
-    and their scale rows shard the head axis (axis 3 in either rank)
-    over 'tp' — a page's bytes AND scales live on the same rank, and
-    the per-position-per-head absmax quantizer needs only its own
-    heads, so the quantize-once byte contract holds per shard."""
-    sh = None if mesh is None else _kv_pool_sharding(mesh)
-    shape = paged_pool_shape(cfg, num_pages, page_size)
-    scales = shape[:-1] + (cfg.num_heads,)
-    return {"k": _pool_zeros(shape, jnp.int8, sh),
-            "v": _pool_zeros(shape, jnp.int8, sh),
-            "k_scale": _pool_zeros(scales, jnp.float32, sh),
-            "v_scale": _pool_zeros(scales, jnp.float32, sh)}
-
-
-def _quantize_rows(x):
-    """:func:`quantize_kv` of [..., nh, hd] as the pool stores it:
-    (int8 [..., nh * hd], scale fp32 [..., nh])."""
-    q, s = quantize_kv(x)
-    return _merge_heads(q), s
-
-
-def _paged_slot_block_quant(cfg, x, blk, layer, k_pool, k_scale, v_pool,
-                            v_scale, page_table, write_pages, write_offs,
-                            lens, mesh=None):
-    """:func:`_paged_slot_block` over the int8 pool: each slot's new K/V
-    quantize on write — int8 bytes into (layer, write_pages[s],
-    write_offs[s]), the absmax scale into the scale arrays at the same
-    coordinate — and attention dequantizes on read through
-    ops/pallas/paged_attn.py::paged_attention_quant."""
-    from ..ops.pallas.paged_attn import paged_attention_quant
-
-    def pattn(q, k, v):
-        kq, ks = _quantize_rows(k[:, 0])     # [S, nh*hd] int8, [S, nh]
-        vq, vs = _quantize_rows(v[:, 0])
-        at = (layer, write_pages, write_offs)
-        kc = k_pool.at[at].set(kq)
-        ksc = k_scale.at[at].set(ks)
-        vc = v_pool.at[at].set(vq)
-        vsc = v_scale.at[at].set(vs)
-        a = paged_attention_quant(q, kc, ksc, vc, vsc, page_table, lens,
-                                  layer, mesh=mesh)
-        return a, (kc, ksc, vc, vsc)
-
-    return block_apply(cfg, x, blk, attn_fn=pattn)     # x, the four pools
-
-
-def decode_step_paged_quant(params, tokens, cfg: GPTConfig, cache_k,
-                            k_scale, cache_v, v_scale, page_table,
-                            write_pages, write_offs, lens, mesh=None):
-    """One decode iteration for every slot through the INT8 paged pool
-    (same contract as :func:`decode_step_paged`; the scale arrays ride
-    along as donated operands).  Returns
-    (logits [S, V] fp32, k, k_scale, v, v_scale)."""
-    x = jnp.take(params["wte"], tokens, axis=0) \
-        + jnp.take(params["wpe"], lens, axis=0)
-    x = x[:, None, :].astype(jnp.dtype(cfg.dtype))        # [S, 1, H]
-
-    def body(xx, blk, layer, pools):
-        xx, pools = _paged_slot_block_quant(
-            cfg, xx, blk, layer, *pools, page_table, write_pages,
-            write_offs, lens, mesh)
-        return xx, pools, None
-
-    x, pools, _ = _layer_scan(body, x, params["blocks"],
-                              (cache_k, k_scale, cache_v, v_scale))
-    x = _layer_norm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
-    logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
-    return (logits[:, 0], *pools)
-
-
-def _dequantize_view(pages, scales, dtype):
-    """Gathered int8 pages [..., ps, nh * hd] with their scales
-    [..., ps, nh] -> the dequantized [..., ps, nh, hd] view."""
-    heads = pages.reshape(*scales.shape, -1)
-    return dequantize_kv(heads, scales, dtype)
-
-
-def forward_paged_chunk_quant(params, tokens, cfg: GPTConfig, cache_k,
-                              k_scale, cache_v, v_scale, pt_row, offset):
-    """:func:`forward_paged_chunk` over the int8 pool: the slot's
-    already-filled pages are dequantized into the fp gathered view, the
-    chunk runs the exact ``_cached_block`` math over it, then ONLY the
-    chunk's own positions — static width C, page-aligned because the
-    engine enforces ``prefill_chunk % page_size == 0`` and chunk offsets
-    are C-multiples — are quantized and scattered back.  Earlier
-    positions never round-trip through requantization, so their bytes
-    (and the pager's content-hash contract) stay exact.  The final
-    chunk's padded tail positions land on the table's scratch-padded
-    page ids like every other pad."""
-    maxP = pt_row.shape[0]
-    ps = cache_k.shape[2]
-    C = tokens.shape[1]
-    cpages = C // ps
-    cd = jnp.dtype(cfg.dtype)
-    heads = (cfg.num_heads, cfg.head_dim)
-    x = embed(cfg, params, tokens, pos_offset=offset)
-    j0 = offset // ps
-
-    def body(xx, blk, layer, pools):
-        kp, ksp, vp, vsp = pools
-        view_k = _dequantize_view(kp[layer, pt_row], ksp[layer, pt_row],
-                                  cd).reshape(1, maxP * ps, *heads)
-        view_v = _dequantize_view(vp[layer, pt_row], vsp[layer, pt_row],
-                                  cd).reshape(1, maxP * ps, *heads)
-        xx, view_k, view_v = _cached_block(cfg, xx, blk, view_k, view_v,
-                                           offset)
-        ck = jax.lax.dynamic_slice(view_k[0], (offset, 0, 0), (C,) + heads)
-        cv = jax.lax.dynamic_slice(view_v[0], (offset, 0, 0), (C,) + heads)
-        ckq, cks = _quantize_rows(ck)             # [C, nh*hd], [C, nh]
-        cvq, cvs = _quantize_rows(cv)
-        pages = jax.lax.dynamic_slice(pt_row, (j0,), (cpages,))
-        kp = kp.at[layer, pages].set(ckq.reshape(cpages, ps, -1))
-        ksp = ksp.at[layer, pages].set(cks.reshape(cpages, ps, -1))
-        vp = vp.at[layer, pages].set(cvq.reshape(cpages, ps, -1))
-        vsp = vsp.at[layer, pages].set(cvs.reshape(cpages, ps, -1))
-        return xx, (kp, ksp, vp, vsp), None
-
-    x, pools, _ = _layer_scan(body, x, params["blocks"],
-                              (cache_k, k_scale, cache_v, v_scale))
-    x = _layer_norm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
-    logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
-    return (logits, *pools)
+    return block_apply(cfg, x, blk, attn_fn=pattn)
 
 
 # --------------------------------------------------------------------------
@@ -1161,8 +1027,8 @@ def forward_paged_chunk_quant(params, tokens, cfg: GPTConfig, cache_k,
 # (inference/serving.py::family_of names the functions and finds the
 # module by the type of ``cfg``): the pools, the three paged programs,
 # what a cached position costs, the prefix-hash salt, and which engine
-# compositions exist.  ``pools`` is the donated pool arrays in operand
-# order: (k, v), or (k, k_scale, v, v_scale) for the int8 pool.
+# compositions exist.  Only ``init_paged_pools`` is TOLD which pool to
+# make; the programs take whichever they are handed.
 
 def check_serving(cfg, **composition):
     """Every engine composition is built for this family; the engine's
@@ -1187,17 +1053,7 @@ def decode_group_pages(cfg: GPTConfig, pools, table_width, tp=1):
                        pools[0].dtype.itemsize, cfg.num_heads // tp)
 
 
-def init_paged_pools(cfg: GPTConfig, num_pages, page_size, dtype=None,
-                     mesh=None, kv_quant=False):
-    if kv_quant:
-        c = init_paged_cache_quant(cfg, num_pages, page_size, mesh=mesh)
-        return (c["k"], c["k_scale"], c["v"], c["v_scale"])
-    c = init_paged_cache(cfg, num_pages, page_size, dtype=dtype, mesh=mesh)
-    return (c["k"], c["v"])
-
-
-def prefill_paged(params, cfg: GPTConfig, pools, tokens, lens, ptab,
-                  kv_quant=False):
+def prefill_paged(params, cfg: GPTConfig, pools, tokens, lens, ptab):
     """Causal forward over the padded prompts ``tokens`` [b, s], then
     one batched scatter of the filled K/V page chunks into the pools
     through the page tables ``ptab`` [b, s / page_size] (pad rows target
@@ -1206,30 +1062,22 @@ def prefill_paged(params, cfg: GPTConfig, pools, tokens, lens, ptab,
     (logits of each row's last true position [b, V], pools).
 
     On the int8 pool the forward still runs — and attends its own
-    prompt — in the compute dtype; K/V QUANTIZE ON WRITE
-    (:func:`quantize_kv`), scales landing in the scale arrays at the
-    same page coordinates.  Quantization error only ever enters on
-    later reads."""
+    prompt — in the compute dtype; K/V QUANTIZE ON WRITE, scales
+    landing in the scale arrays at the same page coordinates.
+    Quantization error only ever enters on later reads."""
     b, s = tokens.shape
     ps = pools[0].shape[2]
-    L = cfg.num_layers
-    fresh = init_cache(cfg, b, s, dtype=jnp.dtype(cfg.dtype) if kv_quant
-                       else pools[0].dtype)
+    fresh = init_cache(cfg, b, s, dtype=_float_dtype(cfg, pools))
     logits, filled = forward_cached(params, tokens, cfg, fresh)
     flat = ptab.reshape(-1)
-
-    def chunks(x):
-        # [L, b, s, ...] -> page chunks [L, b * s/ps, ps, nh*hd | nh]:
-        # rows as the pool stores them, written where it lies
-        return x.reshape(L, b * (s // ps), ps, -1)
-
+    # [L, b, s, ...] -> page chunks [L, b * s/ps, ps, nh*hd | nh]: rows
+    # as the pool stores them, written where it lies
+    lead = (cfg.num_layers, b * (s // ps), ps)
+    k_half, v_half = _halves(pools)
+    at = (slice(None), flat)
     with jax.named_scope("kv_scatter"):
-        if kv_quant:
-            new = (*quantize_kv(filled["k"]), *quantize_kv(filled["v"]))
-        else:
-            new = (filled["k"], filled["v"])
-        pools = tuple(p.at[:, flat].set(chunks(x))
-                      for p, x in zip(pools, new))
+        pools = (*_write(k_half, at, filled["k"], lead),
+                 *_write(v_half, at, filled["v"], lead))
     with jax.named_scope("head_sample"):
         idx = jnp.clip(lens - 1, 0, s - 1)
         last = jnp.take_along_axis(logits, idx[:, None, None],
@@ -1237,24 +1085,73 @@ def prefill_paged(params, cfg: GPTConfig, pools, tokens, lens, ptab,
     return last, pools
 
 
-def chunk_paged(params, cfg: GPTConfig, pools, tokens, pt_row, offset,
-                kv_quant=False):
-    """(logits [1, C, V], pools): :func:`forward_paged_chunk` or its
-    int8 twin."""
-    fn = forward_paged_chunk_quant if kv_quant else forward_paged_chunk
-    logits, *pools = fn(params, tokens, cfg, *pools, pt_row, offset)
-    return logits, tuple(pools)
+def chunk_paged(params, cfg: GPTConfig, pools, tokens, pt_row, offset):
+    """One chunked-prefill piece for a single slot: consume ``tokens``
+    [1, C] starting at absolute position ``offset`` (a traced scalar, so
+    every chunk of every prompt reuses ONE executable), attending the
+    slot's already-filled pages plus the in-chunk causal prefix.
+    Returns (logits [1, C, V] fp32, pools).
+
+    Per layer: gather the slot's page view (dequantized where the pool
+    is int8), splice the chunk in with the exact `_cached_block` math,
+    then write back ONLY the chunk's own positions — static width C,
+    page-aligned because the engine rounds ``prefill_chunk`` to whole
+    pages and chunk offsets are C-multiples.  Earlier positions are
+    never rewritten (from an unchanged view they would be the same
+    bytes, and on the int8 pool they must not round-trip through
+    requantization: the pager's content-hash contract).  Padded tail
+    rows of the final chunk write garbage at positions past the true
+    prompt length, on the table's scratch-padded page ids or masked by
+    ``len`` until decode overwrites them — the same contract as the
+    wave prefill's pads."""
+    maxP = pt_row.shape[0]
+    ps = pools[0].shape[2]
+    C = tokens.shape[1]
+    heads = (cfg.num_heads, cfg.head_dim)
+    held = _float_dtype(cfg, pools)
+    x = embed(cfg, params, tokens, pos_offset=offset)
+    own = jax.lax.dynamic_slice(pt_row, (offset // ps,), (C // ps,))
+
+    def body(xx, blk, layer, pp):
+        halves = _halves(pp)
+        views = [_unrows(tuple(p[layer, pt_row] for p in half),
+                         (1, maxP * ps), heads, held) for half in halves]
+        xx, *views = _cached_block(cfg, xx, blk, *views, offset)
+        out = ()
+        for half, view in zip(halves, views):
+            chunk = jax.lax.dynamic_slice(view[0], (offset, 0, 0),
+                                          (C,) + heads)
+            out += _write(half, (layer, own), chunk, (C // ps, ps))
+        return xx, out, None
+
+    x, pools, _ = _layer_scan(body, x, params["blocks"], pools)
+    return _head(cfg, params, x), pools
 
 
 def decode_paged(params, cfg: GPTConfig, pools, page_table, write_pages,
-                 write_offs, lens, tokens, mesh=None, kv_quant=False):
-    """(logits [S, V], pools, None): :func:`decode_step_paged` or its
-    int8 twin.  The third value is what a family returns WITH the
-    sampled tokens in the step's one readback; this one has nothing."""
-    step = decode_step_paged_quant if kv_quant else decode_step_paged
-    logits, *pools = step(params, tokens, cfg, *pools, page_table,
-                          write_pages, write_offs, lens, mesh=mesh)
-    return logits, tuple(pools), None
+                 write_offs, lens, tokens, mesh=None):
+    """One decode iteration for every slot through the paged pool:
+    consume one token per slot (at its own ``lens[s]``), return
+    (logits [S, V] fp32, pools, None) — the third value is what a
+    family returns WITH the sampled tokens in the step's one readback;
+    this one has nothing.  Inactive slots point their write coordinates
+    at the scratch page and their table rows at scratch, so the batch
+    shape stays static and their garbage never lands on a real page —
+    the host advances only active lens.  ``mesh``: the 'tp' serving
+    mesh of a head-sharded pool, which the paged-attention kernel needs
+    to run per shard (GSPMD cannot split it;
+    ops/pallas/paged_attn.py::_over_heads)."""
+    with jax.named_scope("embed"):
+        x = _embed_at(params, tokens, lens)
+        x = x[:, None, :].astype(jnp.dtype(cfg.dtype))    # [S, 1, H]
+
+    def body(xx, blk, layer, pp):
+        xx, pp = _paged_slot_block(cfg, xx, blk, layer, pp, page_table,
+                                   write_pages, write_offs, lens, mesh)
+        return xx, pp, None
+
+    x, pools, _ = _layer_scan(body, x, params["blocks"], pools)
+    return _head(cfg, params, x)[:, 0], pools, None
 
 
 # --------------------------------------------------------------------------
@@ -1278,8 +1175,7 @@ def decode_paged(params, cfg: GPTConfig, pools, page_table, write_pages,
 # what keeps the prefix-hash/page-byte determinism contract intact.
 
 
-def _paged_verify_block(cfg, x, blk, layer, k_pool, v_pool, page_table,
-                        lens):
+def _paged_verify_block(cfg, x, blk, layer, pools, page_table, lens):
     """block_apply for the W-token speculative verify window: queries at
     absolute positions ``lens[s] + j`` attend the gathered page view
     with the window's own K/V SPLICED IN at their true positions
@@ -1290,28 +1186,35 @@ def _paged_verify_block(cfg, x, blk, layer, k_pool, v_pool, page_table,
     non-speculative decode's ``maxP * ps``, so each ACCEPTED position's
     activations — and therefore the K/V bytes the engine later commits —
     are bit-identical to a sequential decode, which is what the
-    page-byte determinism regression demands.  x: [S, W, H];
-    k/v_pool: the whole [L, P, ps, nh * hd] pools, read at ``layer``;
-    page_table: int32 [S, maxP].  Returns (x_out, win_k, win_v) with the
-    window K/V [S, W, nh * hd] as a page row stores them, in the POOL
-    dtype (the cast a committed write applies) — the pool itself is
-    untouched."""
+    page-byte determinism regression demands.  The window is spliced in
+    as attention READS BACK what a committed write would store
+    (:func:`_rows`, :func:`_unrows`): on the int8 pool a token's own
+    K/V round-trips through the quantizer before attention sees it,
+    exactly as in the sequential decode.  x: [S, W, H]; pools: the
+    whole pool, read at ``layer`` and untouched; page_table: int32
+    [S, maxP].  Returns (x_out, windows): the window's rows
+    [S, W, nh * hd | nh], one array for each pool array in pool order,
+    as a committed write stores them."""
     S, maxP = page_table.shape
-    ps = k_pool.shape[2]
-    nh, hd = cfg.num_heads, cfg.head_dim
-    view = maxP * ps
+    view = maxP * pools[0].shape[2]
+    heads = (cfg.num_heads, cfg.head_dim)
     cd = jnp.dtype(cfg.dtype)
 
     def vattn(q, k, v):
         W = q.shape[1]
-        kw = k.astype(k_pool.dtype)
-        vw = v.astype(v_pool.dtype)
-        kc = k_pool[layer, page_table].reshape(S, view, nh, hd)
-        vc = v_pool[layer, page_table].reshape(S, view, nh, hd)
         rows = jnp.arange(S)[:, None]
         cols = lens[:, None] + jnp.arange(W)[None, :]
-        kc = kc.at[rows, cols].set(kw)      # OOB window lanes drop
-        vc = vc.at[rows, cols].set(vw)
+        windows, spliced = [], []
+        # K is read in float32 (the score math), V in the compute dtype
+        for half, fresh, dt in zip(_halves(pools), (k, v),
+                                   (jnp.float32, cd)):
+            win = _rows(half, fresh, (S, W))
+            held = _unrows(tuple(p[layer, page_table] for p in half),
+                           (S, view), heads, dt)
+            spliced.append(held.at[rows, cols].set(     # OOB lanes drop
+                _unrows(win, (S, W), heads, dt)))
+            windows += win
+        kcf, vcc = spliced
         # one single-query attention PER LANE (W is small and static):
         # each lane's dot_generals have exactly the one-token decode's
         # shapes, so XLA accumulates in the same order and an accepted
@@ -1323,124 +1226,42 @@ def _paged_verify_block(cfg, x, blk, layer, k_pool, v_pool, page_table,
         # same ``k_pos <= len`` bound the decode applies at the step
         # that would have consumed lane j sequentially; their exp(-1e30)
         # underflows to exactly 0, so their differing values never leak.
-        kcf = kc.astype(jnp.float32)
-        vcc = vc.astype(cd)
         outs = []
         for j in range(W):
             lg = jnp.einsum("sqhd,skhd->shqk",
                             q[:, j:j + 1].astype(jnp.float32),
-                            kcf) / math.sqrt(hd)
+                            kcf) / math.sqrt(heads[1])
             m = jnp.arange(view)[None, :] <= (lens + j)[:, None]
             lg = jnp.where(m[:, None, None, :], lg, -1e30)
             pj = jax.nn.softmax(lg, -1).astype(cd)
             outs.append(jnp.einsum("shqk,skhd->sqhd", pj, vcc))
         a = jnp.concatenate(outs, axis=1)             # [S, W, nh, hd]
-        return a, (_merge_heads(kw), _merge_heads(vw))
+        return a, tuple(windows)
 
-    x, (win_k, win_v) = block_apply(cfg, x, blk, attn_fn=vattn)
-    return x, win_k, win_v
+    return block_apply(cfg, x, blk, attn_fn=vattn)
 
 
-def decode_step_paged_verify(params, tokens, cfg: GPTConfig, cache_k,
-                             cache_v, page_table, lens):
+def decode_step_paged_verify(params, cfg: GPTConfig, pools, tokens,
+                             page_table, lens):
     """Speculative verify forward (ISSUE 13): consume ``tokens`` [S, W]
     (W = spec_k + 1 — the last committed token plus the k draft
     candidates) at absolute positions ``lens[s] + j`` through the paged
     pool, WITHOUT writing it.  Returns (logits [S, W, V] fp32,
-    win_k, win_v [L, S, W, nh * hd] in the pool dtype) — the caller
-    commits the accepted prefix with one masked scatter."""
-    S, W = tokens.shape
+    windows): the window's rows [L, S, W, nh * hd | nh] in pool order,
+    exactly the bytes (and, on the int8 pool, the once-per-position
+    scales) a sequential decode would have written — the caller commits
+    the accepted prefix with one masked scatter per pool array."""
+    W = tokens.shape[1]
     pos = lens[:, None] + jnp.arange(W)[None, :]
-    x = jnp.take(params["wte"], tokens, axis=0) \
-        + jnp.take(params["wpe"], pos, axis=0)
-    x = x.astype(jnp.dtype(cfg.dtype))                    # [S, W, H]
+    x = _embed_at(params, tokens, pos).astype(jnp.dtype(cfg.dtype))
 
-    def body(xx, blk, layer, pools):
-        xx, kw, vw = _paged_verify_block(cfg, xx, blk, layer, *pools,
-                                         page_table, lens)
-        return xx, pools, (kw, vw)
+    def body(xx, blk, layer, pp):
+        xx, win = _paged_verify_block(cfg, xx, blk, layer, pp, page_table,
+                                      lens)
+        return xx, pp, win
 
-    x, _, (wk, wv) = _layer_scan(body, x, params["blocks"],
-                                 (cache_k, cache_v))
-    x = _layer_norm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
-    logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
-    return logits, wk, wv
-
-
-def _paged_verify_block_quant(cfg, x, blk, layer, k_pool, k_scale, v_pool,
-                              v_scale, page_table, lens):
-    """:func:`_paged_verify_block` over the int8 pool.  The window K/V
-    quantize IMMEDIATELY (per-position absmax, exactly the bytes a
-    committed write stores) and the in-window attention reads them back
-    DEQUANTIZED — mirroring the sequential int8 decode, where a token's
-    own K/V round-trips through the quantizer before attention sees it,
-    so accepted positions reproduce the non-speculative logits and page
-    bytes exactly.  Returns (x_out, win_kq, win_ks, win_vq, win_vs)."""
-    S, maxP = page_table.shape
-    ps = k_pool.shape[2]
-    nh, hd = cfg.num_heads, cfg.head_dim
-    view = maxP * ps
-    cd = jnp.dtype(cfg.dtype)
-
-    def vattn(q, k, v):
-        W = q.shape[1]
-        kq, ks = quantize_kv(k)                   # [S, W, nh, hd] int8
-        vq, vs = quantize_kv(v)
-        kw = dequantize_kv(kq, ks, jnp.float32)
-        vw = dequantize_kv(vq, vs, jnp.float32)
-        kc = _dequantize_view(
-            k_pool[layer, page_table], k_scale[layer, page_table],
-            jnp.float32).reshape(S, view, nh, hd)
-        vc = _dequantize_view(
-            v_pool[layer, page_table], v_scale[layer, page_table],
-            jnp.float32).reshape(S, view, nh, hd)
-        rows = jnp.arange(S)[:, None]
-        cols = lens[:, None] + jnp.arange(W)[None, :]
-        kc = kc.at[rows, cols].set(kw)      # OOB window lanes drop
-        vc = vc.at[rows, cols].set(vw)
-        # per-lane single-query attention for bitwise parity with the
-        # sequential int8 decode — see _paged_verify_block
-        vcc = vc.astype(cd)
-        outs = []
-        for j in range(W):
-            lg = jnp.einsum("sqhd,skhd->shqk",
-                            q[:, j:j + 1].astype(jnp.float32),
-                            kc) / math.sqrt(hd)
-            m = jnp.arange(view)[None, :] <= (lens + j)[:, None]
-            lg = jnp.where(m[:, None, None, :], lg, -1e30)
-            pj = jax.nn.softmax(lg, -1).astype(cd)
-            outs.append(jnp.einsum("shqk,skhd->sqhd", pj, vcc))
-        a = jnp.concatenate(outs, axis=1)
-        return a, (_merge_heads(kq), ks, _merge_heads(vq), vs)
-
-    x, (kq, ks, vq, vs) = block_apply(cfg, x, blk, attn_fn=vattn)
-    return x, kq, ks, vq, vs
-
-
-def decode_step_paged_verify_quant(params, tokens, cfg: GPTConfig,
-                                   cache_k, k_scale, cache_v, v_scale,
-                                   page_table, lens):
-    """:func:`decode_step_paged_verify` over the INT8 paged pool.
-    Returns (logits [S, W, V] fp32, win_kq [L, S, W, nh * hd] int8,
-    win_ks [L, S, W, nh] fp32, win_vq, win_vs) — quantized exactly once
-    per window position, so the caller's masked commit lands the same
-    bytes AND scales a sequential int8 decode would have."""
-    S, W = tokens.shape
-    pos = lens[:, None] + jnp.arange(W)[None, :]
-    x = jnp.take(params["wte"], tokens, axis=0) \
-        + jnp.take(params["wpe"], pos, axis=0)
-    x = x.astype(jnp.dtype(cfg.dtype))
-
-    def body(xx, blk, layer, pools):
-        xx, *win = _paged_verify_block_quant(
-            cfg, xx, blk, layer, *pools, page_table, lens)
-        return xx, pools, tuple(win)
-
-    x, _, (wkq, wks, wvq, wvs) = _layer_scan(
-        body, x, params["blocks"], (cache_k, k_scale, cache_v, v_scale))
-    x = _layer_norm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
-    logits = (x @ params["wte"].astype(x.dtype).T).astype(jnp.float32)
-    return logits, wkq, wks, wvq, wvs
+    x, _, windows = _layer_scan(body, x, params["blocks"], pools)
+    return _head(cfg, params, x), windows
 
 
 def draft_prefill_slot(params, tokens, cfg: GPTConfig, cache_k, cache_v,

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from ..framework import jax_compat
 from ..framework.jax_compat import partition_spec as P
 from ..distributed.auto.pipeline import StageAssignment, pipeline_stage_loop
-from .gpt import KV_POOL_SPEC_PP, _layer_norm, _layer_scan, _merge_heads
+from .gpt import KV_POOL_SPEC_PP, _layer_norm, _layer_scan
 
 
 def check_pp_config(cfg, pp):
@@ -99,9 +99,9 @@ def _pp_paged_block(cfg, x, blk, layer, kp, vp, page_table, write_pages,
     nh_loc = qkv.shape[-1] // hd
     q, k, v = [qkv[:, :, i].reshape(S, T, nh_loc, hd) for i in range(3)]
     at = (layer, write_pages, write_offs)
-    kc = kp.at[at].set(_merge_heads(k[:, 0]).astype(kp.dtype))
-    vc = vp.at[at].set(_merge_heads(v[:, 0]).astype(vp.dtype))
-    a = paged_attention(q, kc, vc, page_table, lens, layer)
+    kc = kp.at[at].set(k[:, 0].reshape(S, -1).astype(kp.dtype))
+    vc = vp.at[at].set(v[:, 0].reshape(S, -1).astype(vp.dtype))
+    a = paged_attention(q, (kc, vc), page_table, lens, layer)
     a = a.reshape(S, T, -1)
     a = jax.lax.psum(a @ blk["proj_w"].astype(cd), "tp") \
         + blk["proj_b"].astype(cd)
@@ -156,7 +156,7 @@ def _pp_prefill_block(cfg, x, blk, pool_dtype):
 def make_decode_step(cfg, mesh, param_specs, n_microbatch):
     """The pp x tp paged decode step: ``fn(params, toks, ck, cv,
     page_table, wpages, woffs, lens) -> (logits [S, V] fp32, ck, cv)``
-    — same contract as models/gpt.py::decode_step_paged, but the body
+    — same contract as models/gpt.py::decode_paged, but the body
     is one shard_map over ``mesh`` running the 1F1B tick loop: slots
     split into ``n_microbatch`` groups, each group's activation hops
     the stage ring via ppermute while every stage appends the group's

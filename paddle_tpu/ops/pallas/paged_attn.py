@@ -3,7 +3,7 @@ K/V through a block page table instead of a contiguous per-slot strip.
 
 Extends flash_attn.py's blocked online-softmax scaffolding to the paged
 KV layout the serving engine owns: K/V live in ONE pool per engine,
-``[L, num_pages, page_size, nh * hd]`` (models/gpt.py::init_paged_cache:
+``[L, num_pages, page_size, nh * hd]`` (models/gpt.py::init_paged_pools:
 a page is ``page_size`` rows of all heads side by side, so the minor
 axis is a multiple of the 128 lanes and the device keeps the array
 major-to-minor with no padding), and each decode lane's logical sequence
@@ -279,7 +279,8 @@ def group_pages(table_width, page_size, width, itemsize, heads):
     return g
 
 
-def _paged_call(q, pools, scales, page_table, lens, layer, interpret):
+def _paged_call(q, pools, scales, page_table, lens, layer,
+                interpret=False):
     """The one ``pallas_call`` both pools go through.  ``pools`` is the
     engine's whole (k, v) pool, [L, P, ps, nh * hd] each; ``scales`` is
     () for the fp pool and the layer's (k_scale, v_scale), [P, ps, nh],
@@ -298,7 +299,7 @@ def _paged_call(q, pools, scales, page_table, lens, layer, interpret):
     pt_flat = page_table.reshape(-1).astype(jnp.int32)
     lens32 = lens.astype(jnp.int32)
     layer1 = jnp.reshape(layer, (1,)).astype(jnp.int32)
-    quant = bool(scales)
+    quant = len(scales) > 0
 
     def scale_rows(g):
         def index(s, j, pt, ln, ly):
@@ -335,17 +336,22 @@ def _paged_call(q, pools, scales, page_table, lens, layer, interpret):
     return out.reshape(S, 1, nh, hd)
 
 
-def _over_heads(fn, mesh, q, pools, scales, *table_lens_layer):
-    """``fn(q, *pools, *scales, page_table, lens, layer)``, run per 'tp'
-    shard of the head axis under a mesh.  The tensor-parallel engine is
-    a GSPMD ``jit`` over head-sharded pools, and the partitioner cannot
-    split a Mosaic call ("Mosaic kernels cannot be automatically
-    partitioned"), so the kernel is wrapped in a ``shard_map``: each
-    rank runs it on its own nh/tp heads of every page — a contiguous
-    column range of the merged axis — with the page table, lengths and
-    layer index replicated.  Heads are axis 2 of q and of a layer's
-    int8 scale rows, and the last axis (3) of the pools."""
-    args = (q, *pools, *scales, *table_lens_layer)
+def _over_heads(mesh, q, pools, scales, page_table, lens, layer,
+                interpret=False):
+    """:func:`_paged_call`, run per 'tp' shard of the head axis under a
+    mesh.  The tensor-parallel engine is a GSPMD ``jit`` over
+    head-sharded pools, and the partitioner cannot split a Mosaic call
+    ("Mosaic kernels cannot be automatically partitioned"), so the
+    kernel is wrapped in a ``shard_map``: each rank runs it on its own
+    nh/tp heads of every page — a contiguous column range of the merged
+    axis — with the page table, lengths and layer index replicated.
+    Heads are axis 2 of q and of a layer's int8 scale rows, and the last
+    axis (3) of the pools."""
+    def fn(q, *rest):
+        return _paged_call(q, rest[:2], rest[2:-3], *rest[-3:],
+                           interpret=interpret)
+
+    args = (q, *pools, *scales, page_table, lens, layer)
     if mesh is None:
         return fn(*args)
     from ...framework.jax_compat import partition_spec as P, shard_map
@@ -354,15 +360,8 @@ def _over_heads(fn, mesh, q, pools, scales, *table_lens_layer):
     return shard_map(fn, mesh=mesh,
                      in_specs=((heads,) + (pool,) * len(pools)
                                + (heads,) * len(scales)
-                               + (P(),) * len(table_lens_layer)),
+                               + (P(),) * 3),
                      out_specs=heads, check_vma=False)(*args)
-
-
-def _paged_attention_tpu(q, k_pool, v_pool, page_table, lens, layer,
-                         interpret=False):
-    """q: [S, 1, nh, hd] -> [S, 1, nh, hd] through the Pallas kernel."""
-    return _paged_call(q, (k_pool, v_pool), (), page_table, lens, layer,
-                       interpret)
 
 
 def _use_pallas_paged(k_pool, nh, mesh=None):
@@ -387,29 +386,6 @@ def _layer_pages(pool, layer):
     return jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
 
 
-def paged_attention(q, k_pool, v_pool, page_table, lens, layer, mesh=None):
-    """Decode attention through a page table.  q: [S, 1, nh, hd] (one
-    new token per slot, already scattered into its page); k/v_pool: the
-    whole pool, [L, P, ps, nh * hd]; page_table: int32 [S, maxP]; lens:
-    int32 [S]; layer: int32 scalar, which layer's pages to read.
-    Returns [S, 1, nh, hd].  Inference-only (no custom VJP): the decode
-    step never differentiates.  ``mesh``: the serving mesh when the
-    caller is a GSPMD program over head-sharded pools
-    (:func:`_over_heads`); callers already inside a ``shard_map`` pass
-    their local shards and no mesh."""
-    if _use_pallas_paged(k_pool, q.shape[2], mesh):
-        count_paged_kernel()
-        return _over_heads(_paged_attention_tpu, mesh, q, (k_pool, v_pool),
-                           (), page_table, lens, layer)
-    return _ref_paged_attention(q, _layer_pages(k_pool, layer),
-                                _layer_pages(v_pool, layer), page_table,
-                                lens)
-
-
-# --------------------------------------------------------------------------
-# quantized pages (ISSUE 9): int8 K/V + per-position-per-head scales
-# --------------------------------------------------------------------------
-
 def _ref_paged_attention_quant(q, k_pages, k_scale, v_pages, v_scale,
                                page_table, lens):
     """Lax fallback over ONE layer of the int8 pool: dequantize
@@ -428,32 +404,34 @@ def _ref_paged_attention_quant(q, k_pages, k_scale, v_pages, v_scale,
                                 deq(v_pages, v_scale), page_table, lens)
 
 
-def _paged_attention_quant_tpu(q, k_pool, v_pool, k_scale, v_scale,
-                               page_table, lens, layer, interpret=False):
-    """Quantized-pool Pallas path: the layer's scale rows ride their own
-    page-indexed BlockSpecs next to the int8 pages."""
-    return _paged_call(q, (k_pool, v_pool), (k_scale, v_scale), page_table,
-                       lens, layer, interpret)
-
-
-def paged_attention_quant(q, k_pool, k_scale, v_pool, v_scale,
-                          page_table, lens, layer, mesh=None):
-    """Decode attention through a page table over the INT8 pool:
-    k/v_pool [L, P, ps, nh * hd] int8 with per-position-per-head fp32
-    scales [L, P, ps, nh]; the scales fold into the scores and the
-    probabilities on read (in-kernel on TPU).  Same contract, ``mesh``
-    and kernel gate as :func:`paged_attention`.  The kernel takes the
-    int8 pools whole and the scales as the layer's slice: 32 heads on
-    the minor axis is a layout the device stores page-minor, so that
-    slice (4 / hd of a layer's page bytes) is the one thing still relaid
-    out for the call (PERF.md section 7)."""
-    ks, vs = _layer_pages(k_scale, layer), _layer_pages(v_scale, layer)
-    if _use_pallas_paged(k_pool, q.shape[2], mesh):
+def paged_attention(q, pools, page_table, lens, layer, mesh=None):
+    """Decode attention through a page table.  q: [S, 1, nh, hd] (one
+    new token per slot, already scattered into its page); ``pools``:
+    the whole pool in the engine's operand order — (k, v), each
+    [L, P, ps, nh * hd], or for the int8 pool (k, k_scale, v, v_scale)
+    with per-position-per-head fp32 scales [L, P, ps, nh], which fold
+    into the scores and the probabilities on read (in-kernel on TPU);
+    page_table: int32 [S, maxP]; lens: int32 [S]; layer: int32 scalar,
+    which layer's pages to read.  Returns [S, 1, nh, hd].
+    Inference-only (no custom VJP): the decode step never
+    differentiates.  ``mesh``: the serving mesh when the caller is a
+    GSPMD program over head-sharded pools (:func:`_over_heads`);
+    callers already inside a ``shard_map`` pass their local shards and
+    no mesh.  The kernel takes the pages whole and the scales as the
+    layer's slice: 32 heads on the minor axis is a layout the device
+    stores page-minor, so that slice (4 / hd of a layer's page bytes)
+    is the one thing still relaid out for the call (PERF.md section
+    7)."""
+    pages, scales = ((pools, ()) if len(pools) == 2
+                     else (pools[::2], pools[1::2]))
+    scales = tuple(_layer_pages(s, layer) for s in scales)
+    if _use_pallas_paged(pages[0], q.shape[2], mesh):
         count_paged_kernel()
-        count_dequant_kernel("paged_attn")
-        return _over_heads(_paged_attention_quant_tpu, mesh, q,
-                           (k_pool, v_pool), (ks, vs), page_table, lens,
-                           layer)
-    return _ref_paged_attention_quant(
-        q, _layer_pages(k_pool, layer), ks, _layer_pages(v_pool, layer),
-        vs, page_table, lens)
+        if scales:
+            count_dequant_kernel("paged_attn")
+        return _over_heads(mesh, q, pages, scales, page_table, lens, layer)
+    k, v = (_layer_pages(p, layer) for p in pages)
+    if scales:
+        return _ref_paged_attention_quant(q, k, scales[0], v, scales[1],
+                                          page_table, lens)
+    return _ref_paged_attention(q, k, v, page_table, lens)

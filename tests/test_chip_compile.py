@@ -67,9 +67,10 @@ HEADS = [(32, 64), (16, 128)]
 
 def paged_args(chip, nh, hd, ps, dtype, slots=BENCH_SLOTS,
                layers=BENCH_LAYERS, pages=BENCH_PAGES, max_len=MAX_LEN):
-    """(q, k_pool, v_pool), (page_table, lens, layer) of the kernel."""
+    """(q, (k_pool, v_pool)), (page_table, lens, layer) of the kernel
+    (``_paged_call``'s operands but the int8 pool's scales)."""
     pool = chip((layers, pages, ps, nh * hd), dtype)
-    return ((chip((slots, 1, nh, hd), bf16), pool, pool),
+    return ((chip((slots, 1, nh, hd), bf16), (pool, pool)),
             (chip((slots, max_len // ps), i32), chip((slots,), i32),
              chip((), i32)))
 
@@ -77,25 +78,24 @@ def paged_args(chip, nh, hd, ps, dtype, slots=BENCH_SLOTS,
 @pytest.mark.parametrize("nh,hd", HEADS)
 @pytest.mark.parametrize("ps", [16, 32])
 def test_paged_attention_fp(chip, nh, hd, ps):
-    from paddle_tpu.ops.pallas.paged_attn import _paged_attention_tpu
+    from paddle_tpu.ops.pallas.paged_attn import _paged_call
     qkv, rest = paged_args(chip, nh, hd, ps, bf16)
-    assert compiles(_paged_attention_tpu, *qkv, *rest) == 1
+    assert compiles(_paged_call, *qkv, (), *rest) == 1
 
 
 @pytest.mark.parametrize("nh,hd", HEADS)
 def test_paged_attention_int8(chip, nh, hd, ps=32):
-    from paddle_tpu.ops.pallas.paged_attn import _paged_attention_quant_tpu
+    from paddle_tpu.ops.pallas.paged_attn import _paged_call
     qkv, rest = paged_args(chip, nh, hd, ps, i8)
     scale = chip((BENCH_PAGES, ps, nh), f32)
-    assert compiles(_paged_attention_quant_tpu, *qkv, scale, scale,
-                    *rest) == 1
+    assert compiles(_paged_call, *qkv, (scale, scale), *rest) == 1
 
 
 def test_paged_attention_one_tp_shard(chip):
     """What one rank of a tp=4 engine runs: 8 of the 32 heads."""
-    from paddle_tpu.ops.pallas.paged_attn import _paged_attention_tpu
+    from paddle_tpu.ops.pallas.paged_attn import _paged_call
     qkv, rest = paged_args(chip, 8, 64, 16, bf16)
-    assert compiles(_paged_attention_tpu, *qkv, *rest) == 1
+    assert compiles(_paged_call, *qkv, (), *rest) == 1
 
 
 @pytest.mark.parametrize("dtype,group", [(f32, 1), (bf16, 2)],
@@ -113,7 +113,7 @@ def test_paged_attention_largest_admitted_step(chip, dtype, group):
     assert paged_attn.group_pages(64, 64, 32 * 256, itemsize, 32) == group
     assert paged_attn._step_vmem_bytes(
         group, 64, 32 * 256, itemsize, 32) == paged_attn._MAX_STEP_VMEM_BYTES
-    assert compiles(paged_attn._paged_attention_tpu, *qkv, *rest) == 1
+    assert compiles(paged_attn._paged_call, *qkv, (), *rest) == 1
 
 
 def test_paged_attention_gate_follows_the_compiler(chip, monkeypatch):
@@ -125,13 +125,13 @@ def test_paged_attention_gate_follows_the_compiler(chip, monkeypatch):
     monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
     small = dict(slots=4, layers=2, pages=64)
     qkv, rest = paged_args(chip, 2, 16, 8, bf16, **small)
-    assert not paged_attn._use_pallas_paged(qkv[1], 2)
+    assert not paged_attn._use_pallas_paged(qkv[1][0], 2)
     with pytest.raises(Exception, match="aligned to tiling"):
-        compiles(paged_attn._paged_attention_tpu, *qkv, *rest)
+        compiles(paged_attn._paged_call, *qkv, (), *rest)
     qkv, rest = paged_args(chip, 2, 64, 8, bf16, **small)
-    assert paged_attn._use_pallas_paged(qkv[1], 2)
+    assert paged_attn._use_pallas_paged(qkv[1][0], 2)
     assert paged_attn.group_pages(MAX_LEN // 8, 8, 128, 2, 2) == 1
-    assert compiles(paged_attn._paged_attention_tpu, *qkv, *rest) == 1
+    assert compiles(paged_attn._paged_call, *qkv, (), *rest) == 1
 
 
 # --------------------------------------------------------------------------
@@ -264,14 +264,14 @@ def test_kernel_names_and_scopes_reach_the_compiled_program(chip):
     """A ``pl.pallas_call``'s ``name=`` becomes the HLO instruction's
     name and, with the ``jax.named_scope``s around it, its ``op_name``:
     what a profiler trace of the chip shows for the kernel."""
-    from paddle_tpu.ops.pallas.paged_attn import _paged_attention_tpu
+    from paddle_tpu.ops.pallas.paged_attn import _paged_call
 
     def layer(*args):
         with jax.named_scope("layer"), jax.named_scope("paged_attn"):
-            return _paged_attention_tpu(*args)
+            return _paged_call(*args)
 
     qkv, rest = paged_args(chip, 32, 64, 16, bf16)
-    text = jax.jit(layer).lower(*qkv, *rest).compile().as_text()
+    text = jax.jit(layer).lower(*qkv, (), *rest).compile().as_text()
     (call,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert call.lstrip().startswith("%paged_attn_decode")
     assert 'op_name="jit(layer)/layer/paged_attn/paged_attn_decode' in call

@@ -278,6 +278,44 @@ def _make_engine(tiny_model, **kw):
     return PagedServingEngine(tiny_model, **kw)
 
 
+@pytest.mark.parametrize("family", ["gpt", "deepseek_v3"])
+def test_only_init_paged_pools_is_told_the_pool_format(family):
+    """A family is TOLD which pool to make once; its programs read what
+    they were handed off the pools themselves."""
+    import importlib
+    import inspect
+    from paddle_tpu.inference import serving
+    mod = importlib.import_module(f"paddle_tpu.models.{family}")
+    told = [name for name in serving.FAMILY_INTERFACE if "kv_quant"
+            in inspect.signature(getattr(mod, name)).parameters]
+    assert told == ["init_paged_pools"]
+
+
+@pytest.mark.parametrize("kv_dtype,dtypes", [
+    (None, ["float32"] * 2), ("int8", ["int8", "float32"] * 2)])
+def test_engine_holds_the_pool_as_the_family_returned_it(
+        tiny_model, monkeypatch, kv_dtype, dtypes):
+    from paddle_tpu.models import gpt as G
+    made = []
+
+    def spy(*args, real=G.init_paged_pools, **kw):
+        made.append(real(*args, **kw))
+        return made[-1]
+
+    monkeypatch.setattr(G, "init_paged_pools", spy)
+    eng = _make_engine(tiny_model, kv_dtype=kv_dtype)
+    ops = eng._cache_operands()
+    assert isinstance(ops, tuple) and eng._n_cache == len(dtypes)
+    assert len(ops) == len(made[-1]) and all(
+        a is b for a, b in zip(ops, made[-1]))
+    # the family's order: K's arrays, then V's (pages, then their scales)
+    assert [str(a.dtype) for a in ops] == dtypes
+    eng.submit(np.arange(1, 12, dtype=np.int32), 3)
+    eng.run()
+    assert [(a.shape, a.dtype) for a in eng._cache_operands()] == [
+        (a.shape, a.dtype) for a in made[-1]]
+
+
 class TestPagedEngine:
     def test_parity_across_churned_slots(self, tiny_model):
         eng = _make_engine(tiny_model, capture_logits=True)
@@ -916,18 +954,11 @@ def _kernel_reference(q, pools, scales, pt, lens, layer):
 
 
 def _kernel_run(q, pools, scales, pt, lens, layer, mesh=None):
-    import functools
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas import paged_attn
-    if scales:
-        fn = functools.partial(paged_attn._paged_attention_quant_tpu,
-                               interpret=True)
-        scales = [s[layer] for s in scales]
-    else:
-        fn = functools.partial(paged_attn._paged_attention_tpu,
-                               interpret=True)
-    return paged_attn._over_heads(fn, mesh, q, pools, scales, pt, lens,
-                                  jnp.int32(layer))
+    return paged_attn._over_heads(mesh, q, pools,
+                                  [s[layer] for s in scales], pt, lens,
+                                  jnp.int32(layer), interpret=True)
 
 
 class TestPagedKernelOnTheStoredPool:

@@ -536,7 +536,7 @@ class TestPagedAttentionQuantKernel:
     def test_kernel_matches_lax_fallback(self, S, nh, hd, P, ps, maxP):
         import jax.numpy as jnp
         from paddle_tpu.ops.pallas.paged_attn import (
-            _paged_attention_quant_tpu, _ref_paged_attention_quant)
+            _paged_call, _ref_paged_attention_quant)
         rng = np.random.RandomState(S + P)
         q = jnp.asarray(rng.randn(S, 1, nh, hd).astype(np.float32))
         kq = jnp.asarray(rng.randint(-127, 128, (P, ps, nh, hd))
@@ -551,9 +551,9 @@ class TestPagedAttentionQuantKernel:
         lens = jnp.asarray(
             rng.randint(0, maxP * ps, (S,)).astype(np.int32))
         ref = _ref_paged_attention_quant(q, kq, ks, vq, vs, pt, lens)
-        got = _paged_attention_quant_tpu(
-            q, _pool_of(kq), _pool_of(vq), ks, vs, pt, lens, jnp.int32(0),
-            interpret=True)
+        got = _paged_call(
+            q, (_pool_of(kq), _pool_of(vq)), (ks, vs), pt, lens,
+            jnp.int32(0), interpret=True)
         assert float(jnp.abs(ref - got).max()) < 1e-5
 
     def test_kernel_matches_fallback_bf16(self):
@@ -562,7 +562,7 @@ class TestPagedAttentionQuantKernel:
         a missing cast; bf16 can."""
         import jax.numpy as jnp
         from paddle_tpu.ops.pallas.paged_attn import (
-            _paged_attention_quant_tpu, _ref_paged_attention_quant)
+            _paged_call, _ref_paged_attention_quant)
         rng = np.random.RandomState(9)
         q = jnp.asarray(rng.randn(3, 1, 2, 32)).astype(jnp.bfloat16)
         kq = jnp.asarray(rng.randint(-127, 128, (8, 8, 2, 32))
@@ -576,9 +576,9 @@ class TestPagedAttentionQuantKernel:
         pt = jnp.asarray(rng.randint(0, 8, (3, 3)).astype(np.int32))
         lens = jnp.asarray(rng.randint(0, 24, (3,)).astype(np.int32))
         ref = _ref_paged_attention_quant(q, kq, ks, vq, vs, pt, lens)
-        got = _paged_attention_quant_tpu(
-            q, _pool_of(kq), _pool_of(vq), ks, vs, pt, lens, jnp.int32(0),
-            interpret=True)
+        got = _paged_call(
+            q, (_pool_of(kq), _pool_of(vq)), (ks, vs), pt, lens,
+            jnp.int32(0), interpret=True)
         diff = jnp.abs(ref.astype(jnp.float32)
                        - got.astype(jnp.float32))
         # bf16 accumulate: identical dtype semantics, bf16-ulp noise
@@ -587,7 +587,7 @@ class TestPagedAttentionQuantKernel:
     def test_kernel_len_zero_lane(self):
         import jax.numpy as jnp
         from paddle_tpu.ops.pallas.paged_attn import (
-            _paged_attention_quant_tpu, _ref_paged_attention_quant)
+            _paged_call, _ref_paged_attention_quant)
         rng = np.random.RandomState(7)
         q = jnp.asarray(rng.randn(2, 1, 2, 16).astype(np.float32))
         kq = jnp.asarray(rng.randint(-127, 128, (5, 8, 2, 16))
@@ -599,7 +599,7 @@ class TestPagedAttentionQuantKernel:
         pt = jnp.asarray(rng.randint(0, 5, (2, 2)).astype(np.int32))
         lens = jnp.asarray(np.array([0, 9], np.int32))
         ref = _ref_paged_attention_quant(q, kq, ks, vq, vs, pt, lens)
-        got = _paged_attention_quant_tpu(
-            q, _pool_of(kq), _pool_of(vq), ks, vs, pt, lens, jnp.int32(0),
-            interpret=True)
+        got = _paged_call(
+            q, (_pool_of(kq), _pool_of(vq)), (ks, vs), pt, lens,
+            jnp.int32(0), interpret=True)
         assert float(jnp.abs(ref - got).max()) < 1e-5

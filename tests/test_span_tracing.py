@@ -343,10 +343,13 @@ def _op_count(lowered):
             text.count("tpu_custom_call"))
 
 
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
 def test_named_scopes_are_in_the_lowered_steps_and_add_no_op(
-        tiny_model, monkeypatch):
+        tiny_model, monkeypatch, kv_dtype):
+    """Both pools run ONE body of each program (models/gpt.py), so the
+    int8 programs carry the same names."""
     import jax
-    eng = _engine(tiny_model)
+    eng = _engine(tiny_model, kv_dtype=kv_dtype)
     decode, prefill = _lowered_decode_and_prefill(eng)
     text = decode.as_text(debug_info=True)
     for scope in ("embed/", "layer/ln_qkv/", "layer/kv_write/",

@@ -368,13 +368,20 @@ class TestSpecRejectByteParity:
         assert rb.tokens == rs.tokens
         return base, spec
 
+    @staticmethod
+    def _assert_pools_identical(base, spec, n_arrays, when):
+        """Every pool array but its scratch page, byte for byte."""
+        pools = base._cache_operands(), spec._cache_operands()
+        assert len(pools[0]) == len(pools[1]) == n_arrays
+        for i, (a, b) in enumerate(zip(*pools)):
+            assert (np.asarray(a)[:, 1:] == np.asarray(b)[:, 1:]).all(), \
+                f"pool array {i} diverged from the never-speculated " \
+                f"run {when}"
+
     def test_fp_pool_bytes_identical(self, tiny_model):
         base, spec = self._run_pair(tiny_model, "spec_reject:step=2")
-        for name in ("_cache_k", "_cache_v"):
-            a = np.asarray(getattr(base, name))[:, 1:]
-            b = np.asarray(getattr(spec, name))[:, 1:]
-            assert (a == b).all(), f"{name} diverged from the " \
-                "never-speculated run after an all-reject verify"
+        self._assert_pools_identical(base, spec, 2,
+                                     "after an all-reject verify")
 
     def test_int8_pool_and_scales_identical(self, tiny_model):
         # repeat=1 with no step filter: EVERY verify all-rejects — the
@@ -384,11 +391,8 @@ class TestSpecRejectByteParity:
         base, spec = self._run_pair(tiny_model, "spec_reject:repeat=1",
                                     quant="int8", kv_dtype="int8")
         assert spec.stats()["accepted_tokens"] == 0
-        for name in ("_cache_k", "_cache_ks", "_cache_v", "_cache_vs"):
-            a = np.asarray(getattr(base, name))[:, 1:]
-            b = np.asarray(getattr(spec, name))[:, 1:]
-            assert (a == b).all(), f"{name} diverged from the " \
-                "never-speculated run under forced all-reject"
+        self._assert_pools_identical(base, spec, 4,  # pages and scales
+                                     "under forced all-reject")
 
     def test_accepting_run_pool_bytes_identical(self, tiny_model):
         """Stronger than the fault case: even a NORMALLY-accepting spec
@@ -406,10 +410,8 @@ class TestSpecRejectByteParity:
         spec.run(max_steps=200)
         assert rb.tokens == rs.tokens
         assert spec.stats()["accepted_tokens"] > 0
-        for name in ("_cache_k", "_cache_v"):
-            a = np.asarray(getattr(base, name))[:, 1:]
-            b = np.asarray(getattr(spec, name))[:, 1:]
-            assert (a == b).all(), name
+        self._assert_pools_identical(base, spec, 2,
+                                     "in a normally accepting run")
 
 
 # --------------------------------------------------------------------------

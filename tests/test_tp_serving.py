@@ -66,11 +66,12 @@ class TestTPEngine:
         assert eng.stats()["tp"] == 2
         # the pool really shards the head axis: each device holds the
         # nh/2 heads' contiguous half of every page row [ps, nh * hd]
-        shards = eng._cache_k.addressable_shards
+        k_pool = eng._cache_operands()[0]
+        shards = k_pool.addressable_shards
         cfg = tiny_model[1]
         assert len(shards) == 2
-        assert eng._cache_k.shape[2:] == (eng._page_size,
-                                          cfg.num_heads * cfg.head_dim)
+        assert k_pool.shape[2:] == (eng._page_size,
+                                    cfg.num_heads * cfg.head_dim)
         assert shards[0].data.shape[3] == cfg.num_heads // 2 * cfg.head_dim
 
     @pytest.mark.slow      # ~18s; tier-1 budget (per-shard bytes
