@@ -87,6 +87,12 @@ LOGIT_TOL = {"fp": 0.15, "int8": 0.4}
 # precision slip moves every row, those too), and the worst row to half
 # of what an unrelated row reads (about 6: a paging fault).
 LATENT_QUARTILE_TOL, LATENT_MAX_TOL = 0.15, 3.0
+# The overlapped loop's tokens against the drained loop's: the same bf16
+# mathematics compiled twice (one program also returns its logits), so a
+# token may differ only where the drained engine's own row holds a
+# near-tie; a fifth of the bf16-against-float32 bound.  A token read from
+# another slot's row, or a step late, sits of the order of 1 down.
+OVERLAP_TIE_TOL = 0.03
 # what the chip read in PR 21, printed beside every later reading: a
 # precision slip in the paged kernel's reductions (a bf16 rounding of
 # q.k products, say) moves the reading long before it reaches the bound
@@ -367,11 +373,13 @@ def prompts_for(run, cfg, n=None):
 
 
 def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
-                   page_size=16):
+                   page_size=16, capture_logits=True):
     """Build one engine, warm it, and answer ``prompts`` (then two of
     them again, so the prefix cache has something to hit) as a client
     would: ``submit`` + ``step``.  Returns the finished requests and the
-    engine's own account of the run; the engine is gone on return."""
+    engine's own account of the run; the engine is gone on return.
+    ``capture_logits`` engines read every step back before the next (the
+    drained loop); without it the engine runs a step ahead."""
     from paddle_tpu.inference.serving import PagedServingEngine
 
     sz = run.sz
@@ -384,7 +392,7 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
     t0 = time.perf_counter()
     eng = PagedServingEngine(
         (params, cfg), slots=sz.slots, max_len=sz.max_len,
-        prefix_cache=True, capture_logits=True,
+        prefix_cache=True, capture_logits=capture_logits,
         seq_buckets=sz.seq_buckets, batch_buckets=sz.batch_buckets,
         tp=tp, **kw)
     placed = run.bytes_in_use() if tp else None
@@ -451,6 +459,12 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
         "decode_compiles": st["decode_compiles"],
         "prefill_compiles": st["prefill_compiles"],
         "decode_steps": st["decode_steps"],
+        # decode dispatches made before the step before was read back,
+        # and what made the loop read back first, by reason
+        "steps_overlapped": st["steps_overlapped"],
+        "steps_overlapped_share": round(
+            st["steps_overlapped"] / max(1, st["decode_steps"]), 4),
+        "drains": st["drains"],
         "prefix_page_hits": st["prefix_page_hits"],
         "preemptions": st["preemptions"],
         "kv_bytes_total": st["kv_bytes_total"],
@@ -489,7 +503,7 @@ def pool_relayouts_of(eng):
     for b, s in {(eng.batch_buckets[0], eng.seq_buckets[0]),
                  (eng.batch_buckets[-1], eng.seq_buckets[-1])}:
         programs[f"prefill_{b}x{s}"] = (eng._build_prefill(b, s), (
-            ints(b, s), ints(b), ints(b, s // ps)))
+            ints(b, s), ints(b), ints(b, s // ps), ints(slots), ints(b)))
     # a compiled mesh program shows each device's shard of the pool
     local = [jax.ShapeDtypeStruct(a.sharding.shard_shape(a.shape), a.dtype)
              for a in pools]
@@ -563,6 +577,51 @@ def phase_serve(run, params, cfg, pool):
         raise AssertionError(
             f"serve_{pool}: logits off the float32 reference by {worst} "
             f"row-std (> {LOGIT_TOL[pool]})")
+    if pool == "fp":
+        phase_serve_overlapped(run, params, cfg, reqs)
+
+
+def phase_serve_overlapped(run, params, cfg, drained):
+    """The same requests through the engine as the benchmark builds it
+    (``capture_logits=False``): step n+1 is dispatched before step n is
+    read back, the sampled tokens stay on the device in between.  Its
+    tokens against the drained loop's (``drained``, which captured its
+    logits): equal, or at the first position that differs a near-tie in
+    the drained engine's own row — the two loops run the same
+    mathematics in two executables, one of which also returns the
+    logits."""
+    import numpy as np
+
+    reqs, report = serve_requests(
+        run, params, cfg, [r.prompt for r in drained[:len(run.sz.prompt_lens)]],
+        pool="fp", capture_logits=False)
+    split, ties = 0, []
+    for got, want in zip(reqs, drained):
+        if len(got.tokens) != len(want.tokens):
+            raise AssertionError(
+                f"overlapped loop: request {got.id} has {len(got.tokens)} "
+                f"tokens, the drained loop gave {len(want.tokens)}")
+        diff = [i for i, (a, b) in enumerate(zip(got.tokens, want.tokens))
+                if a != b]
+        if diff:
+            row = want.logits[diff[0]]
+            split += 1
+            ties.append(float((row.max() - row[got.tokens[diff[0]]])
+                              / row.std()))
+    emit(phase="serve_fp_overlapped", note="smoke, not a measurement",
+         requests_equal=len(reqs) - split, requests_split=split,
+         split_gap_row_std=ties, tol=OVERLAP_TIE_TOL, **report)
+    if report["drains"].get("capture_logits"):
+        raise AssertionError("an engine without capture_logits drained "
+                             f"for it: {report['drains']}")
+    if report["steps_overlapped"] < report["decode_steps"] // 2:
+        raise AssertionError(
+            f"the loop ran ahead in {report['steps_overlapped']} of "
+            f"{report['decode_steps']} decode steps: {report['drains']}")
+    if ties and max(ties) > OVERLAP_TIE_TOL:
+        raise AssertionError(
+            f"overlapped loop: a token {max(ties)} row-std below the "
+            f"drained loop's argmax (> {OVERLAP_TIE_TOL})")
 
 
 def phase_serve_latent(run):

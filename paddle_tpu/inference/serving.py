@@ -168,6 +168,9 @@ def _stats_family():
         # paged-KV family (PagedServingEngine; zero on slot engines)
         "prefill_chunks": 0, "prefix_page_hits": 0,
         "prefix_page_misses": 0, "cow_copies": 0, "preemptions": 0,
+        # decode dispatches made while an earlier program's sampled
+        # tokens were still unread: the host ran ahead of the device
+        "steps_overlapped": 0,
         # quantized-serving family (ISSUE 9): quantized matmuls executed,
         # KV bytes the int8 pool saved vs the same pool at compute
         # dtype, and fused dequant kernel INSTANTIATIONS — the inc
@@ -1292,7 +1295,7 @@ class ServingEngine:
         "prefill_calls", "decode_steps", "requests_admitted",
         "requests_completed", "tokens_generated",
         "prefill_chunks", "prefix_page_hits", "prefix_page_misses",
-        "cow_copies", "preemptions", "quant_matmuls",
+        "cow_copies", "preemptions", "steps_overlapped", "quant_matmuls",
         "moe_assignments", "moe_experts_touched", "moe_max_expert_load",
         "drafted_tokens", "accepted_tokens", "rejected_tokens",
         "spec_steps", "kv_extracts", "kv_injects", "kv_handoff_bytes",
@@ -1441,6 +1444,24 @@ class _HostKVTier:
 # paged engine (ISSUE 8 tentpole)
 # --------------------------------------------------------------------------
 
+class _Dispatched:
+    """One program the paged engine has enqueued and not read back: the
+    array that holds its sampled tokens (the copy to the host already
+    started), the requests they belong to, and when the enqueue
+    returned."""
+    __slots__ = ("wave", "step", "toks", "logits", "rows", "t_enq",
+                 "attrs")
+
+    def __init__(self, wave, step, toks, logits, rows, attrs):
+        self.wave, self.step = wave, step
+        self.toks, self.logits = toks, logits
+        self.rows, self.attrs = rows, attrs
+        for a in (toks, logits):
+            if a is not None:
+                a.copy_to_host_async()
+        self.t_enq = time.perf_counter()
+
+
 class PagedServingEngine(ServingEngine):
     """Continuous batching over a **block-table paged KV cache**: the
     contiguous-per-slot pool is replaced by ``num_pages`` fixed
@@ -1487,6 +1508,26 @@ class PagedServingEngine(ServingEngine):
       contract so int8 pages never alias fp pages.  Composes with
       ``quant=`` (weight-only int8/fp8 executables) — together they are
       the quantized serving path the bench gates on an accuracy budget.
+
+    **The loop runs one step ahead of its readbacks.**  The sampled
+    tokens stay on the device: every wave and decode program takes the
+    ``[slots]`` token vector as an operand and returns the next one, so
+    wave -> decode -> decode chains with no host value in between, and a
+    ``step()`` call dispatches step n+1 (admission, paging and the
+    enqueue run while the device computes step n) BEFORE it blocks on
+    step n's tokens and commits them.  Lengths, page tables and the
+    pager advance by count at dispatch; a request that ends by length
+    is known by count and its slot is not run again, one that ends by
+    ``eos_token`` is known at commit only and its slot runs one more
+    position, whose token is dropped and whose K/V row lies in a page
+    the request still owned.  A finished request is returned by the
+    ``step()`` call that COMMITS its last token.  Whatever needs the
+    host's view whole — preemption, ``cancel``, a chunked prompt's last
+    chunk, injected or faulted-back pages, a prefill-only hand-off,
+    fault injection, ``capture_logits`` (every step: the debug mode is
+    the synchronous loop), an engine with nothing left to dispatch —
+    first reads back and commits what is in flight (:meth:`_drain`);
+    ``stats()`` counts ``steps_overlapped`` and ``drains`` by reason.
 
     Constraints: ``max_len`` must be a page multiple (seq buckets are
     rounded up to page multiples), and ``prefill_chunk`` must divide
@@ -1561,6 +1602,7 @@ class PagedServingEngine(ServingEngine):
         self._copy_site = _cc.site("serving.copy", maxsize=2)
         self._chunk_site = _cc.site("serving.chunk", maxsize=2)
         self._admit_seq = 0
+        self._drains = collections.Counter()
         super().__init__(model, **kw)
         self._kv_dtype = kv_dtype
         if getattr(self, "_kv_saved_pending", None):
@@ -1668,6 +1710,16 @@ class PagedServingEngine(ServingEngine):
                                    np.int32)
         self._chunk_jobs.clear()
         self._chunk_slots.clear()
+        # the step in flight (discarded with the pool it wrote):
+        # programs enqueued and not read back, in device order; the
+        # token vector the last of them returned (None: the host's
+        # ``_last_tok`` is whole, and the next dispatch starts from it);
+        # decode positions each slot's request may still be given; when
+        # the last program's tokens reached the host
+        self._inflight = collections.deque()
+        self._tok_dev = None
+        self._todo = np.zeros((self.slots,), np.int32)
+        self._t_arrived = 0.0
 
     def _cache_operands(self):
         """The donated KV pool arrays in executable-operand order, as
@@ -1706,7 +1758,7 @@ class PagedServingEngine(ServingEngine):
 
     def _busy(self):
         return (super()._busy() or bool(self._chunk_jobs)
-                or bool(self._inject_queue))
+                or bool(self._inject_queue) or bool(self._inflight))
 
     def _next_admit_seq(self):
         self._admit_seq += 1
@@ -1767,6 +1819,12 @@ class PagedServingEngine(ServingEngine):
                 self._g_occ_peak.set(occ)
 
     def _prefill_group(self, group, tables, sbucket, hits):
+        """Dispatch one wave and admit its requests BY COUNT: slots,
+        page tables and lengths are the wave's from here on, and the
+        decode step that follows runs them off the first tokens the
+        wave scatters into the token vector on the device.  The tokens
+        themselves are read back, stamped and committed when the wave
+        is retired (:meth:`_read_back`)."""
         jnp = self._jnp
         ps = self._page_size
         bbucket = self._batch_bucket(len(group))
@@ -1774,10 +1832,13 @@ class PagedServingEngine(ServingEngine):
         toks = np.zeros((bbucket, sbucket), np.int32)
         lens = np.ones((bbucket,), np.int32)    # pad rows: len 1
         ptab = np.zeros((bbucket, maxPb), np.int32)   # pads -> scratch
+        # pad rows: a slot index past the vector, which the scatter drops
+        slot_ids = np.full((bbucket,), self.slots, np.int32)
         for r, req in enumerate(group):
             toks[r, :len(req.prompt)] = req.prompt
             lens[r] = len(req.prompt)
             ptab[r, :len(tables[r])] = tables[r]
+            slot_ids[r] = req.slot
         fresh = sum(len(t) for t in tables) - hits
         self._inc("prefix_page_hits", hits)
         self._inc("prefix_page_misses", fresh)
@@ -1795,59 +1856,82 @@ class PagedServingEngine(ServingEngine):
             donate = self._donate()
             operands = (self.params, *self._cache_operands(),
                         jnp.asarray(toks), jnp.asarray(lens),
-                        jnp.asarray(ptab))
+                        jnp.asarray(ptab), self._token_vector(),
+                        jnp.asarray(slot_ids))
             fn = self._prefill.get(
                 _cc.make_key(bbucket, sbucket, donate=donate,
                              mesh=self._mesh_key()),
                 lambda: self._build_prefill(bbucket, sbucket),
                 stable_key=self._aot_key("prefill", b=bbucket, s=sbucket),
                 example_args=operands, topology=self._topology())
-        # the wave span IS the serving.prefill_s interval: from the
-        # jitted call to the end of the first-token commit loop
-        with timeline.span("serving.prefill_wave", batch=bbucket,
-                           seq=sbucket, paged=True,
-                           request_ids=[r.id for r in group]) as wave:
+        attrs = dict(batch=bbucket, seq=sbucket, paged=True,
+                     request_ids=[r.id for r in group])
+        with timeline.span("serving.prefill_wave", **attrs):
             with timeline.span("serving.prefill_wave.dispatch"):
                 out = fn(*operands)
-            self._set_cache(out[:self._n_cache])
-            first_tok = out[self._n_cache]
+            self._enqueued(True, out, list(enumerate(group)), attrs)
             self._inc("prefill_calls")
             self._count_quant_matmuls()
-            with timeline.span("serving.prefill_wave.readback"):
-                # ptl: disable-next=PTL004 -- capture_logits debug readback
-                logits_np = (np.asarray(out[self._n_cache + 1])
-                             if self.capture_logits else None)
-                # sampled-first-token readback: the one designed sync
-                # point of the paged prefill wave
-                # ptl: disable-next=PTL004 -- sampled-first-token readback
-                first_np = np.asarray(first_tok)
             for r, req in enumerate(group):
                 s = req.slot
                 self._tables_np[s] = 0
                 self._tables_np[s, :len(tables[r])] = tables[r]
                 self._lens[s] = len(req.prompt)
+                self._todo[s] = req.max_new_tokens - 1
                 self._active[s] = True
                 self._slot_req[s] = req
                 req._admit_seq = self._next_admit_seq()
-                self._append_token(req, int(first_np[r]),
-                                   logits_np[r] if logits_np is not None
-                                   else None)
-                self._last_tok[s] = int(first_np[r])
-                self._inc("requests_admitted")
-                self._memo_first_token(req)
-                if _faults.active() and not self._warming:
-                    _faults.replica_kill_check(
-                        request=self._counts["requests_admitted"])
-                self._maybe_finish_prefill_only(req)
             self._admitting = []
-        if not self._warming:
-            self._h_prefill.observe(wave.dur)
+        if self.capture_logits:
+            self._drain("capture_logits")
+        elif any(req.prefill_only for req in group):
+            # the hand-off extracts the prompt's pages and frees the
+            # slot when the first token is committed: now, not a step on
+            self._drain("handoff")
+
+    def _replicated(self, x):
+        """Pin a program's small output to every device of the mesh, so
+        that what one program returns is placed as the host's own copy
+        of it is (:meth:`_token_vector`).  No-op without a mesh."""
+        if self._mesh is None:
+            return x
+        return jax_compat.with_sharding_constraint(x, self._mesh, ())
+
+    def _token_vector(self):
+        """The ``[slots]`` int32 operand that holds each slot's last
+        sampled token: the vector the last program returned while the
+        chain holds, else the host's copy (the first dispatch, and the
+        first after a drain) — same shape, dtype and placement, so one
+        executable takes both."""
+        if self._tok_dev is not None:
+            return self._tok_dev
+        vec = self._jnp.asarray(self._last_tok.copy())   # never aliased
+        if self._mesh is not None:
+            return self._jax.device_put(
+                vec, jax_compat.named_sharding(self._mesh, ()))
+        pool = self._pools[0]
+        if pool.committed:      # as a program's outputs then are
+            return self._jax.device_put(vec, pool.sharding)
+        return vec
+
+    def _scatter_first(self, first_tok, chain):
+        """``(vector', )``: the wave's first tokens scattered into the
+        token vector at the wave's slots (pad rows carry an index past
+        the vector, which is dropped).  ``()`` for a caller that lowers
+        the wave without the chain (benchmark/sizing reads its memory)."""
+        if len(chain) == 0:
+            return ()
+        vec, slot_ids = chain
+        return (self._replicated(
+            vec.at[slot_ids].set(first_tok, mode="drop")),)
 
     def _build_prefill(self, b, s):
         """Paged prefill executable: the family's causal forward over
         the padded prompts, writing the DONATED pool through the page
         tables (``prefill_paged`` of models/gpt.py or
-        models/deepseek_v3.py), then the first token of each row."""
+        models/deepseek_v3.py), then the first token of each row — as
+        an output the host reads back, and scattered into the token
+        vector the next program takes (the program's last output)."""
         jax, jnp = self._jax, self._jnp
         cfg = self.cfg
         ps = self._page_size
@@ -1863,13 +1947,13 @@ class PagedServingEngine(ServingEngine):
                 cfg, self._mesh, self._param_specs, s=s, b=b,
                 page_size=ps)
 
-            def prefill_pp(params, cache_k, cache_v, tokens, lens, ptab):
+            def prefill_pp(params, cache_k, cache_v, tokens, lens, ptab,
+                           *chain):
                 ck, cv, first_tok, last = pre(
                     params, cache_k, cache_v, tokens, lens, ptab)
                 out_cache = self._constrain_cache((ck, cv))
-                if cap:
-                    return (*out_cache, first_tok, last)
-                return (*out_cache, first_tok)
+                return (*out_cache, first_tok, *((last,) if cap else ()),
+                        *self._scatter_first(first_tok, chain))
 
             donate = ((1, 2) if _donation_enabled() else ())
             return jax.jit(prefill_pp, donate_argnums=donate)
@@ -1878,15 +1962,15 @@ class PagedServingEngine(ServingEngine):
         family = self._family
 
         def prefill(params, *args):
-            tokens, lens, ptab = args[n:]
+            tokens, lens, ptab, *chain = args[n:]
             last, out_cache = family.prefill_paged(
                 params, cfg, args[:n], tokens, lens, ptab)
             out_cache = self._constrain_cache(out_cache)
             with jax.named_scope("head_sample"):
                 first_tok = jnp.argmax(last, -1).astype(jnp.int32)
-            if cap:
-                return (*out_cache, first_tok, last)
-            return (*out_cache, first_tok)
+                chained = self._scatter_first(first_tok, chain)
+            return (*out_cache, first_tok, *((last,) if cap else ()),
+                    *chained)
 
         donate = tuple(range(1, 1 + n)) if _donation_enabled() else ()
         return self._jax.jit(prefill, donate_argnums=donate)
@@ -1938,6 +2022,10 @@ class PagedServingEngine(ServingEngine):
         n = len(req.prompt)
         pos = req._chunk_pos
         take = min(C, n - pos)
+        if pos + take >= n:
+            # the last chunk's token is read here and the slot joins the
+            # decode pool from the host's copy of the token vector
+            self._drain("chunk_done")
         toks = np.zeros((1, C), np.int32)
         toks[0, :take] = req.prompt[pos:pos + take]
         s = req.slot
@@ -1980,6 +2068,7 @@ class PagedServingEngine(ServingEngine):
         self._chunk_jobs.popleft()
         self._chunk_slots.discard(s)
         self._lens[s] = n
+        self._todo[s] = req.max_new_tokens - 1
         self._active[s] = True
         self._append_token(req, int(tok), row_np)
         self._last_tok[s] = int(tok)
@@ -2262,6 +2351,7 @@ class PagedServingEngine(ServingEngine):
             free = self._free_slots()
             if not free:
                 return
+            self._drain("inject")   # the slot is written from the host
             req = self._inject_queue[0]
             slot = free[0]
             try:
@@ -2284,6 +2374,7 @@ class PagedServingEngine(ServingEngine):
                               prefix_hits=hits, engine=self._engine_id)
             self._tables_np[slot] = pages_row
             self._lens[slot] = len(req.prompt)
+            self._todo[slot] = req.max_new_tokens - 1
             self._active[slot] = True
             self._slot_req[slot] = req
             req._admit_seq = self._next_admit_seq()
@@ -2409,6 +2500,7 @@ class PagedServingEngine(ServingEngine):
                 fetched[key] = got
             if not covered or not fetched:
                 return      # device-only hits: the prefill wave wins
+            self._drain("fault_back")   # the slot is written from the host
             slot = free[0]
             try:
                 table, hit_flags = self._pager.admit_pinned(
@@ -2439,6 +2531,7 @@ class PagedServingEngine(ServingEngine):
             self._tables_np[slot] = 0
             self._tables_np[slot, :n_pages] = table
             self._lens[slot] = len(req.prompt)
+            self._todo[slot] = req.max_new_tokens - 1
             self._active[slot] = True
             self._slot_req[slot] = req
             req._admit_seq = self._next_admit_seq()
@@ -2481,7 +2574,9 @@ class PagedServingEngine(ServingEngine):
         scrub it back to its prompt, and put it at the queue head for
         re-admission once pages free up.  NAMED (telemetry event,
         ``preemptions`` counter, ``Request.preemptions``) — exhaustion
-        is never a silent stall or loss."""
+        is never a silent stall or loss.  Every caller has committed
+        what was in flight: the victim restarts from its prompt with
+        every token the client was already sent accounted for."""
         s = req.slot
         if s is not None:
             self._pager.release(s)
@@ -2517,22 +2612,24 @@ class PagedServingEngine(ServingEngine):
                           engine=self._engine_id)
 
     def _ensure_decode_pages(self):
-        """Give every active slot a writable position for this step's
+        """Give every slot this step runs a writable position for its
         token: a fresh tail page on a page boundary, a COW copy when the
-        tail is shared.  On exhaustion, preempt the newest request and
-        retry (``ensure_append`` is idempotent, so re-walking already-
-        ensured slots is safe) — progress is guaranteed because a lone
-        request always fits (submit enforces it)."""
+        tail is shared.  A slot runs while its request may still be
+        given a position (``_todo``: one that ends by length is known
+        by count, before its last token is read).  On exhaustion, first
+        commit what is in flight — a request that finished there frees
+        its pages — then preempt the newest request, and retry
+        (``ensure_append`` is idempotent, so re-walking already-ensured
+        slots is safe): progress is guaranteed because a lone request
+        always fits (submit enforces it).  Returns the mask of the
+        slots to run and their write coordinates."""
         ps = self._page_size
-        wpages = np.zeros((self.slots,), np.int32)   # inactive -> scratch
-        woffs = np.zeros((self.slots,), np.int32)
         while True:
+            run = self._active & (self._todo > 0)
+            wpages = np.zeros((self.slots,), np.int32)  # idle -> scratch
+            woffs = np.zeros((self.slots,), np.int32)
             try:
-                for s in range(self.slots):
-                    if not self._active[s]:
-                        wpages[s] = 0
-                        woffs[s] = 0
-                        continue
+                for s in np.flatnonzero(run):
                     pos = int(self._lens[s])
                     pid, off, cow_src = self._pager.ensure_append(s, pos)
                     if cow_src is not None:
@@ -2540,90 +2637,111 @@ class PagedServingEngine(ServingEngine):
                     self._tables_np[s, pos // ps] = pid
                     wpages[s] = pid
                     woffs[s] = off
-                return wpages, woffs
+                return run, wpages, woffs
             except self._PagesExhausted as e:
+                if self._inflight:
+                    self._drain("page_exhaustion")
+                    continue
                 victim = self._newest_victim()
                 if victim is None:
                     raise
                 self._preempt(victim, str(e))
 
-    # ------------------------------------------------------------- driving
-    def _step_inner(self):
-        with timeline.span("serving.admit"):
-            self._admit()
-        self._advance_chunks()
-        if not self._active.any():
+    # ------------------------------------------------ the step in flight
+    def _enqueued(self, wave, out, rows, attrs):
+        """Take over what a program just enqueued returned: the pool,
+        the token vector for the next program (its last output), and
+        the record of the sampled tokens the host has yet to read."""
+        n = self._n_cache
+        self._set_cache(out[:n])
+        self._tok_dev = out[-1]
+        self._inflight.append(_Dispatched(
+            wave, self._step_idx, out[n],
+            out[n + 1] if self.capture_logits else None, rows, attrs))
+
+    def _retire(self, before=None):
+        """Read back and commit the programs in flight, oldest first —
+        all of them, or those enqueued by a ``step()`` earlier than
+        ``before``."""
+        while self._inflight and (before is None
+                                  or self._inflight[0].step < before):
+            self._read_back(self._inflight.popleft())
+
+    def _drain(self, reason):
+        """Make the host's view whole: read back and commit everything
+        in flight, and start the next dispatch's token vector from the
+        host's copy.  Called by whatever needs host-visible tokens or
+        rewrites a slot from the host; ``stats()["drains"]`` counts, by
+        ``reason``, the calls that found a program in flight."""
+        self._tok_dev = None
+        if not self._inflight:
             return
-        jnp = self._jnp
-        if _faults.active() and not self._warming:
-            if _faults.page_exhaustion_check(
-                    step=self._counts["decode_steps"] + 1):
-                victim = self._newest_victim()
-                if victim is not None:
-                    self._preempt(victim, "injected page_exhaustion")
-            _faults.engine_step_error(self._counts["decode_steps"] + 1)
-            _faults.replica_kill_check(
-                step=self._counts["decode_steps"] + 1)
-        if not self._active.any():
-            return                  # the injected preemption emptied it
-        finished = []
-        with timeline.span("serving.pager.ensure"):
-            wpages, woffs = self._ensure_decode_pages()
-        if not self._active.any():
-            return
-        with timeline.span("serving.decode_operands"):
-            operands = (self.params, *self._cache_operands(),
-                        jnp.asarray(self._tables_np), jnp.asarray(wpages),
-                        jnp.asarray(woffs), jnp.asarray(self._lens),
-                        jnp.asarray(self._last_tok))
-            if self._decode_jit is None:
-                donate = self._donate()
-                self._decode_jit = self._decode_site.get(
-                    _cc.make_key("decode", donate=donate,
-                                 mesh=self._mesh_key()),
-                    self._build_decode,
-                    stable_key=self._aot_key("decode"),
-                    example_args=operands, topology=self._topology())
-                self._inc("decode_compiles")
-        # the decode span IS the serving.decode_step_s interval: from the
-        # jitted call to the end of the commit loop
-        with timeline.span("serving.decode", active=int(self._active.sum()),
-                           paged=True) as decode:
-            with timeline.span("serving.decode.dispatch"):
-                out = self._decode_jit(*operands)
-            self._set_cache(out[:self._n_cache])
-            nxt = out[self._n_cache]
-            self._inc("decode_steps")
-            self._count_quant_matmuls()
-            with timeline.span("serving.decode.readback"):
-                # ptl: disable-next=PTL004 -- capture_logits debug readback
-                logits_np = (np.asarray(out[self._n_cache + 1])
-                             if self.capture_logits else None)
-                # sampled-token readback: THE designed device->host sync
-                # of the paged decode loop
-                # ptl: disable-next=PTL004 -- sampled-token readback
-                nxt_np = np.asarray(nxt)
-                if nxt_np.shape[0] > self.slots:
-                    # the family's per-step counts, behind the tokens
-                    extra = self._family.decode_extra_stats(
-                        self.cfg, nxt_np[self.slots:])
-                    for k, v in extra.items():
-                        self._inc(k, v)
-            with timeline.span("serving.decode.commit"):
-                for s in range(self.slots):
-                    if not self._active[s]:
-                        continue
-                    req = self._slot_req[s]
-                    self._lens[s] += 1
-                    self._append_token(req, int(nxt_np[s]),
-                                       logits_np[s] if logits_np is not None
-                                       else None)
-                    self._last_tok[s] = int(nxt_np[s])
-                    if req.done:
-                        finished.append(req)
-        dt = decode.dur
+        self._retire()
         if not self._warming:
-            self._h_decode.observe(dt)
+            self._drains[reason] += 1
+
+    def _read_back(self, rec):
+        """Block on one program's sampled tokens, stamp and commit
+        them.  The histograms (``serving.prefill_s`` for a wave,
+        ``serving.decode_step_s``) observe the time the device had the
+        program at the head of its queue: from the later of its enqueue
+        returning and the previous program's tokens arriving, to its
+        own tokens' arrival."""
+        name = "serving.prefill_wave" if rec.wave else "serving.decode"
+        with timeline.span(name, **rec.attrs):
+            with timeline.span(name + ".readback"):
+                # ptl: disable-next=PTL004 -- capture_logits debug readback
+                logits_np = (None if rec.logits is None
+                             else np.asarray(rec.logits))
+                # THE designed device->host sync of the loop (tokens must
+                # reach clients), one step behind the dispatch in steady
+                # state: the copy started when the program was enqueued
+                # ptl: disable-next=PTL004 -- lagged sampled-token readback
+                toks_np = np.asarray(rec.toks)
+                arrived = time.perf_counter()
+            dt = arrived - max(rec.t_enq, self._t_arrived)
+            self._t_arrived = arrived
+            if not self._warming:
+                (self._h_prefill if rec.wave else self._h_decode
+                 ).observe(dt)
+            if rec.wave:
+                self._commit_wave(rec, toks_np, logits_np)
+            else:
+                self._commit_decode(rec, toks_np, logits_np, dt)
+
+    def _commit_wave(self, rec, first_np, logits_np):
+        for r, req in rec.rows:
+            tok = int(first_np[r])
+            self._append_token(req, tok, None if logits_np is None
+                               else logits_np[r])
+            self._last_tok[req.slot] = tok
+            self._inc("requests_admitted")
+            self._memo_first_token(req)
+            if _faults.active() and not self._warming:
+                _faults.replica_kill_check(
+                    request=self._counts["requests_admitted"])
+            self._maybe_finish_prefill_only(req)
+
+    def _commit_decode(self, rec, nxt_np, logits_np, dt):
+        if nxt_np.shape[0] > self.slots:
+            # the family's per-step counts, behind the tokens
+            extra = self._family.decode_extra_stats(
+                self.cfg, nxt_np[self.slots:])
+            for k, v in extra.items():
+                self._inc(k, v)
+        finished = []
+        with timeline.span("serving.decode.commit"):
+            for s, req in rec.rows:
+                if req.done:
+                    # ended by eos_token a step ago, after this position
+                    # was dispatched: its token is dropped
+                    continue
+                tok = int(nxt_np[s])
+                self._append_token(req, tok, None if logits_np is None
+                                   else logits_np[s])
+                self._last_tok[s] = tok
+                if req.done:
+                    finished.append(req)
         self._g_occ.set(int(self._active.sum()))
         if not self._warming and timeline.telemetry_dir():
             timeline.emit({"event": "serving_step",
@@ -2650,6 +2768,81 @@ class PagedServingEngine(ServingEngine):
                               decode_s=round(dt, 6),
                               engine=self._engine_id)
 
+    def _abort_inflight(self, err):
+        """Base abort; the step in flight is DISCARDED unread (the
+        rebuild drops it with the pool it wrote: its requests are among
+        the victims and restart from their prompts)."""
+        if self._inflight and not self._warming:
+            self._drains["abort"] += 1
+        return super()._abort_inflight(err)
+
+    # ------------------------------------------------------------- driving
+    def _step_inner(self):
+        """Dispatch this step — admission's waves, then the decode —
+        and only then read back and commit the step before it, which
+        the device computed meanwhile."""
+        with timeline.span("serving.admit"):
+            self._admit()
+        self._advance_chunks()
+        if _faults.active() and not self._warming:
+            # an injected fault is aimed at a step by its number, and
+            # what completed before it stays completed
+            self._drain("fault_injection")
+            if self._active.any():
+                if _faults.page_exhaustion_check(
+                        step=self._counts["decode_steps"] + 1):
+                    victim = self._newest_victim()
+                    if victim is not None:
+                        self._preempt(victim, "injected page_exhaustion")
+                _faults.engine_step_error(
+                    self._counts["decode_steps"] + 1)
+                _faults.replica_kill_check(
+                    step=self._counts["decode_steps"] + 1)
+        with timeline.span("serving.pager.ensure"):
+            run, wpages, woffs = self._ensure_decode_pages()
+        if not run.any():
+            # nothing to give the device: what it still holds is all
+            # that is left to deliver
+            self._drain("idle")
+            return
+        jnp = self._jnp
+        with timeline.span("serving.decode_operands"):
+            # the host rewrites its page tables while this program is
+            # still queued, and ``jnp.asarray`` may alias a numpy
+            # array's memory (it does on the CPU): the program gets a
+            # copy that nobody writes again
+            operands = (self.params, *self._cache_operands(),
+                        jnp.asarray(self._tables_np.copy()),
+                        jnp.asarray(wpages), jnp.asarray(woffs),
+                        jnp.asarray(np.where(run, self._lens, np.int32(0))),
+                        self._token_vector())
+            if self._decode_jit is None:
+                donate = self._donate()
+                self._decode_jit = self._decode_site.get(
+                    _cc.make_key("decode", donate=donate,
+                                 mesh=self._mesh_key()),
+                    self._build_decode,
+                    stable_key=self._aot_key("decode"),
+                    example_args=operands, topology=self._topology())
+                self._inc("decode_compiles")
+        attrs = dict(active=int(run.sum()), paged=True)
+        with timeline.span("serving.decode", **attrs):
+            with timeline.span("serving.decode.dispatch"):
+                out = self._decode_jit(*operands)
+            if self._inflight:
+                self._inc("steps_overlapped")
+            slots = np.flatnonzero(run)
+            self._enqueued(False, out,
+                           [(s, self._slot_req[s]) for s in slots], attrs)
+            self._inc("decode_steps")
+            self._count_quant_matmuls()
+            self._lens[slots] += 1
+            self._todo[slots] -= 1
+        if self.capture_logits:
+            self._drain("capture_logits")
+        else:
+            self._retire(before=self._step_idx)
+
     def _build_decode(self):
         jax, jnp = self._jax, self._jnp
         cfg = self.cfg
@@ -2670,9 +2863,8 @@ class PagedServingEngine(ServingEngine):
                                          page_table, wpages, woffs, lens)
                 nxt = jnp.argmax(logits, -1).astype(jnp.int32)
                 cache = self._constrain_cache((ck, cv))
-                if cap:
-                    return (*cache, nxt, logits)
-                return (*cache, nxt)
+                return (*cache, nxt, *((logits,) if cap else ()),
+                        self._replicated(nxt))
 
             donate = ((1, 2) if _donation_enabled() else ())
             return jax.jit(decode_pp, donate_argnums=donate)
@@ -2686,16 +2878,16 @@ class PagedServingEngine(ServingEngine):
                 params, cfg, args[:n], page_table, wpages, woffs, lens,
                 toks, mesh=self._mesh)
             with jax.named_scope("head_sample"):
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+                nxt = back = jnp.argmax(logits, -1).astype(jnp.int32)
                 if extra is not None:
                     # what the family counts a step (expert loads) rides
                     # the sampled tokens' readback: one array, one sync
-                    nxt = jnp.concatenate(
+                    back = jnp.concatenate(
                         [nxt, extra.reshape(-1).astype(jnp.int32)])
             cache = self._constrain_cache(cache)
-            if cap:
-                return (*cache, nxt, logits)
-            return (*cache, nxt)
+            # last, the vector the next program takes: the tokens alone
+            return (*cache, back, *((logits,) if cap else ()),
+                    self._replicated(nxt))
 
         donate = (tuple(range(1, 1 + self._n_cache))
                   if _donation_enabled() else ())
@@ -2703,7 +2895,10 @@ class PagedServingEngine(ServingEngine):
 
     def cancel(self, request_id):
         """Base cancel plus the injection queue (a handed-off request
-        cancelled before its pages land)."""
+        cancelled before its pages land).  The step in flight is
+        committed first, so what the caller learns — cancelled while
+        queued, or None: running or finished — holds when it returns."""
+        self._drain("cancel")
         out = super().cancel(request_id)
         if out is not None:
             return out
@@ -2832,6 +3027,9 @@ class PagedServingEngine(ServingEngine):
         # included): drivers polling it — the fleet worker's step loop
         # — must see queued handoffs or a decode replica never steps
         out = super().stats()
+        # how often the loop had to make the host's view whole, by what
+        # asked for it (beside ``steps_overlapped``: how often it ran on)
+        out["drains"] = dict(self._drains)
         pg = self._pager.stats()
         for k in ("prefix_page_hits", "prefix_page_misses", "cow_copies"):
             pg.pop(k)    # the engine-mirrored (warmup-quiet) counts win

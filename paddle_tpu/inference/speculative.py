@@ -497,6 +497,9 @@ class SpeculativeServingEngine(PagedServingEngine):
     def _step_inner(self):
         with timeline.span("serving.admit"):
             self._admit()
+        # the verify window is built on the host from every slot's last
+        # committed token: the waves just dispatched are committed first
+        self._drain("speculative")
         self._advance_chunks()
         if not self._active.any():
             return

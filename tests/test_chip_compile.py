@@ -194,7 +194,9 @@ def test_serving_programs_hold_the_pool_in_place(chip, served, nh, hd, ps,
     else:
         b, s = map(int, program.split("_")[1].split("x"))
         fn = eng._build_prefill(b, s)
-        args = (chip((b, s), i32), chip((b,), i32), chip((b, s // ps), i32))
+        # ... the token vector and the wave's slots: the chain's operands
+        args = (chip((b, s), i32), chip((b,), i32), chip((b, s // ps), i32),
+                chip((BENCH_SLOTS,), i32), chip((b,), i32))
     compiled = fn.lower(params, *pools, *args).compile()
     assert pool_relayouts(compiled.as_text(), pools) == []
     mem = compiled.memory_analysis()
@@ -340,7 +342,8 @@ def test_kanana_serving_programs_fit_and_hold_the_pool_in_place(
     else:
         fn = eng._build_prefill(4, 1024)
         args = (chip((4, 1024), i32), chip((4,), i32),
-                chip((4, 1024 // KANANA_PS), i32))
+                chip((4, 1024 // KANANA_PS), i32),
+                chip((KANANA_SLOTS,), i32), chip((4,), i32))
     compiled = fn.lower(params, *pools, *args).compile()
     text = compiled.as_text()
     assert pool_relayouts(text, pools) == []

@@ -199,6 +199,11 @@ def _tree(spans):
 
 
 def test_one_step_yields_the_span_tree_and_self_times_sum(tiny_model):
+    """The loop runs a step ahead of its readbacks, so a program has two
+    spans of its name: one where it is dispatched, and one — a ``step()``
+    later in steady state — where its tokens are read back and
+    committed.  Every name is one of the four sets that partition
+    ``serving.step``."""
     from paddle_tpu.inference.serving import Request
     eng = _engine(tiny_model)
     vocab = tiny_model[1].vocab_size
@@ -210,61 +215,94 @@ def test_one_step_yields_the_span_tree_and_self_times_sum(tiny_model):
     d0, p0 = h_decode.count, h_prefill.count
     d_sum, p_sum = h_decode.sum, h_prefill.sum
     timeline.reset_spans()
+    t_first = time.perf_counter()
+    eng.step()
     eng.step()
     spans = timeline.spans()
 
-    wave = ("serving.prefill_wave", [("serving.prefill_wave.dispatch", []),
-                                     ("serving.prefill_wave.readback", [])])
     pager_admit = ("serving.pager.admit", [])
     operands = ("serving.prefill_operands", [])
-    assert _tree(spans) == [("serving.step", [
-        ("serving.admit", [pager_admit, pager_admit, operands, wave,
-                           pager_admit, operands, wave]),
-        ("serving.pager.ensure", []),
-        ("serving.decode_operands", []),
-        ("serving.decode", [("serving.decode.dispatch", []),
-                            ("serving.decode.readback", []),
-                            ("serving.decode.commit", [])]),
-    ])]
+    dispatched = ("serving.prefill_wave",
+                  [("serving.prefill_wave.dispatch", [])])
+    read_back = ("serving.prefill_wave",
+                 [("serving.prefill_wave.readback", [])])
+    decode_out = [("serving.pager.ensure", []),
+                  ("serving.decode_operands", []),
+                  ("serving.decode", [("serving.decode.dispatch", [])])]
+    assert _tree(spans) == [
+        # step 1 dispatches two waves and a decode step, reads nothing
+        ("serving.step", [
+            ("serving.admit", [pager_admit, pager_admit, operands,
+                               dispatched, pager_admit, operands,
+                               dispatched]),
+            *decode_out]),
+        # step 2 dispatches its decode step, THEN reads step 1 back:
+        # the waves first, as the device ran them
+        ("serving.step", [
+            ("serving.admit", []), *decode_out, read_back, read_back,
+            ("serving.decode", [("serving.decode.readback", []),
+                                ("serving.decode.commit", [])])]),
+    ]
     by_name = {}
     for s in spans:
         by_name.setdefault(s[2], []).append(s)
-    (step,) = by_name["serving.step"]
-    assert step[5]["step"] == eng._step_idx
+    first, second = sorted(by_name["serving.step"], key=lambda s: s[3])
+    assert (first[5]["step"], second[5]["step"]) == (eng._step_idx - 1,
+                                                     eng._step_idx)
     assert [s[5]["request_id"] for s in by_name["serving.pager.admit"]] \
         == [0, 1, 2]
     assert all(s[5]["hits"] == 0 for s in by_name["serving.pager.admit"])
-    waves = by_name["serving.prefill_wave"]
-    assert [w[5]["request_ids"] for w in waves] == [[0, 1], [2]]
-    assert [(w[5]["batch"], w[5]["seq"]) for w in waves] == [(2, 16),
-                                                             (1, 32)]
-    (decode,) = by_name["serving.decode"]
-    assert decode[5]["active"] == 3
+    # a wave's two spans carry the same attributes
+    waves = sorted(by_name["serving.prefill_wave"], key=lambda s: s[3])
+    assert [w[5]["request_ids"] for w in waves] == [[0, 1], [2]] * 2
+    assert [(w[5]["batch"], w[5]["seq"]) for w in waves] \
+        == [(2, 16), (1, 32)] * 2
+    assert [d[5]["active"] for d in by_name["serving.decode"]] == [3, 3, 3]
+    assert eng.stats()["steps_overlapped"] == 2
 
+    # the four name sets of benchmark/lib/spans.py partition both steps
+    from benchmark.lib import spans as reducer
+    known = (reducer.SCHEDULER + reducer.PAGER + reducer.DISPATCH
+             + reducer.READBACK)
+    assert set(by_name) <= set(known)
     own = _self_times(spans)
     assert all(v >= 0 for v in own.values())
-    assert sum(own.values()) == pytest.approx(step[4] - step[3], rel=1e-9)
+    assert sum(own.values()) == pytest.approx(
+        sum(s[4] - s[3] for s in (first, second)), rel=1e-9)
 
-    # the histograms observe the spans' own durations: one clock pair
-    assert h_decode.count - d0 == len(by_name["serving.decode"]) == 1
-    assert h_decode.sum - d_sum == pytest.approx(decode[4] - decode[3])
-    assert h_prefill.count - p0 == len(waves) == 2
-    assert h_prefill.sum - p_sum == pytest.approx(
-        sum(w[4] - w[3] for w in waves))
+    # the histograms: one observation for each program READ BACK (step
+    # 2's decode is still in flight), each the time from the later of
+    # (its enqueue returned, the program before it arrived) to its own
+    # arrival — so they follow one another and fit in the wall time
+    assert h_decode.count - d0 == 1 and h_prefill.count - p0 == 2
+    assert len(eng._inflight) == 1 and not eng._inflight[0].wave
+    observed = (h_decode.sum - d_sum) + (h_prefill.sum - p_sum)
+    assert 0 < observed <= eng._t_arrived - t_first
 
 
 def test_decode_histogram_counts_the_decode_spans_over_a_run(tiny_model):
+    """Over a run that ends idle every decode program is dispatched once
+    and read back once — a ``serving.decode`` span each, the histogram's
+    observation with the second — and every wave likewise."""
     eng = _engine(tiny_model)
     vocab = tiny_model[1].vocab_size
     h = metrics.histogram("serving.decode_step_s")
-    c0 = h.count
+    hp = metrics.histogram("serving.prefill_s")
+    c0, p0 = h.count, hp.count
     timeline.reset_spans()
     for i in range(5):
         eng.submit(_prompt(9 + i, 40 + i, vocab), 4 + i)
     eng.run(max_steps=200)
     names = [s[2] for s in timeline.spans()]
-    assert h.count - c0 == names.count("serving.decode") > 0
-    assert names.count("serving.step") >= names.count("serving.decode")
+    steps = eng.stats()["decode_steps"]
+    assert h.count - c0 == steps > 0
+    assert names.count("serving.decode.dispatch") == steps
+    assert names.count("serving.decode.readback") == steps
+    assert names.count("serving.decode.commit") == steps
+    assert names.count("serving.decode") == 2 * steps
+    assert hp.count - p0 == names.count("serving.prefill_wave.readback") \
+        == names.count("serving.prefill_wave.dispatch") > 0
+    assert names.count("serving.step") >= steps
     assert "serving.prefill" not in names and \
         "serving.decode_step" not in names          # replaced, not doubled
 
