@@ -33,7 +33,7 @@ import time
 
 import numpy as np
 
-from ..lib import flops_bytes, probe, reference, stats, traffic
+from ..lib import flops_bytes, probe, reference, spans, stats, traffic
 
 # The emitted token's float32-reference logit may sit this many
 # row-standard-deviations below the reference row's maximum.  Set from
@@ -220,7 +220,8 @@ def run(ctx):
     ctx.note(phase="window_closed", window_s=t1 - t0,
              steps=len(client.step_s), submitted=client.next_i,
              token_gap_p50_s=stats.percentile(gaps, 50),
-             token_gap_p95_s=stats.percentile(gaps, 95))
+             token_gap_p95_s=stats.percentile(gaps, 95),
+             **spans.ring_use(t0, t1))
     record = {
         "t0": t0, "t1": t1, "window_s": t1 - t0,
         "step_s": list(client.step_s),

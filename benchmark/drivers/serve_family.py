@@ -16,10 +16,9 @@ What differs from ``serve_engine`` (which is GPT by construction):
   (``moe``), the bytes a cached position needs, and — traced — the
   latent kernel's own events (``kernel``) and the samples of the traced
   tail alone (``tail_samples``), for the family's readers
-  (``LAYER_METRICS``).  ``BENCHMARK.json`` cannot list those yet
-  (PERF.md section 7: an accepted test pins the tail of its
-  ``per_layer``), so a traced run prints them on a note line,
-  ``phase: "family_layer_metrics"``, read by the same reader files.
+  (``benchmark/metrics/moe.*.py``, ``decode_step_roofline.moe_mla.py``,
+  ``paged_mla_decode_roofline.py``), which ``BENCHMARK.json`` lists for
+  the cell and ``run.py`` calls like every other.
 
 ``correct`` is decided as for the GPT cell — one decode executable, no
 compile in the window, the latent kernel engaged, no failed request, and
@@ -52,7 +51,7 @@ import time
 
 import numpy as np
 
-from ..lib import probe, stats, traffic, xplane
+from ..lib import probe, spans, stats, traffic, xplane
 from .serve_engine import Client, Item, build_engine, hist_summary
 
 # A row is OFF when the emitted token's float32-reference logit sits
@@ -73,8 +72,6 @@ CHECKED_REQUESTS = 6
 TRACED_S = 3.0
 KERNEL = "paged_mla_decode"     # the latent kernel's ``name=``
 TOP_OPS = 40                    # rows of breakdown.device_ops
-LAYER_METRICS = ("moe.experts_touched_share", "moe.load_max_over_mean",
-                 "decode_step_roofline.moe_mla", "paged_mla_decode_roofline")
 
 
 def family_modules(model_type):
@@ -229,7 +226,8 @@ def run(ctx):
     ctx.note(phase="window_closed", window_s=t1 - t0,
              steps=len(client.step_s), submitted=client.next_i,
              token_gap_p50_s=stats.percentile(gaps, 50),
-             token_gap_p95_s=stats.percentile(gaps, 95))
+             token_gap_p95_s=stats.percentile(gaps, 95),
+             **spans.ring_use(t0, t1))
     pool_positions = after["num_pages"] * after["page_size"]
     record = {
         "t0": t0, "t1": t1, "window_s": t1 - t0,
@@ -263,12 +261,6 @@ def run(ctx):
         if trace and kernel and not any(
                 name.startswith(KERNEL) for name, _ in trace["device_ops"]):
             trace["device_ops"].append([KERNEL, kernel["seconds"]])
-        from .. import run as runner
-        record.update(on_chip=ctx.on_chip,
-                      device_kind=ctx.devices[0].device_kind)
-        ctx.note(phase="family_layer_metrics", **{
-            name: runner.load_reader(name).read(record)
-            for name in LAYER_METRICS})
 
     done = [it for it in items if it.done_t and t0 <= it.done_t <= t1]
     bad = [it for it in items

@@ -72,6 +72,19 @@ def reduce(spans, dropped, t0, t1):
             "self_s": own}
 
 
+def ring_use(t0, t1):
+    """``{"spans_in_window", "ring_spans"}``: how many of the spans the
+    ring holds START in ``[t0, t1)``, beside the ring's depth — how far a
+    window is from wrapping it (``reduce`` raises when it does).  A note,
+    not a metric; ``{}`` for a program without the ring."""
+    from paddle_tpu.observability import timeline
+    ring = getattr(timeline, "spans", None)
+    if ring is None:
+        return {}
+    return {"spans_in_window": sum(t0 <= s[3] < t1 for s in ring()),
+            "ring_spans": timeline.RING_SPANS}
+
+
 def window(run):
     """``reduce`` of the program's ring over the run's window, made once
     and kept on the record (``run["program_spans"]``)."""

@@ -17,18 +17,27 @@ found by the names in the manifest:
     benchmark/traffic/<traffic>.json   the traffic mix; names its driver
     benchmark/drivers/<driver>.py      run(ctx) -> record
     benchmark/metrics/<metric>.py      read(run) -> number, or None
+    benchmark/rehearse/manifest.json, manifest.*.json
+                                       the rehearsal cells, one file or
+                                       many (``rehearsal_manifest``)
 
 A cell of ``BENCHMARK.json`` needs a TPU and as many chips as it asks
 for; without them the run exits non-zero and prints no result.  There is
-no CPU branch under a device metric's name.  The cells of
-``benchmark/rehearse/manifest.json`` are the opposite: tiny, for walking
-the control flow with ``JAX_PLATFORMS=cpu``, and refused on a TPU.
+no CPU branch under a device metric's name.  The rehearsal cells are the
+opposite: tiny, for walking the control flow with ``JAX_PLATFORMS=cpu``,
+and refused on a TPU.  They are listed in
+``benchmark/rehearse/manifest.json`` and in every fragment
+``benchmark/rehearse/manifest.<anything>.json`` beside it, read in sorted
+order as one list: a PR that adds a cell adds its rehearsal as a fragment
+of its own and edits no file that is there.  A cell or configuration
+name that two of those files give is refused, both files named.
 """
 import time
 
 T_START = time.perf_counter()       # as near to process start as we get
 
 import argparse                     # noqa: E402
+import glob                         # noqa: E402
 import importlib                    # noqa: E402
 import importlib.util               # noqa: E402
 import json                         # noqa: E402
@@ -37,25 +46,53 @@ import sys                          # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MANIFEST = "BENCHMARK.json"
-REHEARSAL = os.path.join("benchmark", "rehearse", "manifest.json")
-TRAFFIC_DIR = {MANIFEST: os.path.join("benchmark", "traffic"),
-               REHEARSAL: os.path.join("benchmark", "rehearse", "traffic")}
+REHEARSE = os.path.join("benchmark", "rehearse")
+REHEARSAL = os.path.join(REHEARSE, "manifest.json")
+# keyed by "is a rehearsal"
+TRAFFIC_DIR = {False: os.path.join("benchmark", "traffic"),
+               True: os.path.join(REHEARSE, "traffic")}
 
 
-def load_json(*parts):
-    with open(os.path.join(ROOT, *parts)) as f:
+def load_json(*parts, root=ROOT):
+    with open(os.path.join(root, *parts)) as f:
         return json.load(f)
 
 
+def rehearsal_files(root=ROOT):
+    """``manifest.json`` and then every ``manifest.*.json`` fragment in
+    sorted order, as paths relative to ``root``."""
+    found = glob.glob(os.path.join(root, REHEARSE, "manifest.*.json"))
+    return [REHEARSAL] + sorted(os.path.relpath(p, root) for p in found)
+
+
+def rehearsal_manifest(root=ROOT):
+    """The rehearsal cells of every file of ``rehearsal_files`` as one
+    manifest: ``{"configs": [...], "workloads": [...]}``, in the files'
+    order.  A name that two files give is refused."""
+    merged = {"configs": [], "workloads": []}
+    seen = {key: {} for key in merged}      # name -> the file that gave it
+    for path in rehearsal_files(root):
+        part = load_json(path, root=root)
+        for key, entries in merged.items():
+            for entry in part[key]:
+                first = seen[key].setdefault(entry["name"], path)
+                if first != path:
+                    sys.exit(f"benchmark: {key} name {entry['name']!r} is "
+                             f"given by both {first} and {path}")
+                entries.append(entry)
+    return merged
+
+
 def find_cell(name):
-    """(manifest path, manifest, cell) of the cell called ``name``."""
-    for path in (MANIFEST, REHEARSAL):
-        manifest = load_json(path)
+    """(is a rehearsal, manifest, cell) of the cell called ``name``:
+    ``BENCHMARK.json`` first, then the rehearsal files."""
+    for rehearsal in (False, True):
+        manifest = rehearsal_manifest() if rehearsal else load_json(MANIFEST)
         for cell in manifest["workloads"]:
             if cell["name"] == name:
-                return path, manifest, cell
+                return rehearsal, manifest, cell
     sys.exit(f"benchmark: no cell named {name!r} in {MANIFEST} or "
-             f"{REHEARSAL}")
+             f"{', '.join(rehearsal_files())}")
 
 
 def metrics_of(kind, cell):
@@ -115,12 +152,11 @@ def main(argv=None):
     if args.seed < 0:
         sys.exit("benchmark: --seed must be >= 0")
 
-    path, manifest, cell = find_cell(args.workload)
-    rehearsal = path == REHEARSAL
+    rehearsal, manifest, cell = find_cell(args.workload)
     config_entry = next(c for c in manifest["configs"]
                         if c["name"] == cell["config"])
     config = load_json(config_entry["file"])
-    traffic = load_json(TRAFFIC_DIR[path], cell["traffic"] + ".json")
+    traffic = load_json(TRAFFIC_DIR[rehearsal], cell["traffic"] + ".json")
 
     sys.path.insert(0, ROOT)
     import jax
@@ -132,7 +168,7 @@ def main(argv=None):
     if not rehearsal and platform != "tpu":
         sys.exit(f"benchmark: {cell['name']} needs a TPU, jax found "
                  f"platform {platform!r} — there is no CPU fallback (the "
-                 f"cells of {REHEARSAL} rehearse the control flow)")
+                 f"rehearse-* cells of {REHEARSE} rehearse the control flow)")
     if len(devices) < cell["chips"]:
         sys.exit(f"benchmark: {cell['name']} needs {cell['chips']} chip(s), "
                  f"jax found {len(devices)}")

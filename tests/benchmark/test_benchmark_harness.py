@@ -20,15 +20,18 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
+from benchmark import run as runner                     # noqa: E402
 from benchmark.lib import (flops_bytes, peaks, reference, stats,  # noqa: E402
                            traffic, xplane)
+from grown_tree import grown_root, tree                 # noqa: E402,F401
+import grown_tree                                       # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 
-def load(*parts):
-    with open(os.path.join(ROOT, *parts)) as f:
+def load(*parts, root=ROOT):
+    with open(os.path.join(root, *parts)) as f:
         return json.load(f)
 
 
@@ -262,27 +265,37 @@ def test_xplane_host_threads_never_stand_in_for_the_chip():
 # the manifest
 # --------------------------------------------------------------------------
 
-def test_manifest_keys_sizes_and_limits():
-    assert set(MANIFEST) == {"command", "paths", "run_seconds", "configs",
+# Every test that takes ``tree`` reads only BENCHMARK.json, the rehearsal
+# manifests and file names, and runs twice: on the checkout and on the
+# tree the next cell-adding PR leaves (grown_tree.py).  What it asserts
+# has to hold as the manifest grows: properties, never today's lists.
+
+def test_manifest_keys_sizes_and_limits(tree):
+    manifest = load("BENCHMARK.json", root=tree)
+    assert set(manifest) == {"command", "paths", "run_seconds", "configs",
                              "workloads", "end_to_end", "per_layer"}
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 << 10
-    assert isinstance(MANIFEST["run_seconds"], int)
-    assert 1 <= MANIFEST["run_seconds"] <= 51
-    assert len(MANIFEST["command"]) <= 32
-    for word in MANIFEST["command"]:
+    assert os.path.getsize(os.path.join(tree, "BENCHMARK.json")) <= 64 << 10
+    assert isinstance(manifest["run_seconds"], int)
+    assert 1 <= manifest["run_seconds"] <= 51
+    assert len(manifest["command"]) <= 32
+    for word in manifest["command"]:
         assert not word.startswith("/") and ".." not in word
-    script = MANIFEST["command"][1]
-    assert any(script.startswith(p + "/") for p in MANIFEST["paths"])
-    assert os.path.isfile(os.path.join(ROOT, script))
-    assert 1 <= len(MANIFEST["workloads"]) <= 24
-    four = sum(c["chips"] == 4 for c in MANIFEST["workloads"])
-    assert four <= max(1, len(MANIFEST["workloads"]) // 4)
+    script = manifest["command"][1]
+    assert any(script.startswith(p + "/") for p in manifest["paths"])
+    assert os.path.isfile(os.path.join(tree, script))
+    assert 1 <= len(manifest["configs"]) <= 24
+    assert 1 <= len(manifest["workloads"]) <= 24
+    assert 1 <= len(manifest["end_to_end"]) <= 16
+    assert 1 <= len(manifest["per_layer"]) <= 128
+    four = sum(c["chips"] == 4 for c in manifest["workloads"])
+    assert four <= max(1, len(manifest["workloads"]) // 4)
 
 
-def test_manifest_names_units_and_lines():
+def test_manifest_names_units_and_lines(tree):
+    manifest = load("BENCHMARK.json", root=tree)
     names = []
     for group in ("configs", "workloads", "end_to_end", "per_layer"):
-        for entry in MANIFEST[group]:
+        for entry in manifest[group]:
             names.append((group in ("end_to_end", "per_layer"),
                           entry["name"]))
             assert NAME.match(entry["name"]), entry["name"]
@@ -293,79 +306,144 @@ def test_manifest_names_units_and_lines():
                 assert 1 <= len(text) <= 200, entry
                 assert "\n" not in text and "\t" not in text
     assert len(names) == len(set(names)), "a name is used twice"
-    for m in METRICS:
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
         assert UNIT.match(m["unit"]), m
         assert m["better"] in ("lower", "higher")
         assert m["source"] in ("device_trace", "program_span",
                                "program_counter", "host_clock")
-    for m in MANIFEST["end_to_end"]:
+    for m in manifest["end_to_end"]:
         assert set(m) <= {"name", "unit", "better", "bound", "source",
                           "workloads"}
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.1
-    for m in MANIFEST["per_layer"]:
+    for m in manifest["per_layer"]:
         assert set(m) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    for c in MANIFEST["workloads"]:
+    for c in manifest["workloads"]:
         assert set(c) == {"name", "config", "traffic", "chips", "why"}
         assert NAME.match(c["traffic"]) and c["chips"] in (1, 4)
-    pairs = [(c["config"], c["traffic"]) for c in MANIFEST["workloads"]]
+    pairs = [(c["config"], c["traffic"]) for c in manifest["workloads"]]
     assert len(pairs) == len(set(pairs))
 
 
-def test_manifest_names_only_files_that_exist():
-    files = [c["file"] for c in MANIFEST["configs"]]
+def test_manifest_names_only_files_that_exist(tree):
+    manifest = load("BENCHMARK.json", root=tree)
+    files = [c["file"] for c in manifest["configs"]]
     assert len(files) == len(set(files))
-    used = {c["config"] for c in MANIFEST["workloads"]}
-    for c in MANIFEST["configs"]:
+    used = {c["config"] for c in manifest["workloads"]}
+    for c in manifest["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert c["name"] in used, f"{c['name']} is used by no cell"
-        assert any(c["file"].startswith(p + "/") for p in MANIFEST["paths"])
-        cfg = load(c["file"])
+        assert any(c["file"].startswith(p + "/") for p in manifest["paths"])
+        cfg = load(c["file"], root=tree)
         assert cfg["reduced"] == c["reduced"] and len(c["reduced"]) <= 16
-    for cell in MANIFEST["workloads"]:
-        mix = load("benchmark", "traffic", cell["traffic"] + ".json")
+    for cell in manifest["workloads"]:
+        mix = load("benchmark", "traffic", cell["traffic"] + ".json",
+                   root=tree)
         assert os.path.isfile(os.path.join(
-            ROOT, "benchmark", "drivers", mix["driver"] + ".py"))
-    for m in METRICS:
+            tree, "benchmark", "drivers", mix["driver"] + ".py"))
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
         assert os.path.isfile(os.path.join(
-            ROOT, "benchmark", "metrics", m["name"] + ".py")), m["name"]
-    for path in MANIFEST["paths"]:
-        for _, _, names in os.walk(os.path.join(ROOT, path)):
+            tree, "benchmark", "metrics", m["name"] + ".py")), m["name"]
+    for path in manifest["paths"]:
+        for _, _, names in os.walk(os.path.join(tree, path)):
             for n in names:
                 if not n.endswith(".pyc"):
                     assert re.match(r"^[A-Za-z0-9_.\-]+$", n), n
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_every_cell_reports_what_the_contract_asks(cell):
+def cell_reports_what_the_contract_asks(manifest, cell):
     def reported(kind):
-        return [m["name"] for m in MANIFEST[kind]
+        return [m["name"] for m in manifest[kind]
                 if "workloads" not in m or cell in m["workloads"]]
     e2e, layer = reported("end_to_end"), reported("per_layer")
     assert "setup_s" in e2e and len(e2e) >= 2 and len(layer) >= 1
 
 
-@pytest.mark.parametrize("metric", [m["name"] for m in MANIFEST["per_layer"]])
-def test_layer_metric_moves_a_metric_its_cells_report(metric):
-    m = next(x for x in MANIFEST["per_layer"] if x["name"] == metric)
-    target = next(x for x in MANIFEST["end_to_end"]
+def layer_metric_moves_a_metric_its_cells_report(manifest, metric):
+    cells = [c["name"] for c in manifest["workloads"]]
+    m = next(x for x in manifest["per_layer"] if x["name"] == metric)
+    target = next(x for x in manifest["end_to_end"]
                   if x["name"] == m["moves"])
-    for cell in m.get("workloads", CELLS):
-        assert cell in CELLS
+    for cell in m.get("workloads", cells):
+        assert cell in cells
         assert "workloads" not in target or cell in target["workloads"], (
             f"{metric} moves {m['moves']}, which {cell} does not report")
-    same_layer = {x["layer"] for x in MANIFEST["per_layer"]
+    same_layer = {x["layer"] for x in manifest["per_layer"]
                   if x["layer"].lower() == m["layer"].lower()}
     assert len(same_layer) == 1, "one layer, one spelling"
 
 
-def test_rehearsal_cells_stand_for_real_ones_and_share_no_name():
-    rehearse = load("benchmark", "rehearse", "manifest.json")
-    for cell in rehearse["workloads"]:
-        assert cell["name"] not in CELLS
-        assert cell["stands_for"] in CELLS
-    assert {c["stands_for"] for c in rehearse["workloads"]} == set(CELLS)
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_cell_reports_what_the_contract_asks(cell):
+    cell_reports_what_the_contract_asks(MANIFEST, cell)
+
+
+@pytest.mark.parametrize("metric", [m["name"] for m in MANIFEST["per_layer"]])
+def test_layer_metric_moves_a_metric_its_cells_report(metric):
+    layer_metric_moves_a_metric_its_cells_report(MANIFEST, metric)
+
+
+def test_the_grown_trees_cells_and_layer_metrics_hold_too(grown_root):
+    """The two tests above are parametrised from the checkout as this
+    file is imported; the tree a cell-adding PR leaves is asked here."""
+    manifest = load("BENCHMARK.json", root=grown_root)
+    assert grown_tree.CELL in [c["name"] for c in manifest["workloads"]]
+    for cell in manifest["workloads"]:
+        cell_reports_what_the_contract_asks(manifest, cell["name"])
+    for m in manifest["per_layer"]:
+        layer_metric_moves_a_metric_its_cells_report(manifest, m["name"])
+
+
+def test_rehearsal_cells_stand_for_real_ones_and_share_no_name(tree):
+    """Over ``manifest.json`` and every fragment beside it, as the
+    runner reads them: every real cell has a rehearsal, of another name,
+    whose files are there."""
+    real = [c["name"] for c in load("BENCHMARK.json", root=tree)["workloads"]]
+    rehearse = runner.rehearsal_manifest(tree)
+    cells = rehearse["workloads"]
+    names = [c["name"] for c in cells]
+    assert len(set(names)) == len(names) and not set(names) & set(real)
+    for cell in cells:
+        assert cell["stands_for"] in real
+    assert {c["stands_for"] for c in cells} == set(real)
+    configs = {c["name"]: c["file"] for c in rehearse["configs"]}
+    for cell in cells:
+        assert os.path.exists(os.path.join(tree, configs[cell["config"]]))
+        assert os.path.exists(os.path.join(
+            tree, "benchmark", "rehearse", "traffic",
+            cell["traffic"] + ".json"))
+
+
+def test_fragments_are_read_in_sorted_order_after_the_manifest(tree):
+    files = runner.rehearsal_files(tree)
+    assert files[0] == os.path.join("benchmark", "rehearse", "manifest.json")
+    assert files[1:] == sorted(files[1:]) and len(files) >= 2
+    assert os.path.join("benchmark", "rehearse",
+                        "manifest.kanana2.json") in files
+    first = load(files[0], root=tree)["workloads"]
+    merged = runner.rehearsal_manifest(tree)["workloads"]
+    assert merged[:len(first)] == first
+
+
+@pytest.mark.parametrize("key", ["configs", "workloads"])
+def test_a_name_that_two_rehearsal_files_give_is_refused(key, tmp_path):
+    """The runner exits non-zero and names both files."""
+    rehearse = tmp_path / "benchmark" / "rehearse"
+    rehearse.mkdir(parents=True)
+    shutil.copy(os.path.join(ROOT, "benchmark", "rehearse", "manifest.json"),
+                rehearse)
+    taken = load("benchmark", "rehearse", "manifest.json")[key][0]
+    twice = {"configs": [], "workloads": []}
+    twice[key] = [taken]
+    (rehearse / "manifest.twice.json").write_text(json.dumps(twice))
+    with pytest.raises(SystemExit) as refused:
+        runner.rehearsal_manifest(str(tmp_path))
+    said = str(refused.value.code)      # a string: exit code 1, on stderr
+    assert taken["name"] in said
+    assert os.path.join("benchmark", "rehearse", "manifest.json") in said
+    assert os.path.join("benchmark", "rehearse",
+                        "manifest.twice.json") in said
 
 
 # --------------------------------------------------------------------------
@@ -436,45 +514,76 @@ def test_real_cell_without_a_tpu_prints_no_result(run_cell):
     assert not any(x.startswith("{") and '"metrics"' in x for x in lines)
 
 
-def test_cell_config_and_metric_added_as_files_only(run_cell, tmp_path):
-    """What a later PR does: new files and new entries, no edit to a file
-    that is there — and the runner finds and runs them."""
-    root = str(tmp_path)
-    shutil.copytree(os.path.join(ROOT, "benchmark"),
-                    os.path.join(root, "benchmark"),
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    rehearse = os.path.join(root, "benchmark", "rehearse")
-    cfg = load("benchmark", "rehearse", "configs", "tiny-serve.json")
-    cfg["num_layers"] = 3
-    cfg["engine"]["slots"] = 8
-    with open(os.path.join(rehearse, "configs", "added.json"), "w") as f:
-        json.dump(cfg, f)
-    mix = load("benchmark", "rehearse", "traffic", "tiny-backlog.json")
-    mix["output_len"]["median"] = 6
-    with open(os.path.join(rehearse, "traffic", "added-mix.json"), "w") as f:
-        json.dump(mix, f)
-    with open(os.path.join(root, "benchmark", "metrics",
-                           "added.steps.py"), "w") as f:
-        f.write("def read(run):\n    return len(run['step_s'])\n")
-    manifest = load("benchmark", "rehearse", "manifest.json")
-    manifest["configs"].append(
-        {"name": "added", "file": "benchmark/rehearse/configs/added.json"})
-    manifest["workloads"].append(
-        {"name": "rehearse-added", "config": "added",
-         "traffic": "added-mix", "chips": 1})
-    with open(os.path.join(rehearse, "manifest.json"), "w") as f:
-        json.dump(manifest, f)
-    real = dict(MANIFEST)
-    real["per_layer"] = MANIFEST["per_layer"] + [
-        {"name": "added.steps", "unit": "steps", "better": "higher",
-         "source": "program_counter", "layer": "scheduler",
-         "moves": "setup_s", "workloads": ["rehearse-added"]}]
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(real, f)
+def files_under(root):
+    """{relative path: bytes} of the three paths a PR may only add to."""
+    found = {}
+    for top in (grown_tree.MANIFEST,) + grown_tree.PATHS:
+        top = os.path.join(root, top)
+        walk = os.walk(top) if os.path.isdir(top) else [(root, [], [top])]
+        for folder, dirs, names in walk:
+            dirs[:] = [d for d in dirs if d != "__pycache__"]
+            for n in names:
+                path = os.path.join(folder, n)
+                if not n.endswith(".pyc"):
+                    with open(path, "rb") as f:
+                        found[os.path.relpath(path, root)] = f.read()
+    return found
 
-    rc, lines, err = run_cell(root, "rehearse-added", 1)
+
+def extends(new, old):
+    """``new`` is ``old`` with entries appended: every list starts with
+    the old one's items (themselves extended), nothing else differs."""
+    if isinstance(old, dict):
+        return (isinstance(new, dict) and set(new) == set(old)
+                and all(extends(new[k], old[k]) for k in old))
+    if isinstance(old, list):
+        return (isinstance(new, list) and len(new) >= len(old)
+                and all(extends(n, o) for n, o in zip(new, old)))
+    return new == old
+
+
+def test_extends_sees_an_edit_a_removal_and_a_reordering():
+    old = {"a": [{"n": 1, "w": ["x"]}, {"n": 2}], "k": 51}
+    assert extends({"a": [{"n": 1, "w": ["x", "y"]}, {"n": 2}, {"n": 3}],
+                    "k": 51}, old)
+    assert not extends({"a": [{"n": 1, "w": ["y", "x"]}, {"n": 2}],
+                        "k": 51}, old)
+    assert not extends({"a": [{"n": 2}, {"n": 1, "w": ["x"]}], "k": 51}, old)
+    assert not extends({"a": [{"n": 1, "w": ["x"]}], "k": 51}, old)
+    assert not extends(dict(old, k=50), old)
+    assert not extends(dict(old, more=1), old)
+
+
+def test_cell_config_and_metric_added_as_files_only(run_cell, grown_root):
+    """What the next PR that adds a cell does, to the letter
+    (grown_tree.py::build): files added, entries appended — and
+    (a) no file that was there differs, but ``BENCHMARK.json`` by
+    appended entries; (b) the runner finds and runs the added rehearsal
+    and reports the added metric; (c) is every test of this directory
+    that takes the fixture ``tree``: each passes on the grown tree."""
+    before, after = files_under(ROOT), files_under(grown_root)
+    assert set(before) <= set(after)
+    for path, data in before.items():
+        if path != grown_tree.MANIFEST:
+            assert after[path] == data, f"{path} was edited"
+    added = set(after) - set(before)
+    assert added == {
+        "benchmark/configs/added.json", "benchmark/traffic/added-mix.json",
+        "benchmark/rehearse/configs/tiny-added.json",
+        "benchmark/rehearse/traffic/tiny-added-mix.json",
+        "benchmark/rehearse/manifest.added.json",
+        "benchmark/metrics/added.steps.py"}
+    grown = load("BENCHMARK.json", root=grown_root)
+    assert extends(grown, MANIFEST) and grown != MANIFEST
+    assert grown["per_layer"][-1]["name"] == grown_tree.METRIC
+    assert grown["workloads"][-1]["name"] == grown_tree.CELL
+
+    rc, lines, err = run_cell(grown_root, grown_tree.REHEARSAL, 1)
     assert rc == 0, err[-2000:]
     result = check_last_line(lines, trace=1)
-    assert result["metrics"]["added.steps"]["value"] > 0
-    assert result["metrics"]["added.steps"]["unit"] == "steps"
-    assert "compile.setup_misses" in result["metrics"]
+    assert result["metrics"][grown_tree.METRIC]["value"] > 0
+    assert result["metrics"][grown_tree.METRIC]["unit"] == "steps"
+    # and the accepted metrics whose lists the cell was appended to
+    assert {"compile.setup_misses", "sched.slot_occupancy",
+            "step.decode_ms_p50",
+            "sched.span_self_ms_per_step"} <= set(result["metrics"])

@@ -3,13 +3,11 @@ benchmark: the family's operation and byte counts against hand counts,
 the configuration file against the published widths, the new readers
 off the chip and on a hand-made record, and the rehearsal cell walked
 end to end and traced (in subprocesses, as test_benchmark_harness.py
-does and for its reason; in a copy of ``benchmark/``, because the cell's
-entries are in ``rehearse/manifest.kanana2.json`` and not in the
-manifest that run.py reads)."""
+does and for its reason; from the checkout: the cell's entries are in
+the fragment ``rehearse/manifest.kanana2.json``, which run.py reads)."""
 import importlib.util
 import json
 import os
-import shutil
 import subprocess
 import sys
 
@@ -21,19 +19,22 @@ sys.path.insert(0, ROOT)
 
 from benchmark.drivers import serve_family                  # noqa: E402
 from benchmark.lib import flops_bytes_deepseek_v3 as fb     # noqa: E402
+from grown_tree import grown_root, tree                     # noqa: E402,F401
 
 CELL = "serve-kanana2-30b-backlog"
 NEW_METRICS = ("moe.experts_touched_share", "moe.load_max_over_mean",
                "decode_step_roofline.moe_mla", "paged_mla_decode_roofline")
 
 
-def load(*parts):
-    with open(os.path.join(ROOT, *parts)) as f:
+def load(*parts, root=ROOT):
+    with open(os.path.join(root, *parts)) as f:
         return json.load(f)
 
 
 ARCH = load("benchmark", "configs", "kanana2-30b-a3b-serve.json")
-MANIFEST = load("BENCHMARK.json")
+SPAN_METRICS = ("sched.span_self_ms_per_step", "pager.span_ms_per_step",
+                "step.dispatch_ms_per_step", "step.prefill_share",
+                "step.readback_wait_share")
 
 
 def reader(name):
@@ -129,34 +130,49 @@ def test_program_config_is_built_from_the_file_alone():
     assert reference.__name__.endswith("reference_deepseek_v3")
 
 
-def test_cell_traffic_is_the_issues_letter_for_letter():
-    mix = load("benchmark", "traffic", "backlog-256out.json")
+def test_cell_traffic_is_the_issues_letter_for_letter(tree):
+    mix = load("benchmark", "traffic", "backlog-256out.json", root=tree)
     assert mix["prompt_len"] == {"law": "lognormal", "median": 512,
                                  "sigma": 0.6, "min": 64, "max": 1024}
     assert mix["output_len"] == {"law": "lognormal", "median": 256,
                                  "sigma": 0.7, "min": 32, "max": 1024}
     assert mix["token_ids"] == {"law": "uniform"}
     assert (mix["block"], mix["backlog_depth"], mix["ramp_s"]) == (32, 8, 15)
-    cell = next(c for c in MANIFEST["workloads"] if c["name"] == CELL)
+    cell = next(c for c in load("BENCHMARK.json", root=tree)["workloads"]
+                if c["name"] == CELL)
     assert cell == dict(cell, config="kanana2-30b-a3b-serve",
                         traffic="backlog-256out", chips=1)
 
 
-def test_cell_reports_the_accepted_serving_metrics_its_record_feeds():
-    """GPT's step roofline counts GPT's bytes: not this cell's.  The
-    family's own four readers are files the manifest cannot list yet
-    (an accepted test pins the tail of ``per_layer``, PERF.md section
-    7); the driver prints them on a note line of a traced run."""
+def test_cell_reports_the_accepted_serving_metrics_its_record_feeds(tree):
+    """The cell is ON the list of every serving metric its record feeds
+    (other cells may be too), off GPT's step roofline, which counts
+    GPT's bytes, and the family's own four readers are listed for it."""
+    manifest = load("BENCHMARK.json", root=tree)
+
     def cells(name):
-        return next(m for m in MANIFEST["per_layer"] + MANIFEST["end_to_end"]
+        return next(m for m in manifest["per_layer"] + manifest["end_to_end"]
                     if m["name"] == name).get("workloads")
     for name in ("serve_tokens_per_s", "sched.slot_occupancy",
                  "sched.host_ms_per_step", "pager.pool_fill_peak",
-                 "pager.preempted_share", "step.decode_ms_p50"):
-        assert cells(name) == ["serve-1.3b-backlog", CELL], name
+                 "pager.preempted_share",
+                 "step.decode_ms_p50") + SPAN_METRICS:
+        assert CELL in cells(name), name
     assert CELL not in cells("decode_step_roofline")
-    assert serve_family.LAYER_METRICS == NEW_METRICS
-    for name in NEW_METRICS:
+    listed = {m["name"]: m for m in manifest["per_layer"]}
+    for name, (unit, better, source, layer) in {
+            "moe.experts_touched_share":
+                ("%", "higher", "program_counter", "jitted steps"),
+            "moe.load_max_over_mean":
+                ("ratio", "lower", "program_counter", "jitted steps"),
+            "decode_step_roofline.moe_mla":
+                ("%", "higher", "program_span", "kernels"),
+            "paged_mla_decode_roofline":
+                ("%", "higher", "device_trace", "kernels")}.items():
+        m = listed[name]
+        assert CELL in m["workloads"] and m["moves"] == "serve_tokens_per_s"
+        assert (m["unit"], m["better"], m["source"], m["layer"]) == (
+            unit, better, source, layer), name
         assert callable(reader(name))
 
 
@@ -272,46 +288,12 @@ def test_emitted_gaps_cover_every_generated_row_and_see_a_wrong_token():
 # the rehearsal cell, end to end on the CPU
 # --------------------------------------------------------------------------
 
-def rehearsal_manifest():
-    """``benchmark/rehearse/manifest.json`` with this cell's entries
-    appended: what that file would hold if PR 28 might edit it."""
-    manifest = load("benchmark", "rehearse", "manifest.json")
-    added = load("benchmark", "rehearse", "manifest.kanana2.json")
-    for key in ("configs", "workloads"):
-        manifest[key] = manifest[key] + added[key]
-    return manifest
-
-
-def test_every_real_cell_has_a_rehearsal_of_another_name():
-    """What test_rehearsal_cells_stand_for_real_ones_and_share_no_name
-    asks, over both files (conftest.py says why that one cannot pass)."""
-    real = [c["name"] for c in MANIFEST["workloads"]]
-    cells = rehearsal_manifest()["workloads"]
-    names = [c["name"] for c in cells]
-    assert len(set(names)) == len(names) and not set(names) & set(real)
-    assert {c["stands_for"] for c in cells} == set(real)
-    configs = {c["name"]: c["file"] for c in rehearsal_manifest()["configs"]}
-    for cell in cells:
-        assert os.path.exists(os.path.join(ROOT, configs[cell["config"]]))
-        assert os.path.exists(os.path.join(
-            ROOT, "benchmark", "rehearse", "traffic",
-            cell["traffic"] + ".json"))
-
-
 @pytest.fixture(scope="module")
 def run_cell(tmp_path_factory):
-    """The runner in a copy of ``benchmark/`` whose rehearsal manifest
-    holds the new entries, as test_cell_config_and_metric_added_as_files_only
-    lays out a later PR's files; ``paddle_tpu`` is the checkout's."""
+    """The rehearsal from the checkout, as the other two run:
+    ``JAX_PLATFORMS=cpu python3 benchmark/run.py --workload
+    rehearse-kanana2-backlog``."""
     cache = tmp_path_factory.mktemp("jax_cache")
-    root = str(tmp_path_factory.mktemp("overlay"))
-    shutil.copytree(os.path.join(ROOT, "benchmark"),
-                    os.path.join(root, "benchmark"),
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    with open(os.path.join(root, "benchmark", "rehearse",
-                           "manifest.json"), "w") as f:
-        json.dump(rehearsal_manifest(), f)
 
     def run(trace):
         env = dict(os.environ, JAX_PLATFORMS="cpu",
@@ -319,10 +301,10 @@ def run_cell(tmp_path_factory):
                    PYTHONPATH=ROOT + os.pathsep
                    + os.environ.get("PYTHONPATH", ""))
         proc = subprocess.run(
-            [sys.executable, os.path.join(root, "benchmark", "run.py"),
+            [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
              "--workload", "rehearse-kanana2-backlog", "--seed",
              str(2**31 + 11), "--seconds", "1.5", "--trace", str(trace)],
-            cwd=root, env=env, capture_output=True, text=True, timeout=600)
+            cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
         assert proc.returncode == 0, proc.stderr[-2000:]
         lines = proc.stdout.strip().splitlines()
         return (json.loads(lines[-1]),
@@ -346,16 +328,26 @@ def test_rehearsal_end_to_end(run_cell):
 
 
 def test_rehearsal_traced_reports_the_counters_and_no_chip_share(run_cell):
+    """Every per-layer metric BENCHMARK.json lists for the cell, but the
+    shares of a chip's peak: those are never computed from a CPU run
+    (their readers return None and the line leaves them out)."""
     result, notes = run_cell(1)
     assert result["correct"] is True
-    got = set(result["metrics"])
-    assert {"step.decode_ms_p50", "sched.slot_occupancy",
-            "sched.host_ms_per_step", "pager.pool_fill_peak",
-            "pager.preempted_share", "compile.setup_misses"} == got
+    manifest = load("BENCHMARK.json")
+    listed = {m["name"] for m in manifest["per_layer"]
+              if "workloads" not in m or CELL in m["workloads"]}
+    off_chip = {"decode_step_roofline.moe_mla", "paged_mla_decode_roofline"}
+    assert off_chip <= listed
+    got = result["metrics"]
+    assert set(got) <= listed and not set(got) & off_chip
+    assert {"moe.experts_touched_share", "moe.load_max_over_mean",
+            "sched.host_ms_per_step", "compile.setup_misses"} \
+        | set(SPAN_METRICS) <= set(got)
     assert result["device"]["busy_s"] > 0
-    family = notes["family_layer_metrics"]
-    assert 0 < family["moe.experts_touched_share"] <= 100
-    assert family["moe.load_max_over_mean"] >= 1.0
-    # a share of a chip's peak is never computed from a CPU run
-    assert family["decode_step_roofline.moe_mla"] is None
-    assert family["paged_mla_decode_roofline"] is None
+    assert 0 < got["moe.experts_touched_share"]["value"] <= 100
+    assert got["moe.load_max_over_mean"]["value"] >= 1.0
+    assert got["moe.load_max_over_mean"]["unit"] == "ratio"
+    assert "family_layer_metrics" not in notes
+    # how far the window is from wrapping the program's span ring
+    closed = notes["window_closed"]
+    assert 0 < closed["spans_in_window"] < closed["ring_spans"]
