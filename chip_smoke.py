@@ -18,7 +18,9 @@ seeded random weights:
   int8 pool with int8 weights; then the ``deepseek_v3`` family at
   kanana-2-30b-a3b's widths (8 of 48 layers, every expert) through the
   same engine over its latent page pool, against its own plain float32
-  reference.
+  reference; then the ``ouro`` family (layers looped over shared
+  weights) at a TINY size, for its gate's counters ``loop_tokens`` /
+  ``loop_passes``.
 
 It checks what comes out (falling finite loss, Pallas flash against XLA
 attention, engine logits against the float32 model) and that the Pallas
@@ -467,6 +469,9 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
         "drains": st["drains"],
         "prefix_page_hits": st["prefix_page_hits"],
         "preemptions": st["preemptions"],
+        # (None for a family whose layers run once)
+        "loop_tokens": st.get("loop_tokens"),
+        "loop_passes": st.get("loop_passes"),
         "kv_bytes_total": st["kv_bytes_total"],
         "param_bytes_per_device": eng.param_bytes_per_device(),
         "placed_bytes_per_device": placed,
@@ -680,6 +685,31 @@ def phase_serve_latent(run):
             f"{worst} at worst (> {LATENT_MAX_TOL})")
 
 
+def phase_serve_looped(run):
+    """The ouro family (the stacked layers run several times over shared
+    weights, a page pool of passes x layers) at its TINY size through
+    the same engine, whatever the other phases' size: that it serves on
+    this device, and what its exit gate counted."""
+    import jax
+    from paddle_tpu.models import ouro
+
+    tiny = Run(REHEARSE, run.devices, run.seed, run.on_chip)
+    cfg = ouro.ouro_tiny(hidden_size=128, dtype="bfloat16",
+                         param_dtype="bfloat16")
+    params = ouro.init_params(cfg, jax.random.PRNGKey(run.seed + 4))
+    _, report = serve_requests(tiny, params, cfg, prompts_for(tiny, cfg),
+                               pool="looped", capture_logits=False)
+    emit(phase="serve_looped", note="smoke, not a measurement",
+         device_kind=run.kind,
+         shape=dict(hidden=cfg.hidden_size, layers=cfg.num_hidden_layers,
+                    passes=cfg.total_ut_steps, vocab=cfg.vocab_size),
+         **report)
+    if report["loop_passes"] != cfg.total_ut_steps * report["loop_tokens"]:
+        raise AssertionError(
+            f"serve_looped: {report['loop_passes']} passes for "
+            f"{report['loop_tokens']} tokens at threshold 1")
+
+
 # --------------------------------------------------------------------------
 # --chips 4: the mesh phases and what they are compared with, nothing else
 # --------------------------------------------------------------------------
@@ -829,6 +859,7 @@ def main():
         phase_serve(run, params, cfg, "int8")
         del params
         phase_serve_latent(run)
+        phase_serve_looped(run)
     emit(phase="compile_cache", dir=cache_dir, **run.counters(),
          total_s=round(time.perf_counter() - t0, 1))
     result = {"ok": True,

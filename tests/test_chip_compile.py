@@ -1,4 +1,4 @@
-"""The kernels of the two main paths, and the serving engine's whole
+"""The kernels of the main paths, and the serving engine's whole
 decode and prefill programs, compiled for the chip without the chip: the
 installed TPU compiler takes a DESCRIBED ``v5e:2x2`` device, so each test
 AOT-compiles one Pallas kernel at the widths ``chip_smoke.py`` serves and
@@ -361,4 +361,78 @@ def test_kanana_serving_programs_fit_and_hold_the_pool_in_place(
         assert mem.temp_size_in_bytes < (64 << 20)
     for scope in ("mla_q", "mla_latent", "mla_attn", "mla_out",
                   "moe_router", "moe_routed", "moe_shared", "dense_mlp"):
+        assert scope in text, scope
+
+
+# --------------------------------------------------------------------------
+# the looped family (models/ouro.py) at the benchmark cell's sizes
+# (benchmark/configs/ouro-2.6b-serve.json): 48 layers x 4 passes over a
+# pool of 192 virtual layers, sized to the chip by the 90% rule
+# --------------------------------------------------------------------------
+
+@pytest.fixture
+def served_ouro(chip, monkeypatch):
+    import json
+    from paddle_tpu.inference.serving import PagedServingEngine
+    from paddle_tpu.models import ouro
+    from paddle_tpu.ops.pallas import utils as pallas_utils
+    monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
+    monkeypatch.setenv("PADDLE_TPU_SERVING_DONATE", "1")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "ouro-2.6b-serve.json")) as f:
+        arch = json.load(f)
+    fields = ouro.OuroConfig.__dataclass_fields__
+    cfg = ouro.OuroConfig(**{k: v for k, v in arch.items() if k in fields})
+    params = jax.tree_util.tree_map(
+        lambda x: chip(x.shape, x.dtype),
+        jax.eval_shape(lambda k: ouro.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    e = arch["engine"]
+    eng = PagedServingEngine(
+        (params, cfg), capture_logits=False,
+        **dict(e, num_pages=2, seq_buckets=tuple(e["seq_buckets"]),
+               batch_buckets=tuple(e["batch_buckets"])))
+    pools = tuple(chip(s, bf16) for s in ouro.paged_pool_shapes(
+        cfg, e["num_pages"], e["page_size"]))
+    return eng, params, pools, e
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_4x512"])
+def test_ouro_programs_fit_the_rule_and_hold_the_pool_in_place(
+        chip, served_ouro, program):
+    from paddle_tpu.inference.serving import pool_relayouts
+    eng, params, pools, e = served_ouro
+    slots, ps = e["slots"], e["page_size"]
+    assert pools[0].shape == (192, e["num_pages"], 16, 2048)
+    if program == "decode":
+        fn = eng._build_decode()
+        args = (chip((slots, e["max_len"] // ps), i32),
+                *[chip((slots,), i32)] * 4)
+    else:
+        fn = eng._build_prefill(4, 512)
+        args = (chip((4, 512), i32), chip((4,), i32),
+                chip((4, 512 // ps), i32), chip((slots,), i32),
+                chip((4,), i32))
+    compiled = fn.lower(params, *pools, *args).compile()
+    text = compiled.as_text()
+    assert pool_relayouts(text, pools) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools)
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    # the MOST pages the rule allows: this many fit, one more would not
+    assert total <= KANANA_BUDGET < total + 2 * pools[0].size // e[
+        "num_pages"] * 2, total / 2 ** 30
+    # no stack of weights copied into another layout (Wq and Wk were,
+    # 0.75 GiB, while they were stored (in, out))
+    assert mem.temp_size_in_bytes < (64 << 20)
+    if program == "decode":
+        # one kernel in the scans' body, whatever the passes and layers
+        assert text.count("tpu_custom_call") == 1
+        assert "paged_attn_decode" in text
+    for scope in ("ut_step", "attn_qkv", "rope", "attn_out", "mlp",
+                  "loop_norm", "exit_gate",
+                  "kv_write" if program == "decode" else "kv_scatter"):
         assert scope in text, scope
