@@ -388,7 +388,8 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
     kw = (dict(kv_dtype="int8", page_size=32, quant="int8")
           if pool == "int8" else dict(page_size=page_size))
     kernel_ctr = {"paged": "serving.paged_kernel_calls",
-                  "dequant_matmul": "serving.dequant_kernel_calls_matmul"}
+                  "dequant_matmul": "serving.dequant_kernel_calls_matmul",
+                  "grouped_matmul": "serving.grouped_matmul_kernel_calls"}
     k0 = {k: run.counter(n) for k, n in kernel_ctr.items()}
     c0 = run.counters()
     t0 = time.perf_counter()
@@ -437,7 +438,8 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
         raise AssertionError(
             f"the compiled programs copy the KV pool: {relayouts}")
     if run.on_chip:
-        need = ["paged"] + (["dequant_matmul"] if pool == "int8" else [])
+        need = ["paged"] + {"int8": ["dequant_matmul"],
+                            "latent": ["grouped_matmul"]}.get(pool, [])
         gave_way = [k for k in need if engaged[k] < 1]
         if gave_way:
             raise AssertionError(
@@ -629,6 +631,57 @@ def phase_serve_overlapped(run, params, cfg, drained):
             f"drained loop's argmax (> {OVERLAP_TIE_TOL})")
 
 
+LATENT_DECODE_SLOTS = 64     # the benchmark cell's: 64 x 6 = 384 rows
+
+
+def grouped_matmul_calls(run, params, cfg):
+    """The experts' grouped matmul alone, as a decode step of the
+    benchmark's 64 slots calls it on these weights (near-even routing:
+    every token its own 6 experts in turn): microseconds a call beside
+    the bytes of the experts it touches.  Timed on the chip only — off
+    it the same calls run ``ragged_dot`` once, untimed."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    E, k = cfg.n_routed_experts, cfg.num_experts_per_tok
+    rows = LATENT_DECODE_SLOTS * k
+    picks = (np.arange(rows) * 7) % E          # k distinct a token
+    sizes = jnp.asarray(np.bincount(picks, minlength=E), jnp.int32)
+    moe = params["moe"]
+    layers = moe["eg"].shape[0]
+    cd = jnp.dtype(cfg.dtype)
+    xs = jnp.ones((rows, cfg.hidden_size), cd)
+
+    @jax.jit
+    def layer_by_layer(xs, sizes, eg, eu, ed):
+        def body(c, li):
+            mid = gm.grouped_gate_up(xs, sizes, eg, eu, li)
+            return c + gm.grouped_matmul(mid, sizes, ed, li)[0, 0], None
+        return jax.lax.scan(body, jnp.float32(0),
+                            jnp.arange(layers, dtype=jnp.int32))[0]
+
+    args = (xs, sizes, moe["eg"], moe["eu"], moe["ed"])
+    jax.block_until_ready(layer_by_layer(*args))
+    touched = int((np.asarray(sizes) > 0).sum())
+    report = {"rows": rows, "experts_touched": touched,
+              "expert_bytes_a_layer": int(
+                  touched * 3 * cfg.hidden_size * cfg.moe_intermediate_size
+                  * cd.itemsize),
+              "kernel": bool(run.on_chip)}
+    if run.on_chip:
+        iters = 10
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            out = layer_by_layer(*args)
+        jax.block_until_ready(out)
+        # gate-up and down together: two calls, three products
+        report["us_a_layer"] = round(
+            (time.perf_counter() - t0) / (iters * layers) * 1e6, 1)
+    return report
+
+
 def phase_serve_latent(run):
     """The deepseek_v3 family (latent page pool, dropless experts)
     through the same engine: logits of the first and a later generated
@@ -677,6 +730,7 @@ def phase_serve_latent(run):
          logit_err_later=[e[1] for e in errs], logit_err_max=worst,
          logit_err_lower_quartile=quartile,
          tol=dict(lower_quartile=LATENT_QUARTILE_TOL, max=LATENT_MAX_TOL),
+         grouped_matmul=grouped_matmul_calls(run, params, cfg),
          memory=run.memory(), **report)
     if quartile > LATENT_QUARTILE_TOL or worst > LATENT_MAX_TOL:
         raise AssertionError(

@@ -182,6 +182,9 @@ def _stats_family():
         # Pallas paged-attention kernel instantiations, fp and int8
         # pools alike (same trace-time meaning; 0 off-TPU)
         "paged_kernel_calls": 0,
+        # the experts' grouped-matmul kernel, likewise (the deepseek_v3
+        # family: two an expert layer a program — gate-up, down)
+        "grouped_matmul_kernel_calls": 0,
         # expert-layer family (models/deepseek_v3.py; zero elsewhere):
         # what the decode step counts on the device and hands back
         # with its sampled tokens — assignments routed by the active
@@ -472,6 +475,12 @@ class ServingEngine:
         # own dict, which stats() reports — a global-delta snapshot would
         # misattribute a coexisting engine's traffic
         self._counts = {k: 0 for k in self._stats}
+        # the kernels' engagement counters fire where a program is
+        # TRACED, deep under the family's code, so stats() reports what
+        # the process counted since this engine was built (its own
+        # programs, unless another engine traces meanwhile)
+        self._kernels_before = {k: self._stats[k]
+                                for k in self._KERNEL_COUNTERS}
         self._prefill = _cc.site(
             "serving.prefill",
             maxsize=4 * len(self.seq_buckets) * len(self.batch_buckets),
@@ -1319,11 +1328,16 @@ class ServingEngine:
         self._stats.inc(key, v)
         self._counts[key] = self._counts.get(key, 0) + v
 
+    _KERNEL_COUNTERS = ("dequant_kernel_calls", "paged_kernel_calls",
+                        "grouped_matmul_kernel_calls")
+
     def stats(self):
         """THIS engine's serving.* counters + live gauges, one dict.
         The process-global family (all engines pooled) is
         :func:`serving_stats`."""
         out = dict(self._counts)
+        for k, before in self._kernels_before.items():
+            out[k] = self._stats[k] - before
         out["queue_depth"] = self._queued_total()
         out["slot_occupancy"] = int(self._active.sum())
         out["slot_occupancy_peak"] = self._occ_peak

@@ -39,10 +39,13 @@ so both are stored major-to-minor as the kernel takes them and every
 layer writes them where they lie (``gpt._layer_scan``).
 
 The expert layer is exact top-k and DROPLESS: assignments are sorted by
-expert and the three products run as grouped matmuls
-(``jax.lax.ragged_dot``, which the TPU compiler lowers to its own
-grouped-matmul call) over every expert held — one executable per
-bucket whatever the routing mix.
+expert and the three products run as grouped matmuls over every expert
+held — one executable per bucket whatever the routing mix.  On the chip
+they are ``ops/pallas/grouped_matmul.py``'s kernel at every row count
+(decode's 384 rows and every prefill bucket: it streams each touched
+expert's weights once), two calls a layer — gate and up in one, writing
+``silu(g) * u``, then down; off the chip ``jax.lax.ragged_dot``.  Both
+take the expert STACK whole with the layer's index.
 
 Parameter tree: ``embed [V, H]``, ``head [H, V]``, ``norm_f [H]``,
 ``dense`` (layer 0: attention leaves + ``wg, wu, wd``) and ``moe`` (the
@@ -312,8 +315,13 @@ def moe_ffn(cfg, h2, blk, row_mask=None):
     the WHOLE stack [layers, E, ., .] with ``blk["li"]`` naming the
     layer: the grouped matmul is a custom call, so a layer sliced out
     of the stack for it would be copied (1.2 GB a layer at the
-    published widths); instead the stack goes in whole as layers * E
-    groups, of which only this layer's have rows."""
+    published widths); instead the stack goes in whole with its layer
+    index (``ops/pallas/grouped_matmul.py``: on the chip the kernel's
+    index map names the layer; off it ``ragged_dot`` sees layers * E
+    groups of which only this layer's have rows).  ``silu(g) * u`` is
+    formed in float32 and rounded to the compute dtype once, before
+    the down projection, on either path."""
+    from ..ops.pallas import grouped_matmul as gmm
     cd = jnp.dtype(cfg.dtype)
     T, H = h2.shape
     K, E = cfg.num_experts_per_tok, cfg.n_routed_experts
@@ -329,18 +337,10 @@ def moe_ffn(cfg, h2, blk, row_mask=None):
     with jax.named_scope("moe_routed"):
         order = jnp.argsort(flat, stable=True)
         xs = h2[order // K]                                # expert-sorted
-        groups = math.prod(blk["eg"].shape[:-2])
-        sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((groups,), jnp.int32), sizes, (blk.get("li", 0) * E,))
-
-        def dot(rows, stack):
-            return jax.lax.ragged_dot(
-                rows, stack.astype(cd).reshape((groups,) + stack.shape[-2:]),
-                sizes, preferred_element_type=jnp.float32)
-
-        g = dot(xs, blk["eg"])
-        u = dot(xs, blk["eu"])
-        y = dot((jax.nn.silu(g) * u).astype(cd), blk["ed"])
+        li = blk.get("li")
+        mid = gmm.grouped_gate_up(xs, sizes, blk["eg"].astype(cd),
+                                  blk["eu"].astype(cd), li)
+        y = gmm.grouped_matmul(mid, sizes, blk["ed"].astype(cd), li)
         y = y[jnp.argsort(order)].reshape(T, K, H)         # unsort
         y = jnp.einsum("tkh,tk->th", y, w).astype(cd)
     with jax.named_scope("moe_shared"):

@@ -306,6 +306,29 @@ def test_paged_mla_decode_kernel(chip):
     assert "paged_mla_decode" in text
 
 
+@pytest.mark.parametrize("rows", [384, 24576],
+                         ids=["decode-64x6", "prefill-4x1024x6"])
+@pytest.mark.parametrize("form", ["gate_up", "down"])
+def test_grouped_matmul_kernel(chip, rows, form):
+    """The experts' grouped matmul at 128 experts of [2048, 768] /
+    [768, 2048] in a stack of 7 layers: a whole expert a block (gate and
+    up together 12 MiB twice-buffered, over the default scoped VMEM, so
+    the call states its limit), windows of 128 rows."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    k, n = (2048, 768) if form == "gate_up" else (768, 2048)
+    stacks = (chip((KANANA_LAYERS - 1, 128, k, n), bf16),) * (
+        2 if form == "gate_up" else 1)
+    assert gm.window_rows(rows, 2) == 128
+    assert gm.column_tile(128, k, n, 2, len(stacks),
+                          2 if form == "gate_up" else 4) == n
+    fn = functools.partial(gm._grouped_tpu, gate_up=form == "gate_up")
+    text = jax.jit(fn).lower(chip((rows, k), bf16), chip((128,), i32),
+                             stacks, chip((), i32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "%moe_grouped_matmul" + ("_gate_up" if form == "gate_up"
+                                    else "") in text
+
+
 @pytest.fixture
 def served_kanana(chip, monkeypatch):
     """(engine, params, pools) of the benchmark's own configuration,
@@ -359,6 +382,11 @@ def test_kanana_serving_programs_fit_and_hold_the_pool_in_place(
         # of temporaries when it was)
         assert text.count("paged_mla_decode") >= 2
         assert mem.temp_size_in_bytes < (64 << 20)
+    # the experts' three products: our kernel twice in the scan's body
+    # (gate-up, down) and the compiler's ragged-dot nowhere
+    assert "%moe_grouped_matmul_gate_up" in text
+    assert text.count("moe_grouped_matmul/pallas_call") >= 1
+    assert "ragged" not in text
     for scope in ("mla_q", "mla_latent", "mla_attn", "mla_out",
                   "moe_router", "moe_routed", "moe_shared", "dense_mlp"):
         assert scope in text, scope

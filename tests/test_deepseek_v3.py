@@ -11,6 +11,7 @@ logits (about 4e-3, asserted below): a lower precision where float32 is
 stated fails every comparison here.
 """
 import dataclasses
+import functools
 import importlib.util
 import os
 
@@ -21,7 +22,8 @@ import pytest
 
 from paddle_tpu.inference.serving import PagedServingEngine, ServingEngine
 from paddle_tpu.models import deepseek_v3 as ds
-from paddle_tpu.ops.pallas import paged_mla
+from paddle_tpu.observability import metrics
+from paddle_tpu.ops.pallas import grouped_matmul, paged_mla
 from paddle_tpu.testing import reference_deepseek_v3 as ref
 
 TOL = 5e-6
@@ -36,6 +38,22 @@ def tiny():
 
 def _hp(cfg):
     return dataclasses.asdict(cfg)
+
+
+def _grouped_kernel_on(monkeypatch):
+    """The chip's branch of the experts' grouped matmul, interpreted."""
+    monkeypatch.setattr(grouped_matmul, "pallas_enabled", lambda: True)
+    monkeypatch.setattr(grouped_matmul, "_grouped_tpu", functools.partial(
+        grouped_matmul._grouped_tpu, interpret=True))
+
+
+@pytest.fixture(params=["ragged_dot", "kernel"])
+def moe_path(request, monkeypatch):
+    """``moe_ffn``'s two implementations of the routed products: the
+    off-chip ``ragged_dot`` and the Pallas kernel (interpret mode)."""
+    if request.param == "kernel":
+        _grouped_kernel_on(monkeypatch)
+    return request.param
 
 
 _REF_JITS = {}
@@ -162,7 +180,8 @@ class TestRouting:
         assert np.array_equal(np.asarray(chosen), np.asarray(r_chosen))
         assert np.abs(np.asarray(w) - np.asarray(r_w)).max() < 1e-6
 
-    def test_no_token_is_dropped_when_all_pick_the_same_experts(self, tiny):
+    def test_no_token_is_dropped_when_all_pick_the_same_experts(
+            self, tiny, moe_path):
         """A capacity buffer would drop here: a bias of +10 sends all
         64 tokens to experts 2 and 5.  Every token's output equals the
         reference's, and the counts say 64 each."""
@@ -178,15 +197,34 @@ class TestRouting:
                               [0, 0, 64, 0, 0, 64, 0, 0])
         assert np.abs(np.asarray(y) - np.asarray(want)).max() < TOL
 
-    def test_counts_leave_out_masked_rows(self, tiny):
+    def test_counts_leave_out_masked_rows(self, tiny, moe_path):
         params, cfg = tiny
         blk = self._layer(params)
         h2 = jax.random.normal(jax.random.PRNGKey(7), (6, cfg.hidden_size))
         mask = jnp.asarray([True, False, True, True, False, False])
-        y_all, c_all = ds.moe_ffn(cfg, h2, blk)
-        y, c = ds.moe_ffn(cfg, h2, blk, row_mask=mask)
+        with jax.default_matmul_precision("highest"):
+            y_all, c_all = ds.moe_ffn(cfg, h2, blk)
+            y, c = ds.moe_ffn(cfg, h2, blk, row_mask=mask)
+            want = ref.experts(h2, blk, _hp(cfg))
         assert int(c_all.sum()) == 12 and int(c.sum()) == 6
         assert np.array_equal(np.asarray(y), np.asarray(y_all))
+        assert np.abs(np.asarray(y) - np.asarray(want)).max() < TOL
+
+    def test_the_whole_stack_with_its_layer_index(self, tiny, moe_path):
+        """As the layer scan hands them over: the experts' stacks whole,
+        ``li`` traced, against the reference on that layer alone."""
+        params, cfg = tiny
+        h2 = jax.random.normal(jax.random.PRNGKey(8), (33, cfg.hidden_size))
+        stacks = {k: params["moe"][k] for k in ds.EXPERT_STACKS}
+        step = jax.jit(lambda li, blk: ds.moe_ffn(
+            cfg, h2, dict(blk, li=li, **stacks))[0])
+        for i in range(cfg.num_hidden_layers - 1):
+            blk = self._layer(params, i)
+            sliced = {k: v for k, v in blk.items() if k not in stacks}
+            with jax.default_matmul_precision("highest"):
+                y = step(jnp.int32(i), sliced)
+                want = ref.experts(h2, blk, _hp(cfg))
+            assert np.abs(np.asarray(y) - np.asarray(want)).max() < TOL
 
 
 class TestKernel:
@@ -302,7 +340,6 @@ class TestPagedEngine:
 
     def test_through_the_kernel_in_interpret_mode(self, tiny, monkeypatch):
         """The engine's decode step with the Pallas kernel in it."""
-        import functools
         params, cfg = tiny
         monkeypatch.setattr(paged_mla, "pallas_enabled", lambda: True)
         monkeypatch.setattr(paged_mla, "_paged_mla_tpu", functools.partial(
@@ -313,6 +350,57 @@ class TestPagedEngine:
         eng.run(max_steps=50)
         for r in reqs:
             _assert_request_matches(params, cfg, r)
+
+    def test_through_the_grouped_matmul_kernel_in_interpret_mode(
+            self, tiny, monkeypatch):
+        """Prefill waves and decode steps with the experts' products in
+        the Pallas kernel; the engine's stats say it engaged."""
+        params, cfg = tiny
+        _grouped_kernel_on(monkeypatch)
+        eng = _engine(tiny, slots=2)
+        before = eng.stats()["grouped_matmul_kernel_calls"]
+        reqs = [eng.submit(_tokens(70 + i, n), 5) for i, n in
+                enumerate((7, 18))]
+        eng.run(max_steps=50)
+        for r in reqs:
+            _assert_request_matches(params, cfg, r)
+        assert eng.stats()["grouped_matmul_kernel_calls"] > before
+
+
+class TestGroupedMatmulEngagement:
+    """``serving.grouped_matmul_kernel_calls`` answers "did what XLA
+    built contain the kernel?": a trace with the chip's branch taken
+    holds the kernel and bumps it, the CPU's holds ``ragged_dot`` and
+    bumps nothing."""
+    COUNTER = "serving.grouped_matmul_kernel_calls"
+
+    def _decode_jaxpr(self, tiny):
+        params, cfg = tiny
+        pools = ds.init_paged_pools(cfg, 6, 8)
+        ints = jnp.zeros((2,), jnp.int32)
+        return str(jax.make_jaxpr(lambda *a: ds.decode_paged(
+            params, cfg, *a))(pools, jnp.zeros((2, 4), jnp.int32), ints,
+                              ints, ints + 3, ints))
+
+    def test_kernel_path_holds_the_calls_and_counts_them(self, tiny,
+                                                         monkeypatch):
+        _grouped_kernel_on(monkeypatch)
+        before = metrics.counter(self.COUNTER).value
+        text = self._decode_jaxpr(tiny)
+        # the scan's body, once: gate and up share a call, then down —
+        # the layer's three products in two kernels
+        assert text.count("name=moe_grouped_matmul_gate_up") == 1
+        assert text.count("name=moe_grouped_matmul\n") \
+            + text.count("name=moe_grouped_matmul ") == 1
+        assert "ragged_dot" not in text
+        assert metrics.counter(self.COUNTER).value == before + 2
+
+    def test_cpu_path_counts_nothing(self, tiny):
+        before = metrics.counter(self.COUNTER).value
+        text = self._decode_jaxpr(tiny)
+        assert text.count("= ragged_dot") == 3   # a scan body's products
+        assert "moe_grouped_matmul" not in text
+        assert metrics.counter(self.COUNTER).value == before
 
 
 class TestUnbuiltCompositions:
