@@ -139,6 +139,12 @@ class KVPager:
         # mistaken for one another (a fleet comparing prefix keys across
         # mixed fp32/int8 replicas must never alias them)
         self.hash_key = "" if hash_key is None else str(hash_key)
+        # bytes the engine's slots own BESIDE the pages (a recurrent
+        # layer's state, a window's ring: arrays indexed by slot, with
+        # no lifetime for the pager to manage); the engine sets it, and
+        # ``stats()`` reports it beside the pool's numbers so that a
+        # reading of the pool's fill is not taken for the whole cache
+        self.slot_state_bytes = 0
         if self.num_pages < 2:
             raise ValueError(
                 f"num_pages must be >= 2 (page 0 is scratch), got "
@@ -485,4 +491,5 @@ class KVPager:
             "evictions": self.evictions,
             "pages_per_request_est": self.pages_per_request_est(),
             "chain_digest_count": len(self._cache),
+            "slot_state_bytes": self.slot_state_bytes,
         }

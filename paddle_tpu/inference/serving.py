@@ -97,9 +97,10 @@ def pool_relayouts(hlo_text, pools):
     return found
 
 
-# What a model family gives the paged engine (models/gpt.py and
-# models/deepseek_v3.py both do): everything else in this file is the
-# same scheduler, pager, spans and counters for every family.
+# What a model family gives the paged engine (models/gpt.py,
+# deepseek_v3.py, ouro.py and phi4flash.py all do): everything else in
+# this file is the same scheduler, pager, spans and counters for every
+# family.
 FAMILY_INTERFACE = (
     "check_serving",            # raise, by name, for unbuilt compositions
     "shard_params_for_serving", "kv_pool_spec",
@@ -109,7 +110,24 @@ FAMILY_INTERFACE = (
 # (a family whose ``decode_paged`` returns counts beside the logits also
 # gives ``decode_extra_stats(cfg, flat) -> {counter: increment}``; one
 # whose decode kernel's grid is (slot, page group) by a rule of shapes
-# gives ``decode_group_pages(cfg, pools, table_width, tp) -> G``)
+# gives ``decode_group_pages(cfg, pools, table_width, tp) -> G``; one
+# that keeps state per SLOT beside its pages — a recurrent layer's
+# state, a window's ring — gives ``slot_state_arrays(cfg) -> n``: the
+# last n arrays of ``init_paged_pools``' tuple are indexed by slot, not
+# by page.  Such a family is handed what it needs to keep them:
+# ``init_paged_pools(..., slots=)``, the rows' slot ids in the prefill
+# program, ``prefill_paged(..., slots=)`` (a pad row: an id past the
+# slots), which must overwrite the state of those slots — that is the
+# reset of a slot given to a new request — and the slot and the true
+# length of a chunk, ``chunk_paged(..., slot=, take=)``, which returns
+# the last true row's logits [V] alone; its decode leaves the state of a
+# slot with ``lens == 0`` as it is.  Page copies move the arrays before
+# those n only, ``stats()`` reports the n arrays' bytes as
+# ``slot_state_bytes``, and ``slot_state(slot)`` hands a reference check
+# the family's ``slot_state_of(cfg, pools, slot)``.  A family whose
+# ``prefill_paged`` returns counts behind the pools gives
+# ``prefill_extra_stats(cfg, flat) -> {counter: increment}``: they ride
+# the first tokens' readback, as decode's do)
 
 
 def family_of(cfg):
@@ -182,6 +200,9 @@ def _stats_family():
         # Pallas paged-attention kernel instantiations, fp and int8
         # pools alike (same trace-time meaning; 0 off-TPU)
         "paged_kernel_calls": 0,
+        # the paged differential-attention kernel, likewise (the
+        # phi4flash family: one a window, full and cross layer kind)
+        "paged_diff_kernel_calls": 0,
         # the experts' grouped-matmul kernel, likewise (the deepseek_v3
         # family: two an expert layer a program — gate-up, down)
         "grouped_matmul_kernel_calls": 0,
@@ -1329,6 +1350,7 @@ class ServingEngine:
         self._counts[key] = self._counts.get(key, 0) + v
 
     _KERNEL_COUNTERS = ("dequant_kernel_calls", "paged_kernel_calls",
+                        "paged_diff_kernel_calls",
                         "grouped_matmul_kernel_calls")
 
     def stats(self):
@@ -1705,9 +1727,16 @@ class PagedServingEngine(ServingEngine):
             self._host_tier = _HostKVTier(
                 int(self._host_tier_mb * (1 << 20)),
                 hash_key=self._pager.hash_key)
+        # a family that keeps state per slot says how many arrays at the
+        # end of its tuple are indexed by slot (FAMILY_INTERFACE)
+        count = getattr(self._family, "slot_state_arrays", None)
+        self._n_slot_state = int(count(self.cfg)) if count else 0
         self._pools = pools = tuple(self._family.init_paged_pools(
             self.cfg, self._num_pages, ps, dtype=self._cache_dtype,
-            mesh=self._mesh, kv_quant=self._kv_quant))
+            mesh=self._mesh, kv_quant=self._kv_quant,
+            **({"slots": self.slots} if self._n_slot_state else {})))
+        self._pager.slot_state_bytes = sum(
+            int(a.nbytes) for a in self._slot_state())
         if self._kv_quant and not self._kv_saved_counted:
             # bytes the int8+scale pool saves vs the SAME pool at
             # the compute dtype (what a rebuild without kv_dtype
@@ -1746,6 +1775,18 @@ class PagedServingEngine(ServingEngine):
     @property
     def _n_cache(self):
         return len(self._pools)
+
+    @property
+    def _n_paged(self):
+        """How many arrays of the tuple are indexed by page: the first
+        ones; the rest are indexed by slot."""
+        return len(self._pools) - self._n_slot_state
+
+    def _page_pools(self):
+        return self._pools[:self._n_paged]
+
+    def _slot_state(self):
+        return self._pools[self._n_paged:]
 
     def _chunk_eligible(self, req):
         return (self._prefill_chunk is not None
@@ -1942,10 +1983,12 @@ class PagedServingEngine(ServingEngine):
     def _build_prefill(self, b, s):
         """Paged prefill executable: the family's causal forward over
         the padded prompts, writing the DONATED pool through the page
-        tables (``prefill_paged`` of models/gpt.py or
-        models/deepseek_v3.py), then the first token of each row — as
-        an output the host reads back, and scattered into the token
-        vector the next program takes (the program's last output)."""
+        tables (the family's ``prefill_paged``; one that keeps state
+        per slot is also handed the rows' slot ids, FAMILY_INTERFACE),
+        then the first token of each row — as an output the host reads
+        back (a family's per-wave counts behind them), and scattered
+        into the token vector the next program takes (the program's
+        last output)."""
         jax, jnp = self._jax, self._jnp
         cfg = self.cfg
         ps = self._page_size
@@ -1974,17 +2017,27 @@ class PagedServingEngine(ServingEngine):
 
         n = self._n_cache
         family = self._family
+        per_slot = self._n_slot_state > 0
 
         def prefill(params, *args):
             tokens, lens, ptab, *chain = args[n:]
-            last, out_cache = family.prefill_paged(
-                params, cfg, args[:n], tokens, lens, ptab)
+            if per_slot and not chain:
+                raise ValueError(
+                    f"{family.__name__} keeps state per slot: its wave "
+                    "cannot be lowered without the rows' slot ids")
+            last, out_cache, *extra = family.prefill_paged(
+                params, cfg, args[:n], tokens, lens, ptab,
+                **({"slots": chain[1]} if per_slot else {}))
             out_cache = self._constrain_cache(out_cache)
             with jax.named_scope("head_sample"):
-                first_tok = jnp.argmax(last, -1).astype(jnp.int32)
+                first_tok = back = jnp.argmax(last, -1).astype(jnp.int32)
                 chained = self._scatter_first(first_tok, chain)
-            return (*out_cache, first_tok, *((last,) if cap else ()),
-                    *chained)
+                if extra:
+                    # what the family counts a wave rides the first
+                    # tokens' readback, as a decode step's counts do
+                    back = jnp.concatenate(
+                        [first_tok, extra[0].reshape(-1).astype(jnp.int32)])
+            return (*out_cache, back, *((last,) if cap else ()), *chained)
 
         donate = tuple(range(1, 1 + n)) if _donation_enabled() else ()
         return self._jax.jit(prefill, donate_argnums=donate)
@@ -2045,7 +2098,8 @@ class PagedServingEngine(ServingEngine):
         s = req.slot
         operands = (self.params, *self._cache_operands(),
                     jnp.asarray(toks), jnp.asarray(self._tables_np[s]),
-                    np.int32(pos), np.int32(take))
+                    np.int32(pos), np.int32(take),
+                    *((np.int32(s),) if self._n_slot_state else ()))
         if self._chunk_jit is None:
             donate = self._donate()
             self._chunk_jit = self._chunk_site.get(
@@ -2101,19 +2155,28 @@ class PagedServingEngine(ServingEngine):
         traced scalars, so chunk index never changes the signature.
         The family's ``chunk_paged`` is the program (GPT's int8 pool:
         dequantized gather view in, quantized chunk-only scatter
-        out)."""
+        out); one that keeps state per slot is handed the slot and the
+        chunk's true length, and returns that row's logits alone
+        (FAMILY_INTERFACE)."""
         jax, jnp = self._jax, self._jnp
         cfg = self.cfg
         cap = self.capture_logits
         n = self._n_cache
         family = self._family
 
+        per_slot = self._n_slot_state > 0
+
         def chunk(params, *args):
-            toks, ptab_row, offset, tlen = args[n:]
-            logits, cache = family.chunk_paged(
-                params, cfg, args[:n], toks, ptab_row, offset)
-            last = jax.lax.dynamic_index_in_dim(logits[0], tlen - 1, 0,
-                                                keepdims=False)    # [V]
+            toks, ptab_row, offset, tlen, *slot = args[n:]
+            if per_slot:
+                last, cache = family.chunk_paged(
+                    params, cfg, args[:n], toks, ptab_row, offset,
+                    slot=slot[0], take=tlen)
+            else:
+                logits, cache = family.chunk_paged(
+                    params, cfg, args[:n], toks, ptab_row, offset)
+                last = jax.lax.dynamic_index_in_dim(
+                    logits[0], tlen - 1, 0, keepdims=False)        # [V]
             tok = jnp.argmax(last, -1).astype(jnp.int32)
             cache = self._constrain_cache(cache)
             if cap:
@@ -2157,10 +2220,14 @@ class PagedServingEngine(ServingEngine):
     def _build_copy(self):
         jax = self._jax
 
+        pages = self._n_paged
+
         def cp(*args):
             arrs, (src, dst) = args[:-2], args[-2:]
+            # a page's bytes move; what a slot owns stays where it is
             return self._constrain_cache(
-                tuple(a.at[:, dst].set(a[:, src]) for a in arrs))
+                tuple(a.at[:, dst].set(a[:, src]) for a in arrs[:pages])
+                + arrs[pages:])
 
         donate = (tuple(range(self._n_cache))
                   if _donation_enabled() else ())
@@ -2724,6 +2791,12 @@ class PagedServingEngine(ServingEngine):
                 self._commit_decode(rec, toks_np, logits_np, dt)
 
     def _commit_wave(self, rec, first_np, logits_np):
+        if first_np.shape[0] > rec.attrs["batch"]:
+            # the family's per-wave counts, behind the first tokens
+            extra = self._family.prefill_extra_stats(
+                self.cfg, first_np[rec.attrs["batch"]:])
+            for k, v in extra.items():
+                self._inc(k, v)
         for r, req in rec.rows:
             tok = int(first_np[r])
             self._append_token(req, tok, None if logits_np is None
@@ -2857,7 +2930,26 @@ class PagedServingEngine(ServingEngine):
         else:
             self._retire(before=self._step_idx)
 
+    def slot_state(self, slot):
+        """For a check against a reference, off the serving path: how
+        many positions of ``slot``'s request have been folded into what
+        a family keeps per slot, and that state as host arrays (the
+        family's ``slot_state_of(cfg, pools, slot) -> {name: array}``).
+        The host's view is made whole first, so the request's prompt
+        and tokens hold those positions and one token more — unless
+        that token ended the request, which then no longer has the
+        slot."""
+        self._drain("slot_state")
+        state = self._family.slot_state_of(self.cfg, self._cache_operands(),
+                                           slot)
+        return int(self._lens[slot]), self._jax.device_get(state)
+
     def _build_decode(self):
+        """The one decode executable: the family's ``decode_paged`` over
+        every slot, the whole tuple of ``init_paged_pools`` DONATED and
+        updated where it lies — pages and, for a family that keeps state
+        per slot, the slot arrays behind them (a slot the step does not
+        run has ``lens == 0`` and keeps its state)."""
         jax, jnp = self._jax, self._jnp
         cfg = self.cfg
         cap = self.capture_logits
@@ -3022,7 +3114,7 @@ class PagedServingEngine(ServingEngine):
         halves of that pair count (a page without its scales is not a
         page)."""
         ps = self._page_size
-        total = sum(int(a.nbytes) for a in self._cache_operands())
+        total = sum(int(a.nbytes) for a in self._page_pools())
         page_bytes = total // self._num_pages
         in_use = self._pager.pages_in_use()
         held = int(self._lens.sum()) + sum(

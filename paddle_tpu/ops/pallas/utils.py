@@ -42,6 +42,14 @@ def count_paged_kernel():
     metrics.counter("serving.paged_kernel_calls").inc()
 
 
+def count_paged_diff_kernel():
+    """Trace-time engagement counter of the paged differential-attention
+    kernel (``serving.paged_diff_kernel_calls``): one a kernel instance
+    a compiled executable, as :func:`count_paged_kernel`'s."""
+    from ...observability import metrics
+    metrics.counter("serving.paged_diff_kernel_calls").inc()
+
+
 def count_grouped_matmul_kernel():
     """Trace-time engagement counter of the experts' grouped-matmul
     Pallas kernel (``serving.grouped_matmul_kernel_calls``): one a

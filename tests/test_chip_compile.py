@@ -464,3 +464,101 @@ def test_ouro_programs_fit_the_rule_and_hold_the_pool_in_place(
                   "loop_norm", "exit_gate",
                   "kv_write" if program == "decode" else "kv_scatter"):
         assert scope in text, scope
+
+
+# --------------------------------------------------------------------------
+# the hybrid family (models/phi4flash.py) at the benchmark cell's sizes
+# (benchmark/configs/phi4-mini-flash-serve.json): a one-layer page pool
+# beside the slots' own rings, states and convolution tails, slots and
+# pages by the sizing rule
+# --------------------------------------------------------------------------
+
+def test_paged_diff_attention_kernel(chip):
+    """The differential decode kernel alone, over the pool (a table of
+    64 pages a slot) and over the rings (a fixed table of 8)."""
+    from paddle_tpu.ops.pallas import paged_diff_attn as pda
+    slots = 176
+    for layers, pages, width in ((1, 4562, 64), (8, slots * 8, 8)):
+        pool = chip((layers, pages, 64, 1280), bf16)
+        assert compiles(
+            lambda q, k, v, table, lens, layer, lam: pda._paged_diff_call(
+                q, (k, v), table, lens, layer, lam),
+            chip((slots, 40, 64), bf16), pool, pool,
+            chip((slots, width), i32), chip((slots,), i32), chip((), i32),
+            chip((), f32)) == 1
+
+
+@pytest.fixture
+def served_phi4flash(chip, monkeypatch):
+    import json
+    from paddle_tpu.inference.serving import PagedServingEngine
+    from paddle_tpu.models import phi4flash
+    from paddle_tpu.ops.pallas import utils as pallas_utils
+    monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
+    monkeypatch.setenv("PADDLE_TPU_SERVING_DONATE", "1")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "phi4-mini-flash-serve.json")) as f:
+        arch = json.load(f)
+    fields = phi4flash.Phi4FlashConfig.__dataclass_fields__
+    cfg = phi4flash.Phi4FlashConfig(
+        **{k: v for k, v in arch.items() if k in fields})
+    params = jax.tree_util.tree_map(
+        lambda x: chip(x.shape, x.dtype),
+        jax.eval_shape(lambda k: phi4flash.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    e = arch["engine"]
+    # a small engine: its programs take their shapes from their operands
+    eng = PagedServingEngine(
+        (params, cfg), capture_logits=False,
+        **dict(e, slots=8, num_pages=2, seq_buckets=tuple(e["seq_buckets"]),
+               batch_buckets=tuple(e["batch_buckets"])))
+    pools = (*(chip(s, bf16) for s in phi4flash.paged_pool_shapes(
+        cfg, e["num_pages"], e["page_size"])),
+        *(chip(s, d) for s, d in phi4flash.slot_state_shapes(
+            cfg, e["slots"], e["page_size"])))
+    return eng, params, pools, e
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_4x1024"])
+def test_phi4flash_programs_fit_the_rule_and_hold_the_state_in_place(
+        chip, served_phi4flash, program):
+    from paddle_tpu.inference.serving import pool_relayouts
+    eng, params, pools, e = served_phi4flash
+    slots, ps = e["slots"], e["page_size"]
+    assert [p.shape for p in pools] == [
+        (1, e["num_pages"], 64, 1280)] * 2 + [(8, slots * 8, 64, 1280)] * 2 \
+        + [(9, slots, 5120, 16), (9, slots, 3, 5120)]
+    if program == "decode":
+        fn = eng._build_decode()
+        args = (chip((slots, e["max_len"] // ps), i32),
+                *[chip((slots,), i32)] * 4)
+    else:
+        fn = eng._build_prefill(4, 1024)
+        args = (chip((4, 1024), i32), chip((4,), i32),
+                chip((4, 1024 // ps), i32), chip((slots,), i32),
+                chip((4,), i32))
+    compiled = fn.lower(params, *pools, *args).compile()
+    text = compiled.as_text()
+    # the pool and the rings enter as they are stored and are not copied
+    assert pool_relayouts(text, pools[:2]) == []
+    assert pool_relayouts(text, pools[2:4]) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools)
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total <= KANANA_BUDGET, total / 2 ** 30
+    if program == "decode":
+        # no copy of a slot array, a ring or a stack of weights: the
+        # float32 state of ONE layer is 55 MiB
+        assert mem.temp_size_in_bytes < (64 << 20)
+        # one kernel in each scan's body and one for the full layer
+        assert text.count("tpu_custom_call") == 3
+        assert "paged_diff_attn_decode" in text
+    scopes = ["mlp", "window_attn", "full_attn", "cross_attn", "gmu",
+              "kv_write", "head_sample"]
+    scopes += (["ssm_step", "paged_diff_attn"] if program == "decode"
+               else ["ssm_scan", "state_reset"])
+    for scope in scopes:
+        assert scope in text, scope
