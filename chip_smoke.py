@@ -452,7 +452,7 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
         "matmul": ("pallas_dequant" if engaged["dequant_matmul"]
                    else "xla"),
         "kernel_instances": engaged,
-        # (None for a family whose kernel is not paged_attn_decode)
+        # (None for a family that gives no ``decode_group_pages``)
         "paged_attn_group_pages": st.get("paged_attn_group_pages"),
         "paged_attn_live_step_share": (None if None in live_share
                                        else max(live_share)),

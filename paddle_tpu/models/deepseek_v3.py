@@ -474,6 +474,18 @@ def kv_bytes_per_position(cfg, itemsize):
             * (cfg.kv_lora_rank + cfg.qk_rope_head_dim) * itemsize)
 
 
+def decode_group_pages(cfg, pools, table_width, tp=1):
+    """Pages a grid step of the decode kernel takes over these pools
+    (ops/pallas/paged_mla.py::group_pages; a cached row is ``c`` and
+    the rope lane row side by side, and every head meets it: 'tp' does
+    not split it)."""
+    from ..ops.pallas.paged_mla import group_pages
+    c_pool, r_pool = pools[:2]
+    return group_pages(table_width, c_pool.shape[2],
+                       c_pool.shape[3] + r_pool.shape[3],
+                       c_pool.dtype.itemsize, cfg.num_heads)
+
+
 def decode_extra_stats(cfg, flat):
     """The engine's counters from what :func:`decode_paged` returned
     beside the logits (host side, numpy): ``flat`` is the expert

@@ -290,11 +290,14 @@ KANANA_SLOTS, KANANA_PS, KANANA_PAGES, KANANA_LAYERS = 64, 64, 1017, 8
 KANANA_BUDGET = int(16_909_336_064 * 0.9)
 
 
-def test_paged_mla_decode_kernel(chip):
-    """The latent kernel alone at 64 slots x 32 pages of 64: 32 heads
-    against one shared 512 + 64 wide key."""
+@pytest.mark.parametrize("maxp", [MAX_LEN // KANANA_PS, 6],
+                         ids=["table-32", "table-6"])
+def test_paged_mla_decode_kernel(chip, maxp):
+    """The latent kernel alone at 64 slots x pages of 64: 32 heads
+    against one shared 512 + 64 wide key, both pools handed over whole
+    and copied by the kernel; at the cell's table (32 wide) and at a
+    narrow one (6: two pages a step)."""
     from paddle_tpu.ops.pallas.paged_mla import _paged_mla_tpu
-    maxp = MAX_LEN // KANANA_PS
     fn = functools.partial(_paged_mla_tpu, scale=192 ** -0.5)
     text = jax.jit(fn).lower(
         chip((KANANA_SLOTS, 32, 512), bf16), chip((KANANA_SLOTS, 32, 64), bf16),

@@ -227,36 +227,141 @@ class TestRouting:
             assert np.abs(np.asarray(y) - np.asarray(want)).max() < TOL
 
 
+def _kernel_operands(dtype, lens, ps, maxP, layers=2, seed=0):
+    """Seeded operands of the latent kernel for ``lens``: 4 heads, rank
+    32, rope 8.  Each slot's live table entries name distinct pages;
+    every entry past them holds a page id far out of range, and the
+    pool's LAST page — where an out-of-range index lands when it is
+    clamped — is NaN: a kernel that dereferences a dead entry returns
+    NaN (0 x NaN in the weighted sum)."""
+    rng = np.random.default_rng(seed)
+    S, nh, rank, rope = len(lens), 4, 32, 8
+    P = S * maxP + 2
+    dt = jnp.dtype(dtype)
+    qa = jnp.asarray(rng.normal(size=(S, nh, rank)), dt)
+    qr = jnp.asarray(rng.normal(size=(S, nh, rope)), dt)
+    cp = rng.normal(size=(layers, P, ps, rank))
+    rp = np.zeros((layers, P, ps, 128))
+    rp[..., :rope] = rng.normal(size=(layers, P, ps, rope))
+    cp[:, -1] = rp[:, -1] = np.nan
+    free = rng.permutation(np.arange(1, P - 1))[:S * maxP].reshape(S, maxP)
+    live = np.asarray(lens)[:, None] // ps >= np.arange(maxP)[None, :]
+    pt = np.where(live, free, 10 ** 6)
+    return (qa, qr, jnp.asarray(cp, dt), jnp.asarray(rp, dt),
+            jnp.asarray(pt, jnp.int32), jnp.asarray(lens, jnp.int32))
+
+
+def _assert_kernel_matches(dtype, operands):
+    """float32: kernel and fallback differ by summation order only
+    (4e-7 read); bf16 pools: the kernel's products are exact and both
+    round the output to bf16 once — one bf16 step of an O(1) value,
+    8e-3."""
+    qa, qr, cp, rp, pt, lens = operands
+    # the fallback gathers every entry of the table: hand it the dead
+    # ones as page 0 (masked there), the kernel as they are
+    seen = jnp.where(pt < cp.shape[1], pt, 0)
+    for layer in range(cp.shape[0]):
+        want = paged_mla._ref_paged_mla(qa, qr, cp[layer], rp[layer],
+                                        seen, lens, 0.2)
+        got = paged_mla._paged_mla_tpu(qa, qr, cp, rp, pt, lens,
+                                       jnp.int32(layer), 0.2,
+                                       interpret=True)
+        err = jnp.abs(got.astype(jnp.float32)
+                      - want.astype(jnp.float32)).max()
+        assert float(err) < (2e-6 if dtype == "float32" else 8e-3)
+
+
+# a step of 32 rows (two pages of 16) over a table of 8: four groups a
+# slot; the new token sits AT lens, so rows 0..lens are live
+_R, _VIEW = 32, 128
+MOVER_CASES = {
+    "every-slot-empty": [0, 0, 0],
+    "under-at-over-a-group-boundary": [_R - 1, _R, _R + 1],
+    "under-at-over-a-page-boundary": [14, 15, 16],
+    "partial-last-group-beside-a-full-table": [_R + 5, _VIEW - 1, 3],
+    "next-slot-prefetch-across-an-empty-slot": [2 * _R + 1, 0, _R],
+    "full-tables-only": [_VIEW - 1, _VIEW - 1, _VIEW - 1],
+    "last-group-reached-by-one-row": [3 * _R, 1, 3 * _R - 1],
+}
+
+
+class _Always:
+    """A lengths ref that reads ``value`` for every slot."""
+    def __init__(self, ref, value):
+        self._ref, self._value = ref, value
+
+    def __getitem__(self, idx):
+        return self._ref[idx] * 0 + self._value
+
+
 class TestKernel:
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     def test_interpret_mode_matches_the_fallback(self, dtype):
-        """float32: the two differ by summation order only (4e-7 read);
-        bf16 pools: the kernel's products are exact and both round the
-        output to bf16 once — one bf16 step of an O(1) value, 8e-3."""
-        rng = np.random.default_rng(0)
-        S, nh, rank, rope, ps, P, maxP, L = 3, 4, 32, 8, 8, 30, 8, 2
-        dt = jnp.dtype(dtype)
-        qa = jnp.asarray(rng.normal(size=(S, nh, rank)), dt)
-        qr = jnp.asarray(rng.normal(size=(S, nh, rope)), dt)
-        cp = jnp.asarray(rng.normal(size=(L, P, ps, rank)), dt)
-        rp = np.zeros((L, P, ps, 128), np.float32)
-        rp[..., :rope] = rng.normal(size=(L, P, ps, rope))
-        rp = jnp.asarray(rp, dt)
-        pt = jnp.asarray(rng.permutation(np.arange(1, P))[:S * maxP]
-                         .reshape(S, maxP), jnp.int32)
-        lens = jnp.asarray([0, 13, 61], jnp.int32)   # 1, 2 and 8 pages
-        for layer in range(L):
-            want = paged_mla._ref_paged_mla(qa, qr, cp[layer], rp[layer],
-                                            pt, lens, 0.2)
-            got = paged_mla._paged_mla_tpu(qa, qr, cp, rp, pt, lens,
-                                           jnp.int32(layer), 0.2,
-                                           interpret=True)
-            err = jnp.abs(got.astype(jnp.float32)
-                          - want.astype(jnp.float32)).max()
-            assert float(err) < (2e-6 if dtype == "float32" else 8e-3)
+        """The rule's own pick at these shapes: the whole table of
+        8-row float32 pages in one step, a bf16 page of 8 rows (half a
+        packed tile) one a step."""
+        ops = _kernel_operands(dtype, [0, 13, 61], ps=8, maxP=8)
+        _assert_kernel_matches(dtype, ops)
 
-    def test_group_divides_any_table_width(self):
-        assert [paged_mla._group_of(n) for n in (32, 12, 6, 5)] == [8, 4, 2, 1]
+    @pytest.mark.parametrize("case", sorted(MOVER_CASES))
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_the_kernel_copies_its_live_pages_only(self, dtype, case,
+                                                   monkeypatch):
+        """What the page mover can get wrong, two layers each: which
+        pages of a group are live, which step is next (the slot's next
+        group or the next slot's first), which half a step reads, and
+        that no dead table entry is followed."""
+        monkeypatch.setattr(paged_mla, "GROUP_ROWS", _R)
+        ops = _kernel_operands(dtype, MOVER_CASES[case], ps=16, maxP=8)
+        assert paged_mla.group_pages(8, 16, 32 + 128, jnp.dtype(
+            dtype).itemsize, 4) == 2
+        _assert_kernel_matches(dtype, ops)
+
+    def test_a_dead_entry_followed_would_show(self, monkeypatch):
+        """The guard of the test above: the same operands through a
+        mover that copies every page of a group gives NaN."""
+        monkeypatch.setattr(paged_mla, "GROUP_ROWS", _R)
+        qa, qr, cp, rp, pt, lens = _kernel_operands(
+            "float32", [_R + 5, 3, 0], ps=16, maxP=8)
+        real = paged_mla.page_mover
+
+        def greedy(pt_ref, lens_ref, *a, **kw):
+            return real(pt_ref, _Always(lens_ref, _VIEW - 1), *a, **kw)
+
+        monkeypatch.setattr(paged_mla, "page_mover", greedy)
+        got = paged_mla._paged_mla_tpu(qa, qr, cp, rp, pt, lens,
+                                       jnp.int32(0), 0.2, interpret=True)
+        assert bool(jnp.isnan(got).any())
+
+    @pytest.mark.parametrize("width", [32, 16, 12, 6, 5, 24, 1])
+    def test_group_is_a_rule_of_shapes(self, width):
+        """Shapes in, G out: a power of two that divides the table's
+        width and keeps a step's rows at or under ``GROUP_ROWS``; a page
+        that is not whole packed tiles of its dtype goes one a step."""
+        row = 512 + 128
+        for ps, itemsize in ((64, 2), (16, 2), (8, 4), (16, 4), (128, 2)):
+            g = paged_mla.group_pages(width, ps, row, itemsize, 32)
+            assert g >= 1 and g & (g - 1) == 0 and width % g == 0
+            assert g == 1 or g * ps <= paged_mla.GROUP_ROWS
+            # the largest such: twice as many would break a condition
+            assert (width % (2 * g) or 2 * g * ps > paged_mla.GROUP_ROWS)
+        assert paged_mla.group_pages(width, 8, row, 2, 32) == 1
+        assert paged_mla.group_pages(width, 4, row, 4, 32) == 1
+
+    def test_group_at_the_serving_cell(self):
+        """64 slots x 32 pages of 64 bf16 rows of 640: the pick of the
+        chip's sweep (PERF.md section 6, PR 36)."""
+        assert paged_mla.GROUP_ROWS == 1024
+        assert paged_mla.group_pages(32, 64, 640, 2, 32) == 16
+        assert paged_mla.group_pages(6, 64, 640, 2, 32) == 2
+
+    def test_narrow_rank_falls_back(self, monkeypatch):
+        """A copy out of HBM takes whole 128-lane rows: a latent rank
+        that is not stays with the fallback on the chip."""
+        monkeypatch.setattr(paged_mla, "pallas_enabled", lambda: True)
+        pool = lambda w: jnp.zeros((1, 4, 16, w), jnp.bfloat16)
+        assert paged_mla._use_pallas_mla(pool(512), pool(128), 32)
+        assert not paged_mla._use_pallas_mla(pool(32), pool(128), 4)
 
 
 class TestPagedEngine:
@@ -281,6 +386,37 @@ class TestPagedEngine:
         assert st["moe_max_expert_load"] >= st["decode_steps"] * 2
         assert st["kv_bytes_per_position"] == 3 * (32 + 8) * 4
         assert st["pages_in_use"] == 0
+
+    def test_stats_report_the_decode_kernels_grid(self, tiny):
+        """The family's ``decode_group_pages`` is all ``stats()`` needs:
+        G by the latent kernel's rule of shapes, and the share of
+        (slot, group) grid steps that are live — here the whole table
+        of 8 pages is one step, so a live step an active slot."""
+        params, cfg = tiny
+        eng = _engine(tiny)
+        assert "paged_attn_group_pages" in eng.stats()
+        for i, n in enumerate((5, 20)):
+            eng.submit(_tokens(70 + i, n), 30)
+        for _ in range(4):
+            eng.step()
+        st = eng.stats()
+        pools = ds.init_paged_pools(cfg, 25, 8)
+        assert st["paged_attn_group_pages"] == ds.decode_group_pages(
+            cfg, pools, 8) == paged_mla.group_pages(
+                8, 8, cfg.kv_lora_rank + 128, 4, cfg.num_heads) == 8
+        assert st["paged_attn_live_step_share"] == round(2 / 3, 4)
+        eng.run(max_steps=200)
+        assert eng.stats()["paged_attn_live_step_share"] == 0.0
+
+    @pytest.mark.parametrize("page_size,width,group", [(8, 8, 8), (16, 4, 4),
+                                                       (4, 16, 1)])
+    def test_group_pages_follow_the_engines_shapes(self, tiny, page_size,
+                                                   width, group):
+        """Page size and table width in, G out, through the engine: 4
+        rows of float32 are half a packed tile and go one a step."""
+        eng = _engine(tiny, page_size=page_size, num_pages=200 // page_size)
+        assert eng.stats()["paged_attn_group_pages"] == group
+        assert eng._pages_per_slot == width
 
     def test_after_a_preemption(self, tiny):
         params, cfg = tiny
@@ -341,7 +477,7 @@ class TestPagedEngine:
     def test_through_the_kernel_in_interpret_mode(self, tiny, monkeypatch):
         """The engine's decode step with the Pallas kernel in it."""
         params, cfg = tiny
-        monkeypatch.setattr(paged_mla, "pallas_enabled", lambda: True)
+        monkeypatch.setattr(paged_mla, "_use_pallas_mla", lambda *a: True)
         monkeypatch.setattr(paged_mla, "_paged_mla_tpu", functools.partial(
             paged_mla._paged_mla_tpu, interpret=True))
         eng = _engine(tiny, slots=2)
