@@ -20,7 +20,11 @@ seeded random weights:
   same engine over its latent page pool, against its own plain float32
   reference; then the ``ouro`` family (layers looped over shared
   weights) at a TINY size, for its gate's counters ``loop_tokens`` /
-  ``loop_passes``.
+  ``loop_passes``; then the ``phi4flash`` family (state per slot beside
+  a one-layer pool) at a TINY size, for the rows its prefill waves ran,
+  counted by the host (``prefill_padded_rows``) and by the wave's own
+  program (``prefill_rows``).  Every serve phase prints
+  ``prefill_by_bucket``: what its waves were given and paid for.
 
 It checks what comes out (falling finite loss, Pallas flash against XLA
 attention, engine logits against the float32 model) and that the Pallas
@@ -434,12 +438,33 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
             "must build nothing")
     if st["prefix_page_hits"] < 1:
         raise AssertionError("repeated prompts hit no cached prefix page")
-    if run.on_chip and any(relayouts.values()):
+    # (the tiny hybrid's pool rows are 32 lanes wide: the compiler tiles
+    # them into 128 by a copy that no served width needs)
+    if run.on_chip and pool != "hybrid" and any(relayouts.values()):
         raise AssertionError(
             f"the compiled programs copy the KV pool: {relayouts}")
+    # the operator's view of prefill: the table's sums are the two
+    # counters, and where the wave's own program counts its rows too
+    # (the hybrid family), the host's count is the device's
+    table = st["prefill_by_bucket"]
+    summed = {k: sum(row[k] for row in table.values())
+              for k in ("waves", "tokens", "rows")}
+    counted = {"waves": st["prefill_calls"],
+               "tokens": st["prefill_tokens"],
+               "rows": st["prefill_padded_rows"]}
+    if summed != counted:
+        raise AssertionError(
+            f"prefill_by_bucket sums to {summed}, the counters read "
+            f"{counted}")
+    if st.get("prefill_rows", counted["rows"]) != counted["rows"]:
+        raise AssertionError(
+            f"the host counts {counted['rows']} prefill rows, the waves' "
+            f"own programs {st['prefill_rows']}")
     if run.on_chip:
-        need = ["paged"] + {"int8": ["dequant_matmul"],
-                            "latent": ["grouped_matmul"]}.get(pool, [])
+        # (the tiny hybrid's rows are narrower than a kernel's 128 lanes)
+        need = {"int8": ["paged", "dequant_matmul"],
+                "latent": ["paged", "grouped_matmul"],
+                "hybrid": []}.get(pool, ["paged"])
         gave_way = [k for k in need if engaged[k] < 1]
         if gave_way:
             raise AssertionError(
@@ -471,6 +496,15 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
         "drains": st["drains"],
         "prefix_page_hits": st["prefix_page_hits"],
         "preemptions": st["preemptions"],
+        # what the waves were given and what they paid for, by bucket
+        # (device_s: a smoke reading, as every time here), and the
+        # device's own count of the rows (None: the family gives none)
+        "prefill_by_bucket": {
+            k: dict(row, device_s=round(row["device_s"], 5))
+            for k, row in table.items()},
+        "prefill_tokens": counted["tokens"],
+        "prefill_padded_rows": counted["rows"],
+        "prefill_rows": st.get("prefill_rows"),
         # (None for a family whose layers run once)
         "loop_tokens": st.get("loop_tokens"),
         "loop_passes": st.get("loop_passes"),
@@ -764,6 +798,32 @@ def phase_serve_looped(run):
             f"{report['loop_tokens']} tokens at threshold 1")
 
 
+def phase_serve_hybrid(run):
+    """The phi4flash family (state-space, window and shared-cache
+    layers: most of a sequence's state per slot, a one-layer pool) at
+    its TINY size through the same engine, as the looped phase runs:
+    that it serves on this device, and that the rows its waves ran are
+    one number from both sides (``serve_requests`` holds the host's
+    ``prefill_padded_rows`` to the programs' own ``prefill_rows``)."""
+    import jax
+    from paddle_tpu.models import phi4flash
+
+    tiny = Run(REHEARSE, run.devices, run.seed, run.on_chip)
+    cfg = phi4flash.phi4flash_tiny(dtype="bfloat16",
+                                   param_dtype="bfloat16")
+    params = phi4flash.init_params(cfg, jax.random.PRNGKey(run.seed + 5))
+    _, report = serve_requests(tiny, params, cfg, prompts_for(tiny, cfg),
+                               pool="hybrid", capture_logits=False)
+    emit(phase="serve_hybrid", note="smoke, not a measurement",
+         device_kind=run.kind,
+         shape=dict(hidden=cfg.hidden_size, layers=cfg.num_hidden_layers,
+                    window=cfg.sliding_window, vocab=cfg.vocab_size),
+         **report)
+    if not report["prefill_rows"]:
+        raise AssertionError(
+            "serve_hybrid: the waves' programs handed back no row count")
+
+
 # --------------------------------------------------------------------------
 # --chips 4: the mesh phases and what they are compared with, nothing else
 # --------------------------------------------------------------------------
@@ -914,6 +974,7 @@ def main():
         del params
         phase_serve_latent(run)
         phase_serve_looped(run)
+        phase_serve_hybrid(run)
     emit(phase="compile_cache", dir=cache_dir, **run.counters(),
          total_s=round(time.perf_counter() - t0, 1))
     result = {"ok": True,

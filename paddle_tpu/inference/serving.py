@@ -186,6 +186,10 @@ def _stats_family():
         # paged-KV family (PagedServingEngine; zero on slot engines)
         "prefill_chunks": 0, "prefix_page_hits": 0,
         "prefix_page_misses": 0, "cow_copies": 0, "preemptions": 0,
+        # what the committed prefill waves were given (prompt tokens)
+        # and what their programs ran (batch x seq rows, padding
+        # included): the sums of stats()["prefill_by_bucket"]
+        "prefill_tokens": 0, "prefill_padded_rows": 0,
         # decode dispatches made while an earlier program's sampled
         # tokens were still unread: the host ran ahead of the device
         "steps_overlapped": 0,
@@ -845,8 +849,7 @@ class ServingEngine:
                         request_id=req.id, batch=bbucket, seq=sbucket,
                         wait_s=round(
                             time.perf_counter() - req.submit_t, 6))
-            with timeline.span("serving.prefill_operands", batch=bbucket,
-                               seq=sbucket):
+            with timeline.span("serving.prefill_operands"):
                 donate = self._donate()
                 operands = (self.params, self._cache_k, self._cache_v,
                             jnp.asarray(toks), jnp.asarray(lens),
@@ -1325,6 +1328,7 @@ class ServingEngine:
         "prefill_calls", "decode_steps", "requests_admitted",
         "requests_completed", "tokens_generated",
         "prefill_chunks", "prefix_page_hits", "prefix_page_misses",
+        "prefill_tokens", "prefill_padded_rows",
         "cow_copies", "preemptions", "steps_overlapped", "quant_matmuls",
         "moe_assignments", "moe_experts_touched", "moe_max_expert_load",
         "drafted_tokens", "accepted_tokens", "rejected_tokens",
@@ -1483,15 +1487,16 @@ class _HostKVTier:
 class _Dispatched:
     """One program the paged engine has enqueued and not read back: the
     array that holds its sampled tokens (the copy to the host already
-    started), the requests they belong to, and when the enqueue
-    returned."""
+    started), the requests they belong to, when the enqueue returned,
+    and the id of the span it was dispatched under (``span``), which the
+    readback's span names: a program's two spans are one record."""
     __slots__ = ("wave", "step", "toks", "logits", "rows", "t_enq",
-                 "attrs")
+                 "attrs", "span")
 
-    def __init__(self, wave, step, toks, logits, rows, attrs):
+    def __init__(self, wave, step, toks, logits, rows, attrs, span):
         self.wave, self.step = wave, step
         self.toks, self.logits = toks, logits
-        self.rows, self.attrs = rows, attrs
+        self.rows, self.attrs, self.span = rows, attrs, span
         for a in (toks, logits):
             if a is not None:
                 a.copy_to_host_async()
@@ -1603,6 +1608,7 @@ class PagedServingEngine(ServingEngine):
         self._g_host_tier = metrics.gauge("serving.host_tier_bytes")
         self._h_reclaim_age = metrics.histogram(
             "serving.reclaim_hit_age_s")
+        self._h_chunked = metrics.histogram("serving.prefill_chunked_s")
         # prefill/decode disaggregation (ISSUE 15): kv_handoff=True
         # primes the page extract/inject executables at warmup — a
         # prefill-role replica finishes prefill-only requests with
@@ -1639,6 +1645,7 @@ class PagedServingEngine(ServingEngine):
         self._chunk_site = _cc.site("serving.chunk", maxsize=2)
         self._admit_seq = 0
         self._drains = collections.Counter()
+        self._prefill_by_bucket = {}    # "<batch>x<seq>" -> sums, _count_wave
         super().__init__(model, **kw)
         self._kv_dtype = kv_dtype
         if getattr(self, "_kv_saved_pending", None):
@@ -1835,6 +1842,7 @@ class PagedServingEngine(ServingEngine):
                 break               # FIFO: the long head waits for intake
             free = self._free_slots()
             group, tables, sbucket, hits_total = [], [], None, 0
+            hit_tokens = 0
             exhausted = False
             while (self._queue and len(group) < len(free)
                    and len(group) < self.batch_buckets[-1]):
@@ -1860,9 +1868,13 @@ class PagedServingEngine(ServingEngine):
                 group.append(nxt)
                 tables.append(table)
                 hits_total += hits
+                # a prompt's last page may be a part of one: the cache
+                # supplied no more positions than the prompt has
+                hit_tokens += min(hits * self._page_size, len(nxt.prompt))
             if not group:
                 break
-            self._prefill_group(group, tables, sbucket, hits_total)
+            self._prefill_group(group, tables, sbucket, hits_total,
+                                hit_tokens)
             if exhausted:
                 break
         self._g_queue.set(self._queued_total())
@@ -1873,7 +1885,7 @@ class PagedServingEngine(ServingEngine):
             if occ > self._g_occ_peak.value:
                 self._g_occ_peak.set(occ)
 
-    def _prefill_group(self, group, tables, sbucket, hits):
+    def _prefill_group(self, group, tables, sbucket, hits, hit_tokens):
         """Dispatch one wave and admit its requests BY COUNT: slots,
         page tables and lengths are the wave's from here on, and the
         decode step that follows runs them off the first tokens the
@@ -1906,8 +1918,7 @@ class PagedServingEngine(ServingEngine):
                     request_id=req.id, batch=bbucket, seq=sbucket,
                     wait_s=round(
                         time.perf_counter() - req.submit_t, 6))
-        with timeline.span("serving.prefill_operands", batch=bbucket,
-                           seq=sbucket):
+        with timeline.span("serving.prefill_operands"):
             donate = self._donate()
             operands = (self.params, *self._cache_operands(),
                         jnp.asarray(toks), jnp.asarray(lens),
@@ -1919,12 +1930,17 @@ class PagedServingEngine(ServingEngine):
                 lambda: self._build_prefill(bbucket, sbucket),
                 stable_key=self._aot_key("prefill", b=bbucket, s=sbucket),
                 example_args=operands, topology=self._topology())
-        attrs = dict(batch=bbucket, seq=sbucket, paged=True,
+        # what the wave was given (requests, their prompt tokens, the
+        # positions the prefix cache supplied) beside what it pays for:
+        # the program runs batch x seq rows whatever is in them
+        attrs = dict(batch=bbucket, seq=sbucket, requests=len(group),
+                     tokens=sum(len(r.prompt) for r in group),
+                     rows=bbucket * sbucket, hit_tokens=hit_tokens,
                      request_ids=[r.id for r in group])
-        with timeline.span("serving.prefill_wave", **attrs):
+        with timeline.span("serving.prefill_wave", **attrs) as sp:
             with timeline.span("serving.prefill_wave.dispatch"):
                 out = fn(*operands)
-            self._enqueued(True, out, list(enumerate(group)), attrs)
+            self._enqueued(True, out, list(enumerate(group)), attrs, sp.id)
             self._inc("prefill_calls")
             self._count_quant_matmuls()
             for r, req in enumerate(group):
@@ -2110,7 +2126,10 @@ class PagedServingEngine(ServingEngine):
                 example_args=operands, topology=self._topology())
             self._inc("prefill_compiles")
         t0 = time.perf_counter()
-        with timeline.span("serving.prefill_chunk", pos=pos, take=take):
+        # what the chunk was given beside what its program runs: 1 x C
+        # rows whatever the prompt has left
+        with timeline.span("serving.prefill_chunk", pos=pos, tokens=take,
+                           rows=C):
             out = self._chunk_jit(*operands)
         self._set_cache(out[:self._n_cache])
         tok = out[self._n_cache]
@@ -2125,8 +2144,10 @@ class PagedServingEngine(ServingEngine):
                           chunk_s=round(time.perf_counter() - t0, 6),
                           engine=self._engine_id)
         req._chunk_pos = pos + take
-        # the prefill histogram records the WHOLE admission's work, so
-        # accumulate per-chunk durations and observe once at the end
+        # the HOST's wall time around the chunk calls, summed over the
+        # admission and observed once at its end under a name of its own
+        # (``serving.prefill_chunked_s``): ``serving.prefill_s`` holds
+        # one kind of interval, the device's for a wave (_read_back)
         req._chunk_time += time.perf_counter() - t0
         self._pager.register_prompt(s, req._chunk_pos)
         if req._chunk_pos < n:
@@ -2143,7 +2164,7 @@ class PagedServingEngine(ServingEngine):
         self._inc("requests_admitted")
         self._memo_first_token(req)
         if not self._warming:
-            self._h_prefill.observe(req._chunk_time)
+            self._h_chunked.observe(req._chunk_time)
         if _faults.active() and not self._warming:
             _faults.replica_kill_check(
                 request=self._counts["requests_admitted"])
@@ -2729,16 +2750,18 @@ class PagedServingEngine(ServingEngine):
                 self._preempt(victim, str(e))
 
     # ------------------------------------------------ the step in flight
-    def _enqueued(self, wave, out, rows, attrs):
+    def _enqueued(self, wave, out, rows, attrs, span):
         """Take over what a program just enqueued returned: the pool,
         the token vector for the next program (its last output), and
-        the record of the sampled tokens the host has yet to read."""
+        the record of the sampled tokens the host has yet to read,
+        which keeps the id of the span it was dispatched under."""
         n = self._n_cache
         self._set_cache(out[:n])
         self._tok_dev = out[-1]
         self._inflight.append(_Dispatched(
             wave, self._step_idx, out[n],
-            out[n + 1] if self.capture_logits else None, rows, attrs))
+            out[n + 1] if self.capture_logits else None, rows, attrs,
+            span))
 
     def _retire(self, before=None):
         """Read back and commit the programs in flight, oldest first —
@@ -2767,9 +2790,15 @@ class PagedServingEngine(ServingEngine):
         ``serving.decode_step_s``) observe the time the device had the
         program at the head of its queue: from the later of its enqueue
         returning and the previous program's tokens arriving, to its
-        own tokens' arrival."""
+        own tokens' arrival.  The span carries the same interval as
+        ``device_s`` and names the span the program was dispatched
+        under (``dispatch_span``), so over any stretch of steps the
+        ``device_s`` of the wave spans sum to what ``serving.prefill_s``
+        observed and those of the decode spans to
+        ``serving.decode_step_s``'s."""
         name = "serving.prefill_wave" if rec.wave else "serving.decode"
-        with timeline.span(name, **rec.attrs):
+        with timeline.span(name, dispatch_span=rec.span,
+                           **rec.attrs) as sp:
             with timeline.span(name + ".readback"):
                 # ptl: disable-next=PTL004 -- capture_logits debug readback
                 logits_np = (None if rec.logits is None
@@ -2782,17 +2811,42 @@ class PagedServingEngine(ServingEngine):
                 arrived = time.perf_counter()
             dt = arrived - max(rec.t_enq, self._t_arrived)
             self._t_arrived = arrived
+            # the ring's alone: a profiler's trace has the device's line
+            sp.attrs["device_s"] = dt
             if not self._warming:
                 (self._h_prefill if rec.wave else self._h_decode
                  ).observe(dt)
+                if rec.wave:
+                    self._count_wave(rec.attrs, dt)
             if rec.wave:
                 self._commit_wave(rec, toks_np, logits_np)
             else:
                 self._commit_decode(rec, toks_np, logits_np, dt)
 
+    def _count_wave(self, attrs, dt):
+        """The operator's view of prefill, summed where a wave is
+        committed (a preempted request's second prefill is paid again
+        and counts again; :meth:`_read_back` leaves warm-up's waves
+        out, as it does for the histograms):
+        ``stats()["prefill_by_bucket"]`` and the two totals beside it,
+        the prompt tokens the waves were given and the rows (batch x
+        seq, padding included) their programs ran."""
+        self._inc("prefill_tokens", attrs["tokens"])
+        self._inc("prefill_padded_rows", attrs["rows"])
+        row = self._prefill_by_bucket.setdefault(
+            f"{attrs['batch']}x{attrs['seq']}",
+            dict(waves=0, requests=0, tokens=0, rows=0, device_s=0.0))
+        row["waves"] += 1
+        row["requests"] += attrs["requests"]
+        row["tokens"] += attrs["tokens"]
+        row["rows"] += attrs["rows"]
+        row["device_s"] += dt
+
     def _commit_wave(self, rec, first_np, logits_np):
-        if first_np.shape[0] > rec.attrs["batch"]:
+        if first_np.shape[0] > rec.attrs["batch"] and not self._warming:
             # the family's per-wave counts, behind the first tokens
+            # (traffic counters: a warm-up wave counts nothing, here as
+            # in _count_wave, so the two sides' rows can be compared)
             extra = self._family.prefill_extra_stats(
                 self.cfg, first_np[rec.attrs["batch"]:])
             for k, v in extra.items():
@@ -2810,7 +2864,7 @@ class PagedServingEngine(ServingEngine):
             self._maybe_finish_prefill_only(req)
 
     def _commit_decode(self, rec, nxt_np, logits_np, dt):
-        if nxt_np.shape[0] > self.slots:
+        if nxt_np.shape[0] > self.slots and not self._warming:
             # the family's per-step counts, behind the tokens
             extra = self._family.decode_extra_stats(
                 self.cfg, nxt_np[self.slots:])
@@ -2912,15 +2966,16 @@ class PagedServingEngine(ServingEngine):
                     stable_key=self._aot_key("decode"),
                     example_args=operands, topology=self._topology())
                 self._inc("decode_compiles")
-        attrs = dict(active=int(run.sum()), paged=True)
-        with timeline.span("serving.decode", **attrs):
+        attrs = dict(active=int(run.sum()))
+        with timeline.span("serving.decode", **attrs) as sp:
             with timeline.span("serving.decode.dispatch"):
                 out = self._decode_jit(*operands)
             if self._inflight:
                 self._inc("steps_overlapped")
             slots = np.flatnonzero(run)
             self._enqueued(False, out,
-                           [(s, self._slot_req[s]) for s in slots], attrs)
+                           [(s, self._slot_req[s]) for s in slots], attrs,
+                           sp.id)
             self._inc("decode_steps")
             self._count_quant_matmuls()
             self._lens[slots] += 1
@@ -3136,6 +3191,11 @@ class PagedServingEngine(ServingEngine):
         # how often the loop had to make the host's view whole, by what
         # asked for it (beside ``steps_overlapped``: how often it ran on)
         out["drains"] = dict(self._drains)
+        # the waves committed so far by the bucket their program ran in:
+        # what each was given (requests, prompt tokens), what it paid
+        # for (rows = batch x seq) and the device's time for it
+        out["prefill_by_bucket"] = {
+            k: dict(v) for k, v in sorted(self._prefill_by_bucket.items())}
         pg = self._pager.stats()
         for k in ("prefix_page_hits", "prefix_page_misses", "cow_copies"):
             pg.pop(k)    # the engine-mirrored (warmup-quiet) counts win

@@ -149,10 +149,16 @@ def emit(record):
 _tls = threading.local()
 
 # The in-memory ring every span lands in, oldest first.  Sized from the
-# heaviest traffic the repo measures: a PagedServingEngine with 32 busy
-# slots closes ~100 spans a second (16 a step at 6 steps a second on a
-# v5e chip), ~7,000 over a 51 s window with its ramp and traced tail;
-# this holds nine times that.
+# heaviest traffic the repo measures.  A PagedServingEngine step closes
+# 9 spans and a prefill wave 5 more and one a request, so the GPT
+# backlog cell (32 slots; 52 steps and 19 waves a second on a v5e chip)
+# closes ~590 a second: 29,986 start in its 51 s window, 26,351 in the
+# kanana2 cell's, 13,870 in the phi4flash cell's, 11,703 in the ouro
+# cell's (PERF.md section 5, ``spans_in_window``).  The benchmark's
+# readers run after the traced tail, so the ring has to hold the window
+# and 3 s more: at the GPT cell's 11.2 spans a step it wraps past
+# 65,536 / 54 s / 11.2 = 108 steps a second, twice what that cell runs
+# today — and the readers raise when it has (benchmark/lib/spans.py).
 RING_SPANS = 1 << 16
 _ring = collections.deque(maxlen=RING_SPANS)
 _ids = itertools.count(1)
@@ -267,8 +273,18 @@ def _feed_sinks(sp, depth):
 def span(name, **attrs):
     """Nested timing span: always lands in the ring (:func:`spans`) and
     on the profiler's trace when a session is on; ``with span(...) as
-    sp`` gives ``sp.t0``, ``sp.t1`` and ``sp.dur`` so a histogram fed by
-    the span observes the span's own clock readings."""
+    sp`` gives ``sp.id`` (what a later span names to say which span
+    caused it), ``sp.t0``, ``sp.t1`` and ``sp.dur`` so a histogram fed
+    by the span observes the span's own clock readings.
+
+    Attributes known at entry are given at entry: the profiler's
+    annotation is built from the attributes the span has THEN, so only
+    those are on the trace's host line.  One set inside the block
+    (``sp.attrs["key"] = value``; the span must have been given at
+    least one attribute, or ``sp.attrs`` is None) reaches the ring and
+    the event log alone — ``hits`` on ``serving.pager.admit`` and
+    ``device_s`` on a readback span are such, by design: a trace has
+    the device's own line."""
     return _Span(name, attrs)
 
 
