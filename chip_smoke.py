@@ -393,7 +393,8 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
           if pool == "int8" else dict(page_size=page_size))
     kernel_ctr = {"paged": "serving.paged_kernel_calls",
                   "dequant_matmul": "serving.dequant_kernel_calls_matmul",
-                  "grouped_matmul": "serving.grouped_matmul_kernel_calls"}
+                  "grouped_matmul": "serving.grouped_matmul_kernel_calls",
+                  "flash_prefill": "serving.flash_prefill_kernel_calls"}
     k0 = {k: run.counter(n) for k, n in kernel_ctr.items()}
     c0 = run.counters()
     t0 = time.perf_counter()
@@ -463,7 +464,7 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
     if run.on_chip:
         # (the tiny hybrid's rows are narrower than a kernel's 128 lanes)
         need = {"int8": ["paged", "dequant_matmul"],
-                "latent": ["paged", "grouped_matmul"],
+                "latent": ["paged", "grouped_matmul", "flash_prefill"],
                 "hybrid": []}.get(pool, ["paged"])
         gave_way = [k for k in need if engaged[k] < 1]
         if gave_way:
@@ -476,6 +477,10 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
                       else "lax_gather"),
         "matmul": ("pallas_dequant" if engaged["dequant_matmul"]
                    else "xla"),
+        # a wave's causal attention: the flash forward where the
+        # family has one and a bucket is long enough for it
+        "prefill_attention": ("pallas_flash" if engaged["flash_prefill"]
+                              else "xla"),
         "kernel_instances": engaged,
         # (None for a family that gives no ``decode_group_pages``)
         "paged_attn_group_pages": st.get("paged_attn_group_pages"),

@@ -207,9 +207,9 @@ def _stats_family():
         # the paged differential-attention kernel, likewise (the
         # phi4flash family: one a window, full and cross layer kind)
         "paged_diff_kernel_calls": 0,
-        # the experts' grouped-matmul kernel, likewise (the deepseek_v3
-        # family: two an expert layer a program — gate-up, down)
-        "grouped_matmul_kernel_calls": 0,
+        # the deepseek_v3 family's, likewise: the experts' grouped matmul
+        # (two a layer: gate-up, down), the prefill wave's flash forward
+        "grouped_matmul_kernel_calls": 0, "flash_prefill_kernel_calls": 0,
         # expert-layer family (models/deepseek_v3.py; zero elsewhere):
         # what the decode step counts on the device and hands back
         # with its sampled tokens — assignments routed by the active
@@ -1353,9 +1353,9 @@ class ServingEngine:
         self._stats.inc(key, v)
         self._counts[key] = self._counts.get(key, 0) + v
 
-    _KERNEL_COUNTERS = ("dequant_kernel_calls", "paged_kernel_calls",
-                        "paged_diff_kernel_calls",
-                        "grouped_matmul_kernel_calls")
+    _KERNEL_COUNTERS = tuple(f"{k}_kernel_calls" for k in (
+        "dequant", "paged", "paged_diff", "grouped_matmul",
+        "flash_prefill"))
 
     def stats(self):
         """THIS engine's serving.* counters + live gauges, one dict.

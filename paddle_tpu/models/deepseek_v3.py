@@ -281,6 +281,30 @@ def _mla_attend(cfg, blk, q_nope, q_rope, c, kr, mask):
     return a.reshape(*a.shape[:2], -1)
 
 
+def _mla_attend_flash(cfg, blk, q_nope, q_rope, c, kr, lens):
+    """:func:`_mla_attend` for a prefill wave (T == K, causal, rows at
+    or past ``lens`` [B] padding) with the scores kept in VMEM
+    (``ops/pallas/flash_prefill.py``): the same expansion from the
+    latent, the same bf16 operands and float32 softmax; blocking and
+    where the normalisation lands differ.  Heads stay side by side
+    along the lanes, as the projections write them; the rope halves go
+    in whole lane rows, as the pool holds them; the kernel takes the
+    values and gives the output with positions along the lanes."""
+    from ..ops.pallas import flash_prefill
+    cd = jnp.dtype(cfg.dtype)
+    wuk, wuv = _wukv(cfg, blk)
+    B, T = c.shape[:2]
+    k_nope = jnp.einsum("bkc,chd->bkhd", c.astype(cd), wuk)
+    v_t = jnp.einsum("bkc,chd->bhdk", c.astype(cd), wuv)
+    a_t = flash_prefill.flash_prefill_attention(
+        q_nope.reshape(B, T, -1), k_nope.reshape(B, T, -1),
+        v_t.reshape(B, -1, T), lens, heads=cfg.num_attention_heads,
+        scale=1.0 / math.sqrt(cfg.qk_head_dim),
+        q_rope=_pad_rope(q_rope, cd).reshape(B, T, -1),
+        k_rope=_pad_rope(kr, cd))
+    return jnp.swapaxes(a_t, 1, 2)
+
+
 def _gated_mlp(h, wg, wu, wd, cd):
     g = h @ wg.astype(cd)
     u = h @ wu.astype(cd)
@@ -522,10 +546,14 @@ def prefill_paged(params, cfg, pools, tokens, lens, ptab):
     s / page_size] (pad rows and pad pages target the scratch page).
     Returns (logits of each row's last true position [b, V], pools) —
     the head runs on those b rows only."""
+    from ..ops.pallas.flash_prefill import use_flash_prefill
     b, s = tokens.shape
     ps = pools[0].shape[2]
     flat = ptab.reshape(-1)
     pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    flash = use_flash_prefill(b, s, cfg.num_attention_heads,
+                              cfg.qk_nope_head_dim, cfg.v_head_dim,
+                              ROPE_LANES)
 
     def body(x, blk, layer, pp):
         q_nope, q_rope, c, kr = _mla_project(cfg, x, blk, pos)
@@ -536,7 +564,11 @@ def prefill_paged(params, cfg, pools, tokens, lens, ptab):
             pr = pr.at[layer, flat].set(
                 _pad_rope(kr, pr.dtype).reshape(b * (s // ps), ps, -1))
         with jax.named_scope("mla_attn"):
-            a = _mla_attend(cfg, blk, q_nope, q_rope, c, kr, _causal(s, s))
+            if flash:
+                a = _mla_attend_flash(cfg, blk, q_nope, q_rope, c, kr, lens)
+            else:
+                a = _mla_attend(cfg, blk, q_nope, q_rope, c, kr,
+                                _causal(s, s))
         return _after_attention(cfg, x, blk, a)[0], (pc, pr), None
 
     x, pools, _ = _layers(params, body, _embed(cfg, params, tokens), pools)

@@ -59,6 +59,14 @@ def count_grouped_matmul_kernel():
     metrics.counter("serving.grouped_matmul_kernel_calls").inc()
 
 
+def count_flash_prefill_kernel():
+    """Trace-time engagement counter of the prefill flash forward
+    (``serving.flash_prefill_kernel_calls``): one a kernel instance a
+    compiled executable, as :func:`count_paged_kernel`'s."""
+    from ...observability import metrics
+    metrics.counter("serving.flash_prefill_kernel_calls").inc()
+
+
 def count_dequant_kernel(kernel):
     """Trace-time engagement counter for the quantized-serving kernels
     (ISSUE 9): bumps the aggregate ``serving.dequant_kernel_calls``

@@ -332,6 +332,23 @@ def test_grouped_matmul_kernel(chip, rows, form):
                                     else "") in text
 
 
+@pytest.mark.parametrize("b,s", [(1, 1024), (4, 512), (4, 1024)])
+def test_flash_prefill_kernel(chip, b, s):
+    """The prefill flash forward alone at the buckets the gate gives
+    it: 32 heads side by side along the lanes, q/k 128 + a 128-lane rope
+    row, v 128 and transposed, the rope key one row a position for every
+    head."""
+    from paddle_tpu.ops.pallas import flash_prefill as fp
+    assert 4 * b * 32 * s * s >= fp.MIN_SCORE_BYTES
+    wide, row = chip((b, s, 32 * 128), bf16), chip((b, s, 128), bf16)
+    text = jax.jit(lambda q, qr, k, kr, v_t, lens: fp._flash_prefill_tpu(
+        q, k, v_t, lens, heads=32, scale=192 ** -0.5, q_rope=qr,
+        k_rope=kr)).lower(wide, wide, wide, row, chip((b, 32 * 128, s), bf16),
+                          chip((b,), i32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "flash_prefill_fwd" in text
+
+
 @pytest.fixture
 def served_kanana(chip, monkeypatch):
     """(engine, params, pools) of the benchmark's own configuration,
@@ -385,6 +402,13 @@ def test_kanana_serving_programs_fit_and_hold_the_pool_in_place(
         # of temporaries when it was)
         assert text.count("paged_mla_decode") >= 2
         assert mem.temp_size_in_bytes < (64 << 20)
+    else:
+        # layer 0 and the scan's body: the flash forward twice, and the
+        # float32 scores XLA's attention wrote (1.03 GiB of temporaries
+        # at 4 x 1024 with them, 0.48 without; AOT, PR 38) nowhere
+        assert text.count("flash_prefill_fwd") >= 2
+        assert "f32[4,32,1024,1024]" not in text
+        assert mem.temp_size_in_bytes < (640 << 20)
     # the experts' three products: our kernel twice in the scan's body
     # (gate-up, down) and the compiler's ragged-dot nowhere
     assert "%moe_grouped_matmul_gate_up" in text
