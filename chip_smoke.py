@@ -441,7 +441,8 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
         raise AssertionError("repeated prompts hit no cached prefix page")
     # (the tiny hybrid's pool rows are 32 lanes wide: the compiler tiles
     # them into 128 by a copy that no served width needs)
-    if run.on_chip and pool != "hybrid" and any(relayouts.values()):
+    if run.on_chip and pool not in ("hybrid", "scmoe") \
+            and any(relayouts.values()):
         raise AssertionError(
             f"the compiled programs copy the KV pool: {relayouts}")
     # the operator's view of prefill: the table's sums are the two
@@ -465,7 +466,8 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
         # (the tiny hybrid's rows are narrower than a kernel's 128 lanes)
         need = {"int8": ["paged", "dequant_matmul"],
                 "latent": ["paged", "grouped_matmul", "flash_prefill"],
-                "hybrid": []}.get(pool, ["paged"])
+                "hybrid": [], "scmoe": ["grouped_matmul"]}.get(
+                    pool, ["paged"])
         gave_way = [k for k in need if engaged[k] < 1]
         if gave_way:
             raise AssertionError(
@@ -513,6 +515,11 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
         # (None for a family whose layers run once)
         "loop_tokens": st.get("loop_tokens"),
         "loop_passes": st.get("loop_passes"),
+        # (None for a family whose expert layer holds every column, or
+        # has none): the rows a partial share's combine walked, of its
+        # output's rows, over the decode steps' expert layers
+        "moe_combine_rows": st.get("moe_combine_rows"),
+        "moe_output_rows": st.get("moe_output_rows"),
         "kv_bytes_total": st["kv_bytes_total"],
         "param_bytes_per_device": eng.param_bytes_per_device(),
         "placed_bytes_per_device": placed,
@@ -829,6 +836,50 @@ def phase_serve_hybrid(run):
             "serve_hybrid: the waves' programs handed back no row count")
 
 
+SCMOE_SLOTS = 200            # the benchmark cell's: 200 x 12 = 2,400 rows
+
+
+def phase_serve_scmoe(run):
+    """The longcat_flash family (a SHARE of the routed experts beside
+    identity ones) at a small width but the benchmark cell's share — 16
+    of 512 routed experts held beside 256 identity ones, top-12, 200
+    slots, every slot busy — through the same engine: that it serves on
+    this device, and how many of its expert output's rows the combine
+    walked (``moe_combine_rows`` of ``moe_output_rows``: ≈ one window of
+    128 of 2,432 rows a layer)."""
+    import jax
+    import numpy as np
+    from paddle_tpu.models import longcat_flash
+
+    tiny = Run(dataclasses.replace(REHEARSE, slots=SCMOE_SLOTS),
+               run.devices, run.seed, run.on_chip)
+    cfg = longcat_flash.longcat_flash_tiny(
+        hidden_size=256, ffn_hidden_size=256, expert_ffn_hidden_size=128,
+        q_lora_rank=128, kv_lora_rank=128, qk_rope_head_dim=64,
+        qk_nope_head_dim=128, v_head_dim=128, n_routed_experts=16,
+        n_routed_experts_total=512, first_held_expert=0,
+        zero_expert_num=256, moe_topk=12, dtype="bfloat16",
+        param_dtype="bfloat16")
+    params = longcat_flash.init_params(cfg, jax.random.PRNGKey(run.seed + 6))
+    rng = np.random.RandomState(run.seed + 7)
+    prompts = [rng.randint(0, cfg.vocab_size, (ln,)).astype(np.int32)
+               for ln in np.resize(REHEARSE.prompt_lens, SCMOE_SLOTS)]
+    _, report = serve_requests(tiny, params, cfg, prompts, pool="scmoe",
+                               capture_logits=False)
+    walked, rows = report["moe_combine_rows"], report["moe_output_rows"]
+    emit(phase="serve_scmoe", note="smoke, not a measurement",
+         device_kind=run.kind,
+         shape=dict(hidden=cfg.hidden_size, layers=cfg.num_layers,
+                    held=cfg.n_routed_experts,
+                    routed=cfg.n_routed_experts_total,
+                    identity=cfg.zero_expert_num, top_k=cfg.moe_topk,
+                    slots=SCMOE_SLOTS),
+         moe_combine_share=walked / rows if rows else None, **report)
+    if not rows or not 0 < walked < rows:
+        raise AssertionError(
+            f"serve_scmoe: the combine walked {walked} of {rows} rows")
+
+
 # --------------------------------------------------------------------------
 # --chips 4: the mesh phases and what they are compared with, nothing else
 # --------------------------------------------------------------------------
@@ -978,6 +1029,7 @@ def main():
         phase_serve(run, params, cfg, "int8")
         del params
         phase_serve_latent(run)
+        phase_serve_scmoe(run)
         phase_serve_looped(run)
         phase_serve_hybrid(run)
     emit(phase="compile_cache", dir=cache_dir, **run.counters(),

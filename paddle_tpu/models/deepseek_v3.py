@@ -104,6 +104,20 @@ class ExpertShare(NamedTuple):
     def width(self):
         return self.routed + self.zero
 
+    @property
+    def partial(self):
+        """Whether the router has columns the layer does not hold
+        (routed experts held elsewhere, or identity experts)."""
+        return self.held < self.width
+
+    @property
+    def count_width(self):
+        """Entries of :func:`moe_ffn`'s count vector: the held experts'
+        loads, the identity and the remote assignments, and for a
+        partial share the rows its combine walked and its output's
+        rows."""
+        return self.held + (4 if self.partial else 2)
+
 
 @dataclasses.dataclass
 class DeepseekV3Config:
@@ -400,24 +414,68 @@ def route(cfg, h2, wr, b):
     return chosen.astype(jnp.int32), w
 
 
+def _held_part(h2, blk, order, sizes, w_held, cd):
+    """The held experts' part of a PARTIAL share's layer: (y [T, H]
+    float32, int32 [2]: the rows the combine walked and the output's
+    rows ``R``).  ``order`` sorts the T * k assignments by expert, the
+    held ones first (``sizes`` [held] of them, a group each);
+    ``w_held`` [T, k] is 0 where an assignment is not held.
+
+    The sorted rows go to the grouped matmul padded to its whole
+    windows, ``R`` rows (``grouped_matmul.window_rows``), so its output
+    needs no cut; a pad row is no group's, comes out zero and weighs 0.
+    Only the windows the held rows reach are combined, one at a time:
+    each window's rows, times their weights, are added into their
+    tokens.  Rows past the held ones inside the last window are zeros
+    of weight 0, and every held row lies in a walked window whatever
+    the routing: the sum a whole share's einsum takes over the same
+    terms, added in another order."""
+    from ..ops.pallas import grouped_matmul as gmm
+    T, H = h2.shape
+    n = order.shape[0]
+    tm = gmm.window_rows(n, h2.dtype.itemsize)
+    R = -(-n // tm) * tm
+    tok = jnp.pad(order // w_held.shape[1], (0, R - n))    # pad: token 0
+    wt = jnp.pad(w_held.reshape(-1)[order], (0, R - n))    # pad: weight 0
+    li = blk.get("li")
+    mid = gmm.grouped_gate_up(h2[tok], sizes, blk["eg"].astype(cd),
+                              blk["eu"].astype(cd), li)
+    y = gmm.grouped_matmul(mid, sizes, blk["ed"].astype(cd), li)
+    windows = (jnp.sum(sizes) + (tm - 1)) // tm
+
+    def window(i, out):
+        rows = jax.lax.dynamic_slice_in_dim(y, i * tm, tm)
+        at = jax.lax.dynamic_slice_in_dim(tok, i * tm, tm)
+        wi = jax.lax.dynamic_slice_in_dim(wt, i * tm, tm)
+        return out.at[at].add(rows * wi[:, None])
+
+    out = jax.lax.fori_loop(0, windows, window,
+                            jnp.zeros((T, H), jnp.float32))
+    return out, jnp.stack([windows * tm, jnp.int32(R)]).astype(jnp.int32)
+
+
 def moe_ffn(cfg, h2, blk, row_mask=None):
     """The expert layer over ``h2`` [T, H]: routed + shared + identity,
-    no residual.  Returns (y [T, H], counts int32 [held + 2]: the
-    assignments each HELD expert got from the rows ``row_mask`` admits
-    — every row when None — then the assignments to identity experts
-    and to routed experts held elsewhere, from the same rows; this
-    family's two are 0).
+    no residual.  Returns (y [T, H], counts int32
+    [``share.count_width``]: the assignments each HELD expert got from
+    the rows ``row_mask`` admits — every row when None — then the
+    assignments to identity experts and to routed experts held
+    elsewhere, from the same rows, this family's two 0; a partial share
+    adds the rows its combine walked and its output's rows, from every
+    row: :func:`_held_part`).
 
     No token is dropped: the T * k assignments are sorted by expert and
     each held expert's rows meet its weights in a grouped matmul whose
     group sizes are data.  Shapes depend on T alone.  Of a share
     (``cfg.expert_share``), the assignments the layer does not hold sort
     past its own groups: the grouped matmul computes none of them and
-    gives zeros there (``ops/pallas/grouped_matmul.py``), and their
-    weight is 0 in the sum — what the experts' exchange would carry
-    (not built).  The identity experts' weights are summed a token and
-    meet ``h2`` in one weighted add.  This family's share ``(0, E)``
-    holds every assignment: the same program, the same values.
+    gives zeros there (``ops/pallas/grouped_matmul.py``), and they add
+    nothing to a token — what the experts' exchange would carry (not
+    built).  The identity experts' weights are summed a token and meet
+    ``h2`` in one weighted add.  A share that holds every column (this
+    family's ``(0, E)``) unsorts the whole output and sums it with the
+    weights; a partial one adds its live rows into their tokens
+    (:func:`_held_part`).
 
     ``blk["eg"], ["eu"], ["ed"]`` are one layer's experts [E, ., .], or
     the WHOLE stack [layers, E, ., .] with ``blk["li"]`` naming the
@@ -450,14 +508,19 @@ def moe_ffn(cfg, h2, blk, row_mask=None):
             jnp.sum(jnp.where(held | zero, 0, rows))[None]])
     with jax.named_scope("moe_held"):
         order = jnp.argsort(flat, stable=True)
-        xs = h2[order // K]                                # expert-sorted
-        li = blk.get("li")
-        mid = gmm.grouped_gate_up(xs, sizes, blk["eg"].astype(cd),
-                                  blk["eu"].astype(cd), li)
-        y = gmm.grouped_matmul(mid, sizes, blk["ed"].astype(cd), li)
-        y = y[jnp.argsort(order)].reshape(T, K, H)         # unsort
-        y = jnp.einsum("tkh,tk->th", y,
-                       jnp.where(held.reshape(T, K), w, 0.0))
+        if share.partial:
+            y, walked = _held_part(h2, blk, order, sizes,
+                                   jnp.where(held.reshape(T, K), w, 0.0), cd)
+            counts = jnp.concatenate([counts, walked])
+        else:
+            xs = h2[order // K]                            # expert-sorted
+            li = blk.get("li")
+            mid = gmm.grouped_gate_up(xs, sizes, blk["eg"].astype(cd),
+                                      blk["eu"].astype(cd), li)
+            y = gmm.grouped_matmul(mid, sizes, blk["ed"].astype(cd), li)
+            y = y[jnp.argsort(order)].reshape(T, K, H)     # unsort
+            y = jnp.einsum("tkh,tk->th", y,
+                           jnp.where(held.reshape(T, K), w, 0.0))
     if share.zero:
         with jax.named_scope("moe_zero"):
             w_zero = jnp.sum(jnp.where(zero.reshape(T, K), w, 0.0), -1)
@@ -613,17 +676,25 @@ def decode_group_pages(cfg, pools, table_width, tp=1):
 def decode_extra_stats(cfg, flat):
     """The engine's counters from what :func:`decode_paged` returned
     beside the logits (host side, numpy): ``flat`` is the expert
-    layers' count vectors end to end (:func:`moe_ffn`'s), all from the
+    layers' count vectors end to end (:func:`moe_ffn`'s), from the
     active slots.  The first three are over the HELD experts; the
     identity and the remote assignments are 0 in this family
-    (``models/longcat_flash.py`` shares this)."""
-    rows = flat.reshape(-1, cfg.n_routed_experts + 2)
-    loads = rows[:, :cfg.n_routed_experts]
-    return {"moe_assignments": int(loads.sum()),
-            "moe_experts_touched": int((loads > 0).sum()),
-            "moe_max_expert_load": int(loads.max(axis=1).sum()),
-            "moe_zero_assignments": int(rows[:, -2].sum()),
-            "moe_remote_assignments": int(rows[:, -1].sum())}
+    (``models/longcat_flash.py`` shares this).  A partial share also
+    gives the rows its combine walked and the rows the whole output
+    has (every slot's: the combine walks rows, not slots)."""
+    share = cfg.expert_share
+    E = share.held
+    rows = flat.reshape(-1, share.count_width)
+    loads = rows[:, :E]
+    out = {"moe_assignments": int(loads.sum()),
+           "moe_experts_touched": int((loads > 0).sum()),
+           "moe_max_expert_load": int(loads.max(axis=1).sum()),
+           "moe_zero_assignments": int(rows[:, E].sum()),
+           "moe_remote_assignments": int(rows[:, E + 1].sum())}
+    if share.partial:
+        out["moe_combine_rows"] = int(rows[:, E + 2].sum())
+        out["moe_output_rows"] = int(rows[:, E + 3].sum())
+    return out
 
 
 def paged_pool_shapes(cfg, num_pages, page_size):

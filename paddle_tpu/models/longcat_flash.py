@@ -388,9 +388,11 @@ def chunk_paged(params, cfg, pools, tokens, pt_row, offset):
 def decode_paged(params, cfg, pools, page_table, write_pages, write_offs,
                  lens, tokens, mesh=None, absorbed=True):
     """One decode iteration for every slot.  Returns (logits [S, V]
-    float32, pools, counts int32 [layers, held + 2]: the held experts'
+    float32, pools, counts int32 [layers, held + 4]: the held experts'
     loads, the identity and the remote assignments, from the ACTIVE
-    slots — ``lens > 0``).  ``absorbed`` as ``deepseek_v3``'s."""
+    slots — ``lens > 0`` —, then the rows the expert layer's combine
+    walked and its output's rows, from every slot).  ``absorbed`` as
+    ``deepseek_v3``'s."""
     active = lens > 0
 
     def attend(j, x, blk, pp, layer):

@@ -213,8 +213,9 @@ class TestExpertLayer:
                 y, counts = ds.moe_ffn(share, h0, _moe_blk(
                     params, first=first, held=4))
                 parts.append(np.asarray(y) - np.asarray(identity))
-                # every assignment is held, identity or remote
-                assert int(counts.sum()) == h0.shape[0] * whole.moe_topk
+                # every assignment is held, identity or remote (the two
+                # last entries are the combine's rows)
+                assert int(counts[:-2].sum()) == h0.shape[0] * whole.moe_topk
             got = sum(parts) + np.asarray(identity)
         assert np.abs(got - np.asarray(want)).max() < TOL
         assert np.abs(np.asarray(identity)).max() > 0.01
@@ -233,8 +234,10 @@ class TestExpertLayer:
         first, held = cfg.first_held_expert, cfg.n_routed_experts
         loads = [(chosen == first + i).sum() for i in range(held)]
         zero = (chosen >= cfg.n_routed_experts_total).sum()
+        tm = grouped_matmul.window_rows(chosen.size, 4)
         assert list(np.asarray(counts)) == loads + [
-            zero, chosen.size - sum(loads) - zero]
+            zero, chosen.size - sum(loads) - zero,
+            -(-sum(loads) // tm) * tm, -(-chosen.size // tm) * tm]
 
     def test_a_router_forced_onto_identity_experts_gives_the_weighted_input(
             self, tiny, moe_path):
@@ -252,8 +255,9 @@ class TestExpertLayer:
         k = cfg.moe_topk
         want = k * cfg.routed_scaling_factor / width * np.asarray(h0)
         assert np.abs(np.asarray(y) - want).max() < 1e-5
+        # no held row: the combine walks no window
         assert list(np.asarray(counts)) == [0] * cfg.n_routed_experts + [
-            h0.shape[0] * k, 0]
+            h0.shape[0] * k, 0, 0, h0.shape[0] * k]
 
     def test_the_expert_sublayer_alone_against_the_reference(self, tiny,
                                                              moe_path):
@@ -278,8 +282,10 @@ class TestExpertLayer:
         _, c_all = ds.moe_ffn(cfg, h0, _moe_blk(params))
         _, c_some = ds.moe_ffn(cfg, h0[:10], _moe_blk(params))
         y, c = ds.moe_ffn(cfg, h0, _moe_blk(params), row_mask=mask)
-        assert np.array_equal(np.asarray(c), np.asarray(c_some))
-        assert int(c_all.sum()) == h0.shape[0] * cfg.moe_topk
+        # (the combine's two counts are over every row)
+        assert np.array_equal(np.asarray(c)[:-2], np.asarray(c_some)[:-2])
+        assert np.array_equal(np.asarray(c)[-2:], np.asarray(c_all)[-2:])
+        assert int(c_all[:-2].sum()) == h0.shape[0] * cfg.moe_topk
 
     def test_no_share_that_is_not_one(self):
         for bad in (dict(first_held_expert=10), dict(n_routed_experts=0),
@@ -340,6 +346,139 @@ def test_kanana2s_expert_layer_is_bit_identical_through_the_shared_function(
 
 
 # --------------------------------------------------------------------------
+# a partial share combines only the windows its held rows reach
+# --------------------------------------------------------------------------
+
+def _routed_to(cfg, per_token, seed=0):
+    """(h2 [T, H], blk): layer 0's experts behind a router that gives
+    token ``t`` exactly ``per_token[t]`` held assignments of its k.
+    Feature ``(n, r)`` of a row scores n held columns and k - n others
+    (turned by r, so tokens spread over the experts); the rest of the
+    row is noise the router does not see and the experts do."""
+    share, K, H = cfg.expert_share, cfg.moe_topk, cfg.hidden_size
+    held = np.arange(share.first, share.first + share.held)
+    other = np.setdiff1d(np.arange(share.width), held)
+    feats = (K + 1) * share.held
+    wr = np.zeros((H, share.width), np.float32)
+    for n in range(K + 1):
+        for r in range(share.held):
+            d = n * share.held + r
+            wr[d, held[(r + np.arange(n)) % len(held)]] = 8.0
+            wr[d, other[(r + np.arange(K - n)) % len(other)]] = 8.0
+    rng = np.random.default_rng(seed)
+    h2 = np.zeros((len(per_token), H), np.float32)
+    h2[:, feats:] = rng.standard_normal((len(per_token), H - feats))
+    h2[np.arange(len(per_token)),
+       np.asarray(per_token) * share.held
+       + rng.integers(0, share.held, len(per_token))] = 1.0
+    params = lc.init_params(cfg, jax.random.PRNGKey(seed))
+    blk = dict(_moe_blk(params), wr=jnp.asarray(wr),
+               b=jnp.zeros((share.width,), jnp.float32))
+    return jnp.asarray(h2), blk
+
+
+def _per_token(T, K, n_held, seed=0):
+    """Held assignments a token so that the T tokens hold ``n_held``."""
+    per = np.zeros((T,), np.int64)
+    for t in np.random.default_rng(seed).permutation(T):
+        per[t] = min(K, n_held - per.sum())
+    assert per.sum() == n_held
+    return per
+
+
+def _whole_combine(cfg, h2, blk):
+    """The layer as a whole share combines, over the partial share's own
+    grouped-matmul output: every sorted row unsorted and summed with
+    the held weights, plus the identity term — float32, before the
+    cast."""
+    share, K = cfg.expert_share, cfg.moe_topk
+    T, H = h2.shape
+    chosen, w = ds.route(cfg, h2, blk["wr"], blk["b"])
+    local = chosen.reshape(-1) - share.first
+    held = (local >= 0) & (local < share.held)
+    flat = jnp.where(held, local, share.held)
+    sizes = jnp.zeros((share.held,), jnp.int32).at[flat].add(1, mode="drop")
+    order = jnp.argsort(flat, stable=True)
+    tm = grouped_matmul.window_rows(T * K, h2.dtype.itemsize)
+    R = -(-T * K // tm) * tm
+    xs = h2[jnp.pad(order // K, (0, R - T * K))]
+    mid = grouped_matmul.grouped_gate_up(xs, sizes, blk["eg"], blk["eu"])
+    y = grouped_matmul.grouped_matmul(mid, sizes, blk["ed"])[:T * K]
+    y = y[jnp.argsort(order)].reshape(T, K, H)
+    y = jnp.einsum("tkh,tk->th", y, jnp.where(held.reshape(T, K), w, 0.0))
+    zero = chosen >= share.routed
+    return y + jnp.sum(jnp.where(zero, w, 0.0), -1)[:, None] * h2
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all-rows", "masked"])
+@pytest.mark.parametrize("n_held", [0, 127, 128, 129, "all"])
+@pytest.mark.parametrize("T", [100, 2 * 128],
+                         ids=["decode-100", "prefill-2x128"])
+def test_a_partial_share_combines_its_live_windows_as_the_whole_output(
+        tiny, moe_path, T, n_held, masked):
+    """The partial share's layer (only the windows its held rows reach,
+    added into their tokens) against the whole-share combine of the same
+    grouped-matmul output: equal to float32 rounding, with no held row,
+    at a window's edge and with every assignment held; the combine's
+    counts are its windows and the output's rows."""
+    _, cfg = tiny
+    K = cfg.moe_topk
+    n = T * K if n_held == "all" else n_held
+    h2, blk = _routed_to(cfg, _per_token(T, K, n))
+    mask = (jnp.arange(T) % 3 > 0) if masked else None
+    y, counts = jax.jit(lambda h, b: ds.moe_ffn(cfg, h, b, mask))(h2, blk)
+    want = np.asarray(_whole_combine(cfg, h2, blk))
+    scale = np.abs(want).max()
+    assert np.abs(np.asarray(y) - want).max() <= 1e-6 * scale
+    tm = grouped_matmul.window_rows(T * K, 4)
+    counts = np.asarray(counts)
+    rows = np.ones(T, bool) if mask is None else np.asarray(mask)
+    assert counts[:cfg.n_routed_experts].sum() == _per_token(T, K, n)[
+        rows].sum()
+    assert list(counts[-2:]) == [-(-n // tm) * tm, -(-T * K // tm) * tm]
+
+
+def test_the_controls_tool_notes_the_combines_rows():
+    """``tools/scmoe_controls.py`` hands the driver's record on and
+    notes the rows the combine walked of the output's rows."""
+    spec = importlib.util.spec_from_file_location(
+        "scmoe_controls", os.path.join(ROOT, "tools", "scmoe_controls.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    notes = []
+    ctx = type("Ctx", (), {"note": lambda self, **f: notes.append(f)})()
+    record = {"moe": {"moe_combine_rows": 128 * 3,
+                      "moe_output_rows": 2432 * 3}}
+    assert tool.run_noting_the_combine(ctx, lambda c: record) is record
+    assert notes == [{"phase": "moe_combine", "moe_combine_rows": 384,
+                      "moe_output_rows": 7296,
+                      "moe_combine_share": 384 / 7296}]
+
+
+def test_only_a_partial_share_builds_the_combine_loop(tiny):
+    """A share that holds every column keeps the unsort and the einsum:
+    no loop in its layer; a partial share's layer has one."""
+    def text(cfg, params, blk):
+        h2 = _h0(cfg).astype(cfg.dtype)
+        return jax.jit(lambda h, b: ds.moe_ffn(cfg, h, b)).lower(
+            h2, blk).as_text()
+
+    whole = ds.deepseek_v3_tiny()
+    assert not whole.expert_share.partial
+    params = ds.init_params(whole, jax.random.PRNGKey(2))
+    blk = {k: v[0] for k, v in params["moe"].items()}
+    assert "while" not in text(whole, params, blk)
+    params, cfg = tiny
+    assert cfg.expert_share.partial
+    assert "while" in text(cfg, params, _moe_blk(params))
+    # the counts a full share hands the engine keep their keys
+    flat = np.zeros((2 * whole.expert_share.count_width,), np.int32)
+    assert set(ds.decode_extra_stats(whole, flat)) == {
+        "moe_assignments", "moe_experts_touched", "moe_max_expert_load",
+        "moe_zero_assignments", "moe_remote_assignments"}
+
+
+# --------------------------------------------------------------------------
 # through the paged engine
 # --------------------------------------------------------------------------
 
@@ -374,6 +513,40 @@ class TestPagedEngine:
         assert st["kv_bytes_per_position"] == 4 * (32 + 8) * 4
         assert st["pages_in_use"] == 0
 
+    def test_the_combine_counts_its_windows_through_stats(
+            self, tiny, monkeypatch):
+        """Every slot busy in every decode step (one wave of 40, answers
+        of one length): each layer's combine walks ceil(held rows / 128)
+        windows of 128 of its 256 rows, and ``stats()`` sums them."""
+        params, cfg = tiny
+        seen = []
+
+        def spy(c, flat):
+            seen.append(np.asarray(flat).copy())
+            return ds.decode_extra_stats(c, flat)
+
+        monkeypatch.setattr(lc, "decode_extra_stats", spy)
+        slots, K, E = 40, cfg.moe_topk, cfg.n_routed_experts
+        eng = _engine(tiny, slots=slots, num_pages=90, max_len=32,
+                      batch_buckets=(1, slots), seq_buckets=(16,))
+        reqs = [eng.submit(_tokens(200 + i, 8), 6) for i in range(slots)]
+        eng.run(max_steps=50)
+        assert all(r.done and not r.failed for r in reqs)
+        st = eng.stats()
+        assert len(seen) == st["decode_steps"] > 0
+        tm, R = 128, 256        # 40 x 4 assignments in whole windows
+        walked = 0
+        for flat in seen:
+            rows = flat.reshape(cfg.num_layers, -1)
+            # every slot counted: the held rows are all of them
+            assert (rows[:, :E + 2].sum(1) == slots * K).all()
+            n_held = rows[:, :E].sum(1)
+            assert (rows[:, -2] == -(-n_held // tm) * tm).all()
+            assert (rows[:, -1] == R).all()
+            walked += int(rows[:, -2].sum())
+        assert 0 < st["moe_combine_rows"] == walked
+        assert st["moe_output_rows"] == st["decode_steps"] * cfg.num_layers * R
+
     def test_the_pool_holds_two_blocks_a_layer(self, tiny):
         _, cfg = tiny
         c, r = lc.init_paged_pools(cfg, 5, 8)
@@ -399,7 +572,7 @@ class TestPagedEngine:
                 assert np.abs(np.asarray(a) - np.asarray(u)).max() < TOL
                 assert np.abs(np.asarray(a[0]) - want[pos]).max() < TOL
                 assert counts.shape == (cfg.num_layers,
-                                        cfg.n_routed_experts + 2)
+                                        cfg.n_routed_experts + 4)
                 pools = new_pools
 
     def test_chunked_prefill(self, tiny):
