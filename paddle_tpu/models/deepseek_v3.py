@@ -398,14 +398,15 @@ def route(cfg, h2, wr, b):
     the chosen scores renormalised where ``cfg.norm_topk_prob``, then
     scaled.  Scores and sums in float32, the product at HIGHEST (a
     float32 matmul is one bf16 pass on the chip otherwise); ties go to
-    the lower expert id (``lax.top_k``'s rule)."""
+    the lower expert id (``lax.top_k``'s rule).  ``b`` None: a router
+    with no selection bias (``models/cohere2_moe.py``)."""
     logits = jnp.dot(h2.astype(jnp.float32), wr.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     if cfg.scoring_func == "softmax":
         s = jax.nn.softmax(logits, -1)
     else:
         s = jax.nn.sigmoid(logits)
-    _, chosen = jax.lax.top_k(s + b.astype(jnp.float32),
+    _, chosen = jax.lax.top_k(s if b is None else s + b.astype(jnp.float32),
                               cfg.num_experts_per_tok)
     w = jnp.take_along_axis(s, chosen, -1)
     if cfg.norm_topk_prob:
@@ -456,8 +457,9 @@ def _held_part(h2, blk, order, sizes, w_held, cd):
 
 def moe_ffn(cfg, h2, blk, row_mask=None):
     """The expert layer over ``h2`` [T, H]: routed + shared + identity,
-    no residual.  Returns (y [T, H], counts int32
-    [``share.count_width``]: the assignments each HELD expert got from
+    no residual; a block without ``b`` routes with no selection bias,
+    one without ``sg`` has no shared term.  Returns (y [T, H], counts
+    int32 [``share.count_width``]: the assignments each HELD expert got from
     the rows ``row_mask`` admits — every row when None — then the
     assignments to identity experts and to routed experts held
     elsewhere, from the same rows, this family's two 0; a partial share
@@ -493,7 +495,7 @@ def moe_ffn(cfg, h2, blk, row_mask=None):
     T, H = h2.shape
     K, E = cfg.num_experts_per_tok, share.held
     with jax.named_scope("moe_router"):
-        chosen, w = route(cfg, h2, blk["wr"], blk["b"])
+        chosen, w = route(cfg, h2, blk["wr"], blk.get("b"))
         local = chosen.reshape(-1) - share.first           # [T * K]
         held = (local >= 0) & (local < E)
         zero = chosen.reshape(-1) >= share.routed

@@ -148,11 +148,14 @@ def _fp_kernel(lens_ref, *refs, rope, scale, block_q, block_k):
 
 def _flash_prefill_tpu(q, k, v_t, lens, *, heads, scale, q_rope=None,
                        k_rope=None, block_q=None, block_k=None,
-                       interpret=False):
-    """q, k [b, s, heads * dk]; v_t [b, heads * dv, s]; -> the output
-    TRANSPOSED, [b, heads * dv, s]."""
+                       interpret=False, group=1):
+    """q [b, s, heads * dk]; k [b, s, heads / group * dk]; v_t [b, heads
+    / group * dv, s]; -> the output TRANSPOSED, [b, heads * dv, s].
+    Query head ``h`` meets key/value head ``h // group``."""
     b, s = q.shape[:2]
-    dk, dv = q.shape[2] // heads, v_t.shape[1] // heads
+    dk, dv = q.shape[2] // heads, v_t.shape[1] * group // heads
+    # the key/value head a query head's grid steps fetch
+    kv = (lambda h: h) if group == 1 else (lambda h: h // group)
     rope = q_rope is not None
     block_q = min(block_q or BLOCKS[0], s)
     block_k = min(block_k or BLOCKS[1], s)
@@ -182,7 +185,7 @@ def _flash_prefill_tpu(q, k, v_t, lens, *, heads, scale, q_rope=None,
         return pl.BlockSpec(
             (None, block_k, width),
             lambda b, h, qi, ki, ln: (b, k_block(qi, ki, ln[b]),
-                                      0 if shared else h))
+                                      0 if shared else kv(h)))
 
     operands = [(rows(q, sq), q_spec(dk))]
     if rope:
@@ -192,7 +195,7 @@ def _flash_prefill_tpu(q, k, v_t, lens, *, heads, scale, q_rope=None,
         operands.append((rows(k_rope, sk), k_spec(k_rope.shape[2], True)))
     operands.append((rows(v_t, sk, 2), pl.BlockSpec(
         (None, dv, block_k),
-        lambda b, h, qi, ki, ln: (b, h, k_block(qi, ki, ln[b])))))
+        lambda b, h, qi, ki, ln: (b, kv(h), k_block(qi, ki, ln[b])))))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, heads, sq // block_q, sk // block_k),
@@ -229,15 +232,18 @@ def use_flash_prefill(b, s, heads, qk_width, v_width, rope_width=0):
 
 
 def flash_prefill_attention(q, k, v_t, lens, *, heads, scale, q_rope=None,
-                            k_rope=None):
+                            k_rope=None, group=1):
     """Causal attention of a prefill wave through the compiled kernel;
     the caller asked :func:`use_flash_prefill` first.  q, k
     [b, s, heads * dk]; v_t [b, heads * dv, s], the values TRANSPOSED;
     lens int32 [b]: rows at or past ``lens[b]`` are padding, and their
     outputs are not defined (whole blocks of them are zeros); q_rope
     [b, s, heads * R] and k_rope [b, s, R], or neither: the second
-    score product, its key shared by the heads.  Returns the output
+    score product, its key shared by the heads.  ``group`` query heads
+    share a key/value head (grouped-query attention: k and v_t hold
+    ``heads / group`` heads, which no copy repeats; each query head's
+    grid steps fetch its group's blocks).  Returns the output
     transposed as the values came, [b, heads * dv, s], in q's dtype."""
     count_flash_prefill_kernel()
     return _flash_prefill_tpu(q, k, v_t, lens, heads=heads, scale=scale,
-                              q_rope=q_rope, k_rope=k_rope)
+                              q_rope=q_rope, k_rope=k_rope, group=group)

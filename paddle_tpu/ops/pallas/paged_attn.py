@@ -53,12 +53,16 @@ def _ref_paged_attention(q, k_pages, v_pages, page_table, lens):
     as the pool stores them (or [P, ps, nh, hd]: the same bytes);
     page_table: int32 [S, maxP]; lens: int32 [S] (the new token sits at
     position lens[s], already scattered into its page).  Returns
-    [S, 1, nh, hd]."""
+    [S, 1, nh, hd].  Pages of fewer K/V heads than ``nh`` are read by
+    groups of query heads (:func:`_ref_paged_gqa`)."""
     S, maxP = page_table.shape
     ps = k_pages.shape[1]
     nh, hd = q.shape[2:]
     cd = q.dtype
     view = maxP * ps
+    nkv = math.prod(k_pages.shape[2:]) // hd
+    if nkv != nh:
+        return _ref_paged_gqa(q, k_pages, v_pages, page_table, lens, nkv)
     kc = k_pages[page_table].reshape(S, view, nh, hd)
     vc = v_pages[page_table].reshape(S, view, nh, hd)
     logits = jnp.einsum("sqhd,skhd->shqk", q.astype(jnp.float32),
@@ -67,6 +71,25 @@ def _ref_paged_attention(q, k_pages, v_pages, page_table, lens):
     logits = jnp.where(mask[:, None, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, -1).astype(cd)
     return jnp.einsum("shqk,skhd->sqhd", probs, vc.astype(cd))
+
+
+def _ref_paged_gqa(q, k_pages, v_pages, page_table, lens, nkv):
+    """:func:`_ref_paged_attention` for grouped-query heads: query head
+    ``i`` of ``nh`` reads K/V head ``i // (nh / nkv)``."""
+    S, maxP = page_table.shape
+    nh, hd = q.shape[2:]
+    g = nh // nkv
+    view = maxP * k_pages.shape[1]
+    kc = k_pages[page_table].reshape(S, view, nkv, hd)
+    vc = v_pages[page_table].reshape(S, view, nkv, hd)
+    qg = q.reshape(S, nkv, g, hd).astype(jnp.float32)
+    logits = jnp.einsum("sngd,sknd->sngk", qg,
+                        kc.astype(jnp.float32)) / math.sqrt(hd)
+    mask = jnp.arange(view)[None, :] <= lens[:, None]       # [S, view]
+    logits = jnp.where(mask[:, None, None, :], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, -1).astype(q.dtype)
+    out = jnp.einsum("sngk,sknd->sngd", probs, vc.astype(q.dtype))
+    return out.reshape(S, 1, nh, hd)
 
 
 def _head_mask(nh, hd):
@@ -106,7 +129,8 @@ def _dot_f32(a, b, contract_b):
 
 
 def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, *rest,
-                         page_size, head_dim, group, table_width, quant):
+                         page_size, head_dim, group, table_width, quant,
+                         q_group=1):
     """Grid (slot, page group).  A step works on ``group`` physical
     pages of K and of V as ONE block of rows, [group * ps, C], online
     softmax across a lane's groups exactly like flash_attn's streamed
@@ -142,12 +166,22 @@ def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, *rest,
     widen to bf16 exactly, and their scales are [rows, nh] like the
     transposed scores and probabilities, so they fold in there
     (``q . (k*s) == (q . k) * s``) and the pages are never dequantized
-    elementwise."""
+    elementwise.
+
+    Grouped-query pages (``q_group`` > 1 query heads a K/V head; no int8
+    form) take no block-diagonal query: q_ref is [nh, hd], and each K/V
+    head's ``hd`` lanes of the rows meet its ``q_group`` query rows in
+    a ``[q_group, hd] x [hd, rows]`` product, so the MXU does the
+    needed products only; the accumulator is [nh, hd] and leaves as
+    the output."""
     G, ps = group, page_size
     pools, rest = rest[:2], rest[2:]
     if quant:
         ks_refs, vs_refs, rest = rest[:G], rest[G:2 * G], rest[2 * G:]
-    o_ref, k_buf, v_buf, sem, turn, qbd_scr, m_scr, l_scr, acc_scr = rest
+    if q_group > 1:
+        o_ref, k_buf, v_buf, sem, turn, m_scr, l_scr, acc_scr = rest
+    else:
+        o_ref, k_buf, v_buf, sem, turn, qbd_scr, m_scr, l_scr, acc_scr = rest
     bufs = (k_buf, v_buf)
     s = pl.program_id(0)
     j = pl.program_id(1)
@@ -184,9 +218,20 @@ def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, *rest,
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
-        q = jnp.broadcast_to(q_ref[:].astype(jnp.float32), (nh, C))
-        qbd_scr[:] = jnp.where(_head_mask(nh, head_dim), q,
-                               0.0).astype(qbd_scr.dtype)
+        if q_group == 1:
+            q = jnp.broadcast_to(q_ref[:].astype(jnp.float32), (nh, C))
+            qbd_scr[:] = jnp.where(_head_mask(nh, head_dim), q,
+                                   0.0).astype(qbd_scr.dtype)
+
+    def by_kv_head(a, rows, contract):
+        """``_dot_f32`` of each K/V head's ``q_group`` rows of ``a``
+        against its ``hd`` lanes of ``rows`` (a buffer half), stacked
+        back in query-head order."""
+        hd, g = head_dim, q_group
+        return jnp.concatenate([
+            _dot_f32(a[h * g:(h + 1) * g], rows[:, pl.ds(h * hd, hd)],
+                     contract)
+            for h in range(nh // g)], 0)
 
     # groups entirely past the fill bound contribute nothing; skipping
     # them is the paged analogue of the causal block skip
@@ -206,12 +251,17 @@ def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, *rest,
         copies(s, j, buf, start=False)
         turn[0] = 1 - buf
 
-        k, v = k_buf[buf], v_buf[buf]                    # [G * ps, C]
-        if quant:
-            k = k.astype(jnp.bfloat16)
-            v = v.astype(jnp.bfloat16)
-        # scores[h, p] = q[h, :] . k[p, h, :]
-        scr = _dot_f32(qbd_scr[:], k, 1) / math.sqrt(head_dim)
+        if q_group > 1:
+            # scores[i, p] = q[i, :] . k[p, i // q_group, :]
+            scr = by_kv_head(q_ref[:], k_buf.at[buf], 1) / math.sqrt(
+                head_dim)
+        else:
+            k, v = k_buf[buf], v_buf[buf]                # [G * ps, C]
+            if quant:
+                k = k.astype(jnp.bfloat16)
+                v = v.astype(jnp.bfloat16)
+            # scores[h, p] = q[h, :] . k[p, h, :]
+            scr = _dot_f32(qbd_scr[:], k, 1) / math.sqrt(head_dim)
         if quant:
             scr = scr * jnp.concatenate([r[:] for r in ks_refs], 0).T
         pos = first + jax.lax.broadcasted_iota(jnp.int32, scr.shape, 1)
@@ -225,6 +275,11 @@ def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, *rest,
             alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True),
             l_scr.shape)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        if q_group > 1:
+            # acc[i, d] += sum_p p[i, p] * v[p, i // q_group, d]
+            acc_scr[:] = acc_scr[:] * alpha + by_kv_head(p, v_buf.at[buf],
+                                                         0)
+            return
         if quant:
             p = p * jnp.concatenate([r[:] for r in vs_refs], 0).T
         # acc[h, h*hd + d] += sum_p p[h, p] * v[p, h*hd + d]
@@ -233,6 +288,9 @@ def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, *rest,
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
         l = jnp.maximum(l_scr[:, :1], 1e-30)             # [nh, 1]
+        if q_group > 1:
+            o_ref[:] = (acc_scr[:] / l).astype(o_ref.dtype)
+            return
         own = jnp.where(_head_mask(nh, head_dim), acc_scr[:] / l, 0.0)
         o_ref[:] = jnp.sum(own, axis=0, keepdims=True).astype(o_ref.dtype)
 
@@ -293,20 +351,26 @@ def _paged_call(q, pools, scales, page_table, lens, layer,
     S, T, nh, hd = q.shape
     assert T == 1, "paged decode kernel is single-token"
     ps, C = pools[0].shape[2:]
-    assert C == nh * hd, (pools[0].shape, q.shape)
+    assert C % hd == 0 and nh % (C // hd) == 0, (pools[0].shape, q.shape)
+    q_group = nh // (C // hd)
     maxP = page_table.shape[1]
     G = group_pages(maxP, ps, C, pools[0].dtype.itemsize, nh)
     pt_flat = page_table.reshape(-1).astype(jnp.int32)
     lens32 = lens.astype(jnp.int32)
     layer1 = jnp.reshape(layer, (1,)).astype(jnp.int32)
     quant = len(scales) > 0
+    assert q_group == 1 or not quant, "grouped-query pages are bf16 or fp32"
 
     def scale_rows(g):
         def index(s, j, pt, ln, ly):
             return pt[s * maxP + jnp.minimum(j * G + g, ln[s] // ps)], 0, 0
         return pl.BlockSpec((None, ps, nh), index)
 
-    row = pl.BlockSpec((None, 1, C), lambda s, j, pt, ln, ly: (s, 0, 0))
+    # a slot's queries: one row of every head side by side, or (grouped
+    # query heads) a row a head
+    rows_q, width_q = (1, C) if q_group == 1 else (nh, hd)
+    row = pl.BlockSpec((None, rows_q, width_q),
+                       lambda s, j, pt, ln, ly: (s, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S, maxP // G),
@@ -318,20 +382,22 @@ def _paged_call(q, pools, scales, page_table, lens, layer,
             *[pltpu.VMEM((2, G * ps, C), pools[0].dtype)] * 2,
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),         # which half is current
-            pltpu.VMEM((nh, C), q.dtype),        # block-diagonal query
+            *([pltpu.VMEM((nh, C), q.dtype)]     # block-diagonal query
+              if q_group == 1 else []),
             pltpu.VMEM((nh, 128), jnp.float32),  # running max, lanes equal
             pltpu.VMEM((nh, 128), jnp.float32),  # running sum, lanes equal
-            pltpu.VMEM((nh, C), jnp.float32),
+            pltpu.VMEM((nh, width_q), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, page_size=ps, head_dim=hd,
-                          group=G, table_width=maxP, quant=quant),
+                          group=G, table_width=maxP, quant=quant,
+                          q_group=q_group),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, 1, C), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, rows_q, width_q), q.dtype),
         name="paged_attn_decode",
         interpret=interpret,
-    )(pt_flat, lens32, layer1, q.reshape(S, 1, C), *pools,
+    )(pt_flat, lens32, layer1, q.reshape(S, rows_q, width_q), *pools,
       *(x for x in scales for _ in range(G)))
     return out.reshape(S, 1, nh, hd)
 

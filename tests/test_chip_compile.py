@@ -686,3 +686,110 @@ def test_longcat_programs_fit_the_rule_and_hold_the_pool_in_place(
                   "scmoe_shortcut", "moe_router", "moe_held", "moe_zero",
                   "dense_mlp"):
         assert scope in text, scope
+
+
+# --------------------------------------------------------------------------
+# the grouped-query window/global family (models/cohere2_moe.py) at the
+# benchmark cell's sizes (benchmark/configs/command-a-plus-serve.json): 4
+# layers (three rope window layers and a NoPE full one), 128 query heads
+# on 8 K/V heads of 128, rings of 4,096 rows, 16 of 128 experts held,
+# slots and pages by the rule
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("table", [64, 128], ids=["ring", "pool"])
+def test_grouped_query_decode_kernel_at_the_served_widths(chip, table):
+    """16 query heads a K/V head, a ring's table of 64 pages and the
+    pool's of 128 (max_len 8192): one kernel, no block-diagonal query."""
+    from paddle_tpu.ops.pallas import paged_attn
+    pool = chip((3, 64 * 64, 64, 1024), bf16)
+    assert paged_attn.group_pages(table, 64, 1024, 2, 128) == 4
+    assert compiles(paged_attn._paged_call, chip((64, 1, 128, 128), bf16),
+                    (pool, pool), (), chip((64, table), i32),
+                    chip((64,), i32), chip((), i32)) == 1
+
+
+def test_prefill_forward_group_factor_at_the_served_widths(chip):
+    from paddle_tpu.ops.pallas import flash_prefill
+    fn = functools.partial(flash_prefill._flash_prefill_tpu, heads=128,
+                           scale=128 ** -0.5, group=16)
+    assert compiles(fn, chip((1, 4096, 128 * 128), bf16),
+                    chip((1, 4096, 8 * 128), bf16),
+                    chip((1, 8 * 128, 4096), bf16), chip((1,), i32)) == 1
+
+
+@pytest.fixture
+def served_cohere2(chip, monkeypatch):
+    import json
+    from paddle_tpu.inference.serving import PagedServingEngine
+    from paddle_tpu.models import cohere2_moe as cm
+    from paddle_tpu.ops.pallas import utils as pallas_utils
+    monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
+    monkeypatch.setenv("PADDLE_TPU_SERVING_DONATE", "1")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "command-a-plus-serve.json")) as f:
+        arch = json.load(f)
+    fields = cm.Cohere2MoeConfig.__dataclass_fields__
+    cfg = cm.Cohere2MoeConfig(**{k: v for k, v in arch.items()
+                                 if k in fields})
+    params = jax.tree_util.tree_map(
+        lambda x: chip(x.shape, x.dtype),
+        jax.eval_shape(lambda k: cm.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    e = arch["engine"]
+    eng = PagedServingEngine(
+        (params, cfg), capture_logits=False,
+        **dict(e, slots=8, num_pages=2, seq_buckets=tuple(e["seq_buckets"]),
+               batch_buckets=tuple(e["batch_buckets"])))
+    pools = (*(chip(s, bf16) for s in cm.paged_pool_shapes(
+        cfg, e["num_pages"], e["page_size"])),
+        *(chip(s, d) for s, d in cm.slot_state_shapes(
+            cfg, e["slots"], e["page_size"])))
+    return eng, params, pools, e
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_1x4096"])
+def test_cohere2_programs_fit_the_rule_and_write_no_scores(
+        chip, served_cohere2, program):
+    """The decode program reads every layer through the grouped-query
+    kernel; the largest prefill wave takes the flash forward in both
+    kinds of layer and holds no [1, 128, 4096, 4096] float32 scores (8
+    GiB); both fit the 90% rule at the sized slots and pages, the pool
+    and the rings taken in place."""
+    from paddle_tpu.inference.serving import pool_relayouts
+    eng, params, pools, e = served_cohere2
+    slots, ps = e["slots"], e["page_size"]
+    assert [p.shape for p in pools] == [(1, e["num_pages"], 64, 1024)] * 2 \
+        + [(3, slots * 64, 64, 1024)] * 2
+    if program == "decode":
+        fn = eng._build_decode()
+        args = (chip((slots, e["max_len"] // ps), i32),
+                *[chip((slots,), i32)] * 4)
+    else:
+        fn = eng._build_prefill(1, 4096)
+        args = (chip((1, 4096), i32), chip((1,), i32),
+                chip((1, 4096 // ps), i32), chip((slots,), i32),
+                chip((1,), i32))
+    compiled = fn.lower(params, *pools, *args).compile()
+    text = compiled.as_text()
+    assert pool_relayouts(text, pools[:2]) == []
+    assert pool_relayouts(text, pools[2:]) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools)
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total <= KANANA_BUDGET, total / 2 ** 30
+    assert "f32[1,128,4096,4096]" not in text
+    if program == "decode":
+        # one kernel in the window layers' scan body, one for the full
+        # layer, and the experts' two grouped matmuls in each
+        assert text.count("paged_attn_decode") >= 2
+        assert mem.temp_size_in_bytes < (64 << 20)
+    else:
+        assert text.count("flash_prefill_fwd") >= 2
+        assert mem.temp_size_in_bytes < (1 << 30)
+    assert "%moe_grouped_matmul_gate_up" in text
+    for scope in ("window_attn", "full_attn", "routed_share",
+                  "shared_experts", "kv_write", "head_sample"):
+        assert scope in text, scope
