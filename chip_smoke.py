@@ -386,6 +386,7 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
     engine's own account of the run; the engine is gone on return.
     ``capture_logits`` engines read every step back before the next (the
     drained loop); without it the engine runs a step ahead."""
+    import numpy as np
     from paddle_tpu.inference.serving import PagedServingEngine
 
     sz = run.sz
@@ -413,12 +414,14 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
 
     t0 = time.perf_counter()
     live_share = []    # of the decode kernel's grid steps, step by step
+    per_page = []      # the latent kernel's DMA descriptors a live page
 
     def answer(reqs):
         while not all(r.done for r in reqs):
             eng.step()
-            live_share.append(
-                eng.stats().get("paged_attn_live_step_share"))
+            st = eng.stats()
+            live_share.append(st.get("paged_attn_live_step_share"))
+            per_page.append(st.get("paged_mla_descriptors_per_page"))
 
     reqs = [eng.submit(p, sz.new_tokens) for p in prompts]
     answer(reqs)
@@ -488,6 +491,11 @@ def serve_requests(run, params, cfg, prompts, *, pool, tp=None,
         "paged_attn_group_pages": st.get("paged_attn_group_pages"),
         "paged_attn_live_step_share": (None if None in live_share
                                        else max(live_share)),
+        # the mean over steps with a slot active (None for a family
+        # whose decode kernel gives no ``decode_copy_descriptors``)
+        "paged_mla_descriptors_per_page": (
+            None if None in per_page or not any(per_page)
+            else round(float(np.mean([x for x in per_page if x])), 4)),
         "pool_relayouts": {k: len(v) for k, v in relayouts.items()},
         "requests": len(reqs) + len(again),
         "prompt_lens": [len(p) for p in prompts],

@@ -110,7 +110,10 @@ FAMILY_INTERFACE = (
 # (a family whose ``decode_paged`` returns counts beside the logits also
 # gives ``decode_extra_stats(cfg, flat) -> {counter: increment}``; one
 # whose decode kernel's grid is (slot, page group) by a rule of shapes
-# gives ``decode_group_pages(cfg, pools, table_width, tp) -> G``; one
+# gives ``decode_group_pages(cfg, pools, table_width, tp) -> G``, and
+# one whose kernel copies its pages itself may give
+# ``decode_copy_descriptors(cfg, lens, G, page_size) -> (descriptors,
+# pages)``, what a call spends issuing those copies; one
 # that keeps state per SLOT beside its pages — a recurrent layer's
 # state, a window's ring — gives ``slot_state_arrays(cfg) -> n``: the
 # last n arrays of ``init_paged_pools``' tuple are indexed by slot, not
@@ -3212,6 +3215,15 @@ class PagedServingEngine(ServingEngine):
             out["paged_attn_live_step_share"] = round(
                 int(live.sum())
                 / (self.slots * (self._pages_per_slot // G)), 4)
+            copies_of = getattr(self._family, "decode_copy_descriptors",
+                                None)
+            if copies_of is not None:
+                # DMA starts and waits a live page of the active slots
+                # costs the kernel's scalar core, one call
+                n, pages = copies_of(self.cfg, self._lens[self._active], G,
+                                     self._page_size)
+                out["paged_mla_descriptors_per_page"] = (
+                    round(n / pages, 4) if pages else 0.0)
         tier = self._host_tier
         out["host_tier_bytes"] = int(tier.bytes) if tier else 0
         out["host_tier_entries"] = len(tier) if tier else 0

@@ -32,11 +32,11 @@ latent ``c`` directly, ``o_lat = sum_j P_j c_j`` and ``o = o_lat Wuv`` —
 so the cache holds per position per layer only ``c`` (after its norm)
 and ``kr`` (after rope), and ``ops/pallas/paged_mla.py`` reads it.
 
-The latent pool is two arrays in the engine's two donated slots:
-``[L, pages, page_size, kv_lora_rank]`` and ``[L, pages, page_size,
-128]`` (the rope half in a whole 128-lane row, ``rope`` columns used),
-so both are stored major-to-minor as the kernel takes them and every
-layer writes them where they lie (``gpt._layer_scan``).
+The latent pool is ONE array in the engine's one donated slot:
+``[L, pages, page_size, kv_lora_rank + 128]``, a row ``c`` and then the
+rope key in a whole 128-lane row (``rope`` columns used, zeros past
+them), so a page is one contiguous block the kernel copies at once and
+every layer writes its rows where they lie (``gpt._layer_scan``).
 
 The expert layer is exact top-k and DROPLESS: assignments are sorted by
 expert and the three products run as grouped matmuls over every expert
@@ -669,10 +669,17 @@ def decode_group_pages(cfg, pools, table_width, tp=1):
     the rope lane row side by side, and every head meets it: 'tp' does
     not split it)."""
     from ..ops.pallas.paged_mla import group_pages
-    c_pool, r_pool = pools[:2]
-    return group_pages(table_width, c_pool.shape[2],
-                       c_pool.shape[3] + r_pool.shape[3],
-                       c_pool.dtype.itemsize, cfg.num_heads)
+    pool = pools[0]
+    return group_pages(table_width, pool.shape[2], pool.shape[3],
+                       pool.dtype.itemsize, cfg.num_heads)
+
+
+def decode_copy_descriptors(cfg, lens, group, page_size):
+    """(DMA descriptors, live pages) one call of the decode kernel
+    spends on slots of lengths ``lens`` with ``group`` pages a grid
+    step (ops/pallas/paged_mla.py::copy_descriptors)."""
+    from ..ops.pallas.paged_mla import copy_descriptors
+    return copy_descriptors(lens, group, page_size)
 
 
 def decode_extra_stats(cfg, flat):
@@ -700,14 +707,18 @@ def decode_extra_stats(cfg, flat):
 
 
 def paged_pool_shapes(cfg, num_pages, page_size):
-    lead = (cfg.num_hidden_layers, num_pages, page_size)
-    return lead + (cfg.kv_lora_rank,), lead + (ROPE_LANES,)
+    """ONE array: a cached row is ``c`` (``kv_lora_rank`` lanes) and
+    the rope key in a lane row of its own, so a page is one copy for
+    the decode kernel (ops/pallas/paged_mla.py)."""
+    return ((cfg.num_hidden_layers, num_pages, page_size,
+             cfg.kv_lora_rank + ROPE_LANES),)
 
 
 def init_paged_pools(cfg, num_pages, page_size, dtype=None, mesh=None,
                      kv_quant=False):
-    """The latent pool: (c, kr), zeros.  Page 0 is the scratch page.
-    The unused lanes of ``kr`` stay zero for ever: every write pads."""
+    """The latent pool: ``(rows,)``, zeros.  Page 0 is the scratch
+    page.  The lanes past the rope key stay zero for ever: every write
+    pads (:func:`_pool_rows`)."""
     assert mesh is None and not kv_quant
     cd = jnp.dtype(dtype or cfg.dtype)
     return tuple(gpt._pool_zeros(s, cd)
@@ -719,6 +730,18 @@ def _pad_rope(kr, dtype):
     return jnp.pad(kr.astype(dtype), pad)
 
 
+def _pool_rows(c, kr, dtype):
+    """The pool's rows of latents ``c`` [..., rank] and rope keys
+    ``kr`` [..., rope]: ``c``, then ``kr`` padded to a lane row."""
+    return jnp.concatenate([c.astype(dtype), _pad_rope(kr, dtype)], -1)
+
+
+def _split_rows(cfg, rows):
+    """(c, kr) of pool rows [..., rank + ROPE_LANES]."""
+    rank = cfg.kv_lora_rank
+    return rows[..., :rank], rows[..., rank:rank + cfg.qk_rope_head_dim]
+
+
 def prefill_attend(cfg, blk, x, pos, pools, layer, flat, lens, flash):
     """One attention block of a prefill wave over ``x`` [b, s, H]: the
     projections, the latents scattered into ``pools`` at ``layer``
@@ -727,20 +750,18 @@ def prefill_attend(cfg, blk, x, pos, pools, layer, flat, lens, flash):
     ``flash`` (``flash_prefill.use_flash_prefill``).  Returns (a [b, s,
     nh * v], pools)."""
     b, s = x.shape[:2]
-    ps = pools[0].shape[2]
+    pool, = pools
+    ps = pool.shape[2]
     q_nope, q_rope, c, kr = _mla_project(cfg, x, blk, pos)
     with jax.named_scope("kv_scatter"):
-        pc, pr = pools
-        pc = pc.at[layer, flat].set(
-            c.astype(pc.dtype).reshape(b * (s // ps), ps, -1))
-        pr = pr.at[layer, flat].set(
-            _pad_rope(kr, pr.dtype).reshape(b * (s // ps), ps, -1))
+        pool = pool.at[layer, flat].set(_pool_rows(
+            c, kr, pool.dtype).reshape(b * (s // ps), ps, -1))
     with jax.named_scope("mla_attn"):
         if flash:
             a = _mla_attend_flash(cfg, blk, q_nope, q_rope, c, kr, lens)
         else:
             a = _mla_attend(cfg, blk, q_nope, q_rope, c, kr, _causal(s, s))
-    return a, (pc, pr)
+    return a, (pool,)
 
 
 def prefill_uses_flash(cfg, b, s):
@@ -785,20 +806,17 @@ def chunk_attend(cfg, blk, x, pos, pools, layer, pt_row, offset):
     pools)."""
     C = x.shape[1]
     maxP = pt_row.shape[0]
-    ps = pools[0].shape[2]
+    pool, = pools
+    ps = pool.shape[2]
     q_nope, q_rope, c, kr = _mla_project(cfg, x, blk, pos)
-    views = []
-    for pool, new in zip(pools, (c, _pad_rope(kr, pools[1].dtype))):
-        view = pool[layer, pt_row].reshape(1, maxP * ps, -1)
-        views.append(jax.lax.dynamic_update_slice(
-            view, new.astype(pool.dtype), (0, offset, 0)))
-    pools = tuple(pool.at[layer, pt_row].set(v[0].reshape(maxP, ps, -1))
-                  for pool, v in zip(pools, views))
+    view = jax.lax.dynamic_update_slice(
+        pool[layer, pt_row].reshape(1, maxP * ps, -1),
+        _pool_rows(c, kr, pool.dtype), (0, offset, 0))
+    pool = pool.at[layer, pt_row].set(view[0].reshape(maxP, ps, -1))
     with jax.named_scope("mla_attn"):
-        a = _mla_attend(cfg, blk, q_nope, q_rope, views[0],
-                        views[1][..., :cfg.qk_rope_head_dim],
+        a = _mla_attend(cfg, blk, q_nope, q_rope, *_split_rows(cfg, view),
                         _causal(C, maxP * ps, offset))
-    return a, pools
+    return a, (pool,)
 
 
 def chunk_paged(params, cfg, pools, tokens, pt_row, offset):
@@ -828,28 +846,26 @@ def decode_attend(cfg, blk, x, lens, pools, layer, page_table, write_pages,
     [S, nh * v], pools)."""
     from ..ops.pallas import paged_mla
     S = x.shape[0]
+    pool, = pools
     q_nope, q_rope, c, kr = _mla_project(cfg, x, blk, lens)
     with jax.named_scope("kv_write"):
-        pc, pr = pools
-        pc = pc.at[layer, write_pages, write_offs].set(c.astype(pc.dtype))
-        pr = pr.at[layer, write_pages, write_offs].set(
-            _pad_rope(kr, pr.dtype))
+        pool = pool.at[layer, write_pages, write_offs].set(
+            _pool_rows(c, kr, pool.dtype))
     with jax.named_scope("mla_attn"):
         if absorbed:
             wuk, wuv = _wukv(cfg, blk)
             q_abs = jnp.einsum("shd,chd->shc", q_nope, wuk)
             o_lat = paged_mla.paged_mla_attention(
-                q_abs, q_rope, pc, pr, page_table, lens, layer,
+                q_abs, q_rope, pool, page_table, lens, layer,
                 1.0 / math.sqrt(cfg.qk_head_dim))
             a = jnp.einsum("shc,chd->shd", o_lat, wuv).reshape(S, -1)
         else:
-            view = page_table.shape[1] * pc.shape[2]
-            cv = pc[layer][page_table].reshape(S, view, -1)
-            rv = pr[layer][page_table].reshape(S, view, -1)
+            view = page_table.shape[1] * pool.shape[2]
+            rows = pool[layer][page_table].reshape(S, view, -1)
             mask = (jnp.arange(view)[None, :] <= lens[:, None])[:, None]
             a = _mla_attend(cfg, blk, q_nope[:, None], q_rope[:, None],
-                            cv, rv[..., :cfg.qk_rope_head_dim], mask)[:, 0]
-    return a, (pc, pr)
+                            *_split_rows(cfg, rows), mask)[:, 0]
+    return a, (pool,)
 
 
 def decode_paged(params, cfg, pools, page_table, write_pages, write_offs,

@@ -313,6 +313,7 @@ check_serving = ds.check_serving
 decode_extra_stats = ds.decode_extra_stats
 kv_pool_spec = ds.kv_pool_spec
 decode_group_pages = ds.decode_group_pages
+decode_copy_descriptors = ds.decode_copy_descriptors
 
 
 def shard_params_for_serving(params, cfg, mesh):
@@ -338,14 +339,16 @@ def kv_bytes_per_position(cfg, itemsize):
 
 
 def paged_pool_shapes(cfg, num_pages, page_size):
-    lead = (cfg.attention_blocks, num_pages, page_size)
-    return lead + (cfg.kv_lora_rank,), lead + (ds.ROPE_LANES,)
+    """``deepseek_v3.paged_pool_shapes``' one array of ``c`` and rope
+    rows, two blocks a layer."""
+    return ((cfg.attention_blocks, num_pages, page_size,
+             cfg.kv_lora_rank + ds.ROPE_LANES),)
 
 
 def init_paged_pools(cfg, num_pages, page_size, dtype=None, mesh=None,
                      kv_quant=False):
-    """The latent pool: (c, kr), zeros, two blocks a layer.  Page 0 is
-    the scratch page."""
+    """The latent pool: ``(rows,)``, zeros, two blocks a layer.  Page 0
+    is the scratch page."""
     assert mesh is None and not kv_quant
     cd = jnp.dtype(dtype or cfg.dtype)
     return tuple(gpt._pool_zeros(s, cd)

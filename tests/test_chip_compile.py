@@ -294,15 +294,15 @@ KANANA_BUDGET = int(16_909_336_064 * 0.9)
                          ids=["table-32", "table-6"])
 def test_paged_mla_decode_kernel(chip, maxp):
     """The latent kernel alone at 64 slots x pages of 64: 32 heads
-    against one shared 512 + 64 wide key, both pools handed over whole
-    and copied by the kernel; at the cell's table (32 wide) and at a
-    narrow one (6: two pages a step)."""
+    against one shared 512 + 64 wide key, the pool of 640-wide rows
+    handed over whole and copied by the kernel a page at a time, a
+    whole group waited for at once; at the cell's table (32 wide) and
+    at a narrow one (6: two pages a step)."""
     from paddle_tpu.ops.pallas.paged_mla import _paged_mla_tpu
     fn = functools.partial(_paged_mla_tpu, scale=192 ** -0.5)
     text = jax.jit(fn).lower(
         chip((KANANA_SLOTS, 32, 512), bf16), chip((KANANA_SLOTS, 32, 64), bf16),
-        chip((KANANA_LAYERS, KANANA_PAGES, KANANA_PS, 512), bf16),
-        chip((KANANA_LAYERS, KANANA_PAGES, KANANA_PS, 128), bf16),
+        chip((KANANA_LAYERS, KANANA_PAGES, KANANA_PS, 512 + 128), bf16),
         chip((KANANA_SLOTS, maxp), i32), chip((KANANA_SLOTS,), i32),
         chip((), i32)).compile().as_text()
     assert text.count("tpu_custom_call") == 1
@@ -652,7 +652,7 @@ def test_longcat_programs_fit_the_rule_and_hold_the_pool_in_place(
     from paddle_tpu.inference.serving import pool_relayouts
     eng, params, pools, e = served_longcat
     slots, ps = e["slots"], e["page_size"]
-    assert pools[0].shape == (8, e["num_pages"], 64, 512)
+    assert [p.shape for p in pools] == [(8, e["num_pages"], 64, 512 + 128)]
     if program == "decode":
         fn = eng._build_decode()
         args = (chip((slots, e["max_len"] // ps), i32),

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from paddle_tpu.inference.serving import PagedServingEngine, ServingEngine
 from paddle_tpu.models import deepseek_v3 as ds
@@ -228,28 +229,30 @@ class TestRouting:
             assert np.abs(np.asarray(y) - np.asarray(want)).max() < TOL
 
 
-def _kernel_operands(dtype, lens, ps, maxP, layers=2, seed=0):
+def _kernel_operands(dtype, lens, ps, maxP, layers=2, seed=0, nh=4,
+                     rank=32):
     """Seeded operands of the latent kernel for ``lens``: 4 heads, rank
-    32, rope 8.  Each slot's live table entries name distinct pages;
-    every entry past them holds a page id far out of range, and the
-    pool's LAST page — where an out-of-range index lands when it is
-    clamped — is NaN: a kernel that dereferences a dead entry returns
-    NaN (0 x NaN in the weighted sum)."""
+    32, rope 8 (by default), the pool's rows ``c`` and the rope key in
+    a lane row of its own after it.  Each slot's live table entries
+    name distinct pages; every entry past them holds a page id far out
+    of range, and the pool's LAST page — where an out-of-range index
+    lands when it is clamped — is NaN: a kernel that dereferences a
+    dead entry returns NaN (0 x NaN in the weighted sum)."""
     rng = np.random.default_rng(seed)
-    S, nh, rank, rope = len(lens), 4, 32, 8
+    S, rope = len(lens), 8
     P = S * maxP + 2
     dt = jnp.dtype(dtype)
     qa = jnp.asarray(rng.normal(size=(S, nh, rank)), dt)
     qr = jnp.asarray(rng.normal(size=(S, nh, rope)), dt)
-    cp = rng.normal(size=(layers, P, ps, rank))
-    rp = np.zeros((layers, P, ps, 128))
-    rp[..., :rope] = rng.normal(size=(layers, P, ps, rope))
-    cp[:, -1] = rp[:, -1] = np.nan
+    pool = np.zeros((layers, P, ps, rank + 128))
+    pool[..., :rank] = rng.normal(size=(layers, P, ps, rank))
+    pool[..., rank:rank + rope] = rng.normal(size=(layers, P, ps, rope))
+    pool[:, -1] = np.nan
     free = rng.permutation(np.arange(1, P - 1))[:S * maxP].reshape(S, maxP)
     live = np.asarray(lens)[:, None] // ps >= np.arange(maxP)[None, :]
     pt = np.where(live, free, 10 ** 6)
-    return (qa, qr, jnp.asarray(cp, dt), jnp.asarray(rp, dt),
-            jnp.asarray(pt, jnp.int32), jnp.asarray(lens, jnp.int32))
+    return (qa, qr, jnp.asarray(pool, dt), jnp.asarray(pt, jnp.int32),
+            jnp.asarray(lens, jnp.int32))
 
 
 def _assert_kernel_matches(dtype, operands):
@@ -257,14 +260,14 @@ def _assert_kernel_matches(dtype, operands):
     (4e-7 read); bf16 pools: the kernel's products are exact and both
     round the output to bf16 once — one bf16 step of an O(1) value,
     8e-3."""
-    qa, qr, cp, rp, pt, lens = operands
+    qa, qr, pool, pt, lens = operands
     # the fallback gathers every entry of the table: hand it the dead
     # ones as page 0 (masked there), the kernel as they are
-    seen = jnp.where(pt < cp.shape[1], pt, 0)
-    for layer in range(cp.shape[0]):
-        want = paged_mla._ref_paged_mla(qa, qr, cp[layer], rp[layer],
-                                        seen, lens, 0.2)
-        got = paged_mla._paged_mla_tpu(qa, qr, cp, rp, pt, lens,
+    seen = jnp.where(pt < pool.shape[1], pt, 0)
+    for layer in range(pool.shape[0]):
+        want = paged_mla._ref_paged_mla(qa, qr, pool[layer], seen, lens,
+                                        0.2)
+        got = paged_mla._paged_mla_tpu(qa, qr, pool, pt, lens,
                                        jnp.int32(layer), 0.2,
                                        interpret=True)
         err = jnp.abs(got.astype(jnp.float32)
@@ -322,7 +325,7 @@ class TestKernel:
         """The guard of the test above: the same operands through a
         mover that copies every page of a group gives NaN."""
         monkeypatch.setattr(paged_mla, "GROUP_ROWS", _R)
-        qa, qr, cp, rp, pt, lens = _kernel_operands(
+        qa, qr, pool, pt, lens = _kernel_operands(
             "float32", [_R + 5, 3, 0], ps=16, maxP=8)
         real = paged_mla.page_mover
 
@@ -330,7 +333,7 @@ class TestKernel:
             return real(pt_ref, _Always(lens_ref, _VIEW - 1), *a, **kw)
 
         monkeypatch.setattr(paged_mla, "page_mover", greedy)
-        got = paged_mla._paged_mla_tpu(qa, qr, cp, rp, pt, lens,
+        got = paged_mla._paged_mla_tpu(qa, qr, pool, pt, lens,
                                        jnp.int32(0), 0.2, interpret=True)
         assert bool(jnp.isnan(got).any())
 
@@ -357,12 +360,144 @@ class TestKernel:
         assert paged_mla.group_pages(6, 64, 640, 2, 32) == 2
 
     def test_narrow_rank_falls_back(self, monkeypatch):
-        """A copy out of HBM takes whole 128-lane rows: a latent rank
-        that is not stays with the fallback on the chip."""
+        """The rope key's lanes must start a whole 128-lane row into the
+        pool's row: a latent rank that is not a multiple of 128 stays
+        with the fallback on the chip."""
         monkeypatch.setattr(paged_mla, "pallas_enabled", lambda: True)
         pool = lambda w: jnp.zeros((1, 4, 16, w), jnp.bfloat16)
-        assert paged_mla._use_pallas_mla(pool(512), pool(128), 32)
-        assert not paged_mla._use_pallas_mla(pool(32), pool(128), 4)
+        assert paged_mla._use_pallas_mla(pool(512 + 128), 512, 32)
+        assert not paged_mla._use_pallas_mla(pool(32 + 128), 32, 4)
+
+
+# lengths over a table of 8 pages of 16: 0, 31, 32, 63, 64, 95 and the
+# full table, n = 1, 2, 3, 4, 5, 6 and 8 live pages.  A step of 32 rows
+# (G = 2) meets each of them as whole groups and a last group of one
+# page; a step of 64 (G = 4) as last groups of 1, 2 or 3 pages, a whole
+# one (n = 4) and a whole one and more (5, 6, 8)
+GROUP_LENS = [0, _R - 1, _R, 2 * _R - 1, 2 * _R, 3 * _R - 1, _VIEW - 1]
+
+
+class _Counted:
+    """``pltpu`` whose copies report each start, and each wait with the
+    semaphore of its half right after it, to the host."""
+    def __init__(self, real, log):
+        self._real, self._log = real, log
+
+    def make_async_copy(self, src, dst, sem):
+        real, log = self._real.make_async_copy(src, dst, sem), self._log
+
+        class Copy:
+            def start(self):
+                real.start()
+                jax.debug.callback(lambda: log["start"].append(1))
+
+            def wait(self):
+                real.wait()
+                jax.debug.callback(
+                    lambda v: log["after_wait"].append(int(v)),
+                    pl.semaphore_read(sem))
+        return Copy()
+
+    def __getattr__(self, name):
+        return getattr(self._real, name)
+
+
+class TestOneCopyAPage:
+    """The pool as ONE array of rows (``c``, then the rope key in a
+    lane row of its own): a live page is one copy, a whole group one
+    wait.  Held to the two-array kernel it replaced
+    (``tests/latent_two_pool.py``) bit for bit on the same contents."""
+
+    @pytest.mark.parametrize("rows,group", [(_R, 2), (2 * _R, 4)])
+    @pytest.mark.parametrize("heads", [32, 64])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_the_kernel_is_the_two_array_kernel_bit_for_bit(
+            self, dtype, heads, rows, group, monkeypatch):
+        import latent_two_pool as two
+        monkeypatch.setattr(paged_mla, "GROUP_ROWS", rows)
+        qa, qr, pool, pt, lens = _kernel_operands(
+            dtype, GROUP_LENS, ps=16, maxP=8, nh=heads, rank=512)
+        assert paged_mla.group_pages(8, 16, pool.shape[-1], jnp.dtype(
+            dtype).itemsize, heads) == group
+        for layer in range(pool.shape[0]):
+            li = jnp.int32(layer)
+            got = paged_mla._paged_mla_tpu(qa, qr, pool, pt, lens, li, 0.2,
+                                           interpret=True)
+            want = two._paged_mla_tpu(qa, qr, pool[..., :512],
+                                      pool[..., 512:], pt, lens, li, 0.2,
+                                      interpret=True)
+            assert got.dtype == want.dtype
+            assert np.array_equal(np.asarray(got), np.asarray(want))
+            assert not bool(jnp.isnan(got).any())
+
+    @pytest.mark.parametrize("rows,group", [(_R, 2), (2 * _R, 4)])
+    @pytest.mark.parametrize("case", sorted(MOVER_CASES) + ["group-lens"])
+    def test_each_wait_is_for_what_was_started(self, case, rows, group,
+                                               monkeypatch):
+        """No wait leaves its half's semaphore below 0 (a wait for bytes
+        never started: the chip would wait for ever), and every live
+        step ends with it at 0 (no copy left unwaited); the starts and
+        waits a call issues are :func:`paged_mla.copy_descriptors`'
+        count."""
+        monkeypatch.setattr(paged_mla, "GROUP_ROWS", rows)
+        lens_ = MOVER_CASES.get(case, GROUP_LENS)
+        qa, qr, pool, pt, lens = _kernel_operands(
+            "bfloat16", lens_, ps=16, maxP=8, layers=1)
+        log = {"start": [], "after_wait": [], "after_take": []}
+        monkeypatch.setattr(paged_mla, "pltpu",
+                            _Counted(paged_mla.pltpu, log))
+        real = paged_mla.page_mover
+
+        def mover(*a, **kw):
+            prime, take = real(*a, **kw)
+            sem = a[5]
+
+            def checked(s, j):
+                half = take(s, j)
+                jax.debug.callback(
+                    lambda v: log["after_take"].append(int(v)),
+                    pl.semaphore_read(sem.at[half]))
+                return half
+            return prime, checked
+
+        monkeypatch.setattr(paged_mla, "page_mover", mover)
+        got = paged_mla._paged_mla_tpu(qa, qr, pool, pt, lens, jnp.int32(0),
+                                       0.2, interpret=True)
+        jax.block_until_ready(got)
+        jax.effects_barrier()
+        assert min(log["after_wait"]) == 0
+        live = -(-(np.asarray(lens_) // 16 + 1) // group)
+        assert log["after_take"] == [0] * int(live.sum())
+        descriptors, pages = paged_mla.copy_descriptors(lens_, group, 16)
+        assert len(log["start"]) == pages
+        assert len(log["start"]) + len(log["after_wait"]) == descriptors
+
+    @pytest.mark.parametrize("path", ["xla", "kernel"])
+    def test_programs_equal_the_two_array_writers(self, tiny, path,
+                                                  monkeypatch):
+        """A bucketed wave, a chunked prefill and decode steps, through
+        the decode kernel (interpret mode) or the XLA fallback."""
+        import latent_two_pool as two
+        if path == "kernel":
+            two.kernels_on(monkeypatch)
+        two.assert_held_to_two_arrays(ds, *tiny, monkeypatch)
+
+    def test_descriptors_a_page_by_hand(self):
+        """n = len // ps + 1 pages a slot, each started once; n // G
+        whole groups waited for once each, the n % G pages of the last
+        group one wait each."""
+        ps, G = 64, 16
+        # 1 page: 1 + 1; 16 pages: 16 + 1; 17: 17 + 2; 19: 19 + 4;
+        # 64 (a full table of 64): 64 + 4
+        lens = [0, 16 * ps - 1, 16 * ps, 18 * ps + 38, 64 * ps - 1]
+        assert paged_mla.copy_descriptors(lens, G, ps) == (
+            2 + 17 + 19 + 23 + 68, 1 + 16 + 17 + 19 + 64)
+        assert paged_mla.copy_descriptors([5, 40], 1, 8) == (
+            (1 + 1) + (6 + 6), 1 + 6)
+        assert paged_mla.copy_descriptors([], G, ps) == (0, 0)
+        cfg = ds.deepseek_v3_tiny()
+        assert ds.decode_copy_descriptors(cfg, lens, G, ps) == \
+            paged_mla.copy_descriptors(lens, G, ps)
 
 
 class TestPagedEngine:
@@ -408,6 +543,30 @@ class TestPagedEngine:
         assert st["paged_attn_live_step_share"] == round(2 / 3, 4)
         eng.run(max_steps=200)
         assert eng.stats()["paged_attn_live_step_share"] == 0.0
+
+    def test_stats_report_descriptors_a_live_page(self, tiny, monkeypatch):
+        """``paged_mla_descriptors_per_page`` is the decode kernel's
+        starts and waits over the active slots' live pages, from
+        ``_lens``: one start a page, one wait a whole group of G and one
+        a page of the last, partial group; 0 with no slot active.  Two
+        pages of 8 a grid step, so both kinds of group occur."""
+        monkeypatch.setattr(paged_mla, "GROUP_ROWS", 16)
+        eng = _engine(tiny)
+        G, ps = eng.stats()["paged_attn_group_pages"], 8
+        assert G == 2 and eng.stats()["paged_mla_descriptors_per_page"] == 0
+        for i, n in enumerate((5, 29)):
+            eng.submit(_tokens(80 + i, n), 30)
+        for _ in range(4):
+            eng.step()
+        lens = eng._lens[eng._active]
+        assert len(lens) == 2 and lens.max() >= G * ps
+        n = lens // ps + 1
+        want = (n.sum() + (n // G).sum() + (n % G).sum()) / n.sum()
+        assert eng.stats()["paged_mla_descriptors_per_page"] == round(
+            float(want), 4)
+        assert 1 + 1 / G <= want < 2
+        eng.run(max_steps=200)
+        assert eng.stats()["paged_mla_descriptors_per_page"] == 0.0
 
     @pytest.mark.parametrize("page_size,width,group", [(8, 8, 8), (16, 4, 4),
                                                        (4, 16, 1)])

@@ -548,9 +548,22 @@ class TestPagedEngine:
         assert st["moe_output_rows"] == st["decode_steps"] * cfg.num_layers * R
 
     def test_the_pool_holds_two_blocks_a_layer(self, tiny):
+        """One array: a row is ``c`` (32) and the rope key's lane row."""
         _, cfg = tiny
-        c, r = lc.init_paged_pools(cfg, 5, 8)
-        assert c.shape == (4, 5, 8, 32) and r.shape == (4, 5, 8, 128)
+        rows, = lc.init_paged_pools(cfg, 5, 8)
+        assert rows.shape == (4, 5, 8, 32 + 128)
+
+    @pytest.mark.parametrize("path", ["xla", "kernel"])
+    def test_programs_equal_the_two_array_writers(self, tiny, path,
+                                                  monkeypatch):
+        """A bucketed wave, a chunked prefill and decode steps through
+        the one-array pool equal the two-array writers', bit for bit
+        (``tests/latent_two_pool.py``), through the decode kernel
+        (interpret mode) or the XLA fallback."""
+        import latent_two_pool as two
+        if path == "kernel":
+            two.kernels_on(monkeypatch)
+        two.assert_held_to_two_arrays(lc, *tiny, monkeypatch)
 
     def test_absorbed_decode_matches_the_published_order(self, tiny):
         params, cfg = tiny

@@ -38,10 +38,13 @@ ENGINE_PROGRAMS = {
     ("gpt", "prefill"): "519d9f0e877203e8",
     ("ouro", "decode"): "136ff2087cb55220",
     ("ouro", "prefill"): "6f038466104f8f2b",
-    ("kanana2", "decode"): "eb5916692e76cfb2",
-    ("kanana2", "prefill"): "a0607c0ce170287a",
-    ("longcat", "decode"): "818c16096952b231",
-    ("longcat", "prefill"): "1a24826d5f346e1f",
+    # the latent families' pool is ONE array of rows (c, then the rope
+    # key's lane row) where it was two: every program writes and reads
+    # it so, and the decode kernel takes it whole
+    ("kanana2", "decode"): "a0babb6ce3f80e95",
+    ("kanana2", "prefill"): "9e33dfc561c3f24e",
+    ("longcat", "decode"): "531f0fadf6ba39b3",
+    ("longcat", "prefill"): "4f8cbe006d72e283",
     ("phi4flash", "decode"): "176245507ed01eb4",
     ("phi4flash", "prefill"): "a9777275438e65bb",
 }
